@@ -164,8 +164,7 @@ def main() -> int:
     cc = None
     if args.compile_cache:
         from repro.serving import CompileCache
-        cc = CompileCache(args.compile_cache)
-        cc.enable_xla_cache()
+        cc = CompileCache(args.compile_cache)    # places the XLA cache too
         cc.prewarm()
     rep = run_loadgen(smoke=args.smoke, wave=args.wave,
                       lane_width=args.lane_width, compile_cache=cc)

@@ -81,10 +81,6 @@ import numpy as np
 ITERS_1D = 2000           # paper uses 100k; scaled for CPU wall-time — the
 REPEATS = 3               # us/iter metric is iteration-count invariant
 
-# How this harness invokes the Pallas kernels. Recorded in the JSON meta so
-# interpret-mode and TPU-compiled timings can never be silently compared.
-KERNEL_INTERPRET = True
-
 # Machine-readable result records: [{"name": ..., "us_per_call": ...,
 # <derived k/v>}, ...], dumped to BENCH_pso.json by main().
 RESULTS = []
@@ -217,12 +213,10 @@ def async_sweep(smoke=False) -> None:
 
     def async_call(k):
         return run_queue_lock_fused_async(cfg, s0, iters=iters,
-                                          sync_every=k, block_n=block_n,
-                                          interpret=KERNEL_INTERPRET)
+                                          sync_every=k, block_n=block_n)
 
     def sync_call():
-        return run_queue_lock_fused(cfg, s0, iters=iters, block_n=block_n,
-                                    interpret=KERNEL_INTERPRET)
+        return run_queue_lock_fused(cfg, s0, iters=iters, block_n=block_n)
 
     fns = {k: (lambda k=k: jax.block_until_ready(async_call(k).gbest_fit))
            for k in sweep}
@@ -438,8 +432,7 @@ def custom_objective(smoke=False) -> None:
         last = {}
 
         def call(cfg=cfg, s0=s0, last=last):
-            out = run_queue_lock_fused(cfg, s0, iters=iters,
-                                       interpret=KERNEL_INTERPRET)
+            out = run_queue_lock_fused(cfg, s0, iters=iters)
             last["gbest"] = float(jax.block_until_ready(out.gbest_fit))
 
         t = _time(call, repeats=1)  # deterministic: timed runs = quality run
@@ -664,11 +657,15 @@ def main() -> None:
         lm_bench()
     if args.out:
         import platform
+        from repro.kernels.pso_step import default_interpret
         doc = {
             "meta": {
                 "backend": jax.default_backend(),
                 "jax_version": jax.__version__,
-                "pallas_interpret": KERNEL_INTERPRET,
+                # how the kernels ran (the builders decide from the
+                # platform), so interpret-mode and TPU-compiled timings can
+                # never be silently compared
+                "pallas_interpret": default_interpret(),
                 "smoke": bool(args.smoke),
                 # recorded so compare.py can tell same-runner A/Bs (where
                 # the hard gate is meaningful) from cross-machine diffs.

@@ -18,9 +18,8 @@ per-dimension bounds and min/max sense), or a bare pure-jnp callable.
 * ``backend``: ``jnp`` (vmap-able XLA step functions), ``kernel`` (the
   fused/async Pallas TPU kernels; only ``queue_lock``/``async`` exist as
   kernels), or ``auto`` — kernel on a TPU backend for the two fused
-  variants, jnp everywhere else.
-* ``interpret``: Pallas interpret mode; ``None`` means auto (False only on
-  an actual TPU backend).
+  variants, jnp everywhere else. The kernels run compiled on a TPU and in
+  the Pallas interpreter on any other backend; nothing here chooses.
 * ``islands``/``exchange_interval``: shard the swarm over devices
   (``repro.core.distributed``) — ``variant="async"`` uses the barrier-free
   island ring exchange, the synchronous variants the ``_pmax_best``
@@ -77,7 +76,9 @@ class Method:
     everywhere else — EXCEPT when ``telemetry=True``, which always
     resolves to the kernel (the contention counters are collected inside
     the fused Pallas kernels; on non-TPU hosts the kernel runs in
-    interpret mode). ``record_history=True`` works on either backend: the
+    interpret mode). Problems with a projection-mode constraint set always
+    resolve to jnp (see ``resolve_backend``). ``record_history=True`` works
+    on either backend: the
     jnp engines scan the trajectory in-program, the kernel backend chunks
     its launch at sync points with a gbest readback per boundary.
 
@@ -103,7 +104,6 @@ class Method:
     backend: str = "auto"                 # auto | jnp | kernel
     sync_every: int = ASYNC_SYNC_EVERY    # async variant publication interval
     block_n: Optional[int] = None         # kernel particle-block size
-    interpret: Optional[bool] = None      # None: False only on real TPU
     islands: int = 0                      # >0: shard over this many devices
     exchange_interval: int = 1            # iterations between island syncs
     record_history: bool = False          # Result.history: gbest per sync
@@ -182,12 +182,31 @@ class Method:
                 "do not surface per-iteration gbest); drop islands= or "
                 "record the trajectory from a single-device solve")
 
-    def resolve_backend(self) -> str:
+    def _pinned_backend(self, problem: Problem) -> Optional[str]:
+        """The backend fixed by the problem or by this Method's knobs, or
+        None where ``backend="auto"`` leaves the choice open."""
+        from repro.kernels.pso_step import tpu_lowerable
+        if not tpu_lowerable(problem):
+            # Projection-mode constraint sets sort inside the advance, and
+            # Mosaic has no sort lowering: these problems run on jnp.
+            if self.backend == "kernel" or self.telemetry:
+                raise ValueError(
+                    f"problem {problem.name!r} has a projection-mode "
+                    f"constraint set, which the Pallas kernels cannot "
+                    f"compile on a TPU (no sort lowering); use "
+                    f"backend='jnp' or 'auto' without telemetry")
+            return "jnp"
         if self.backend != "auto":
             return self.backend
         if self.telemetry:
             # the contention counters only exist inside the fused kernels
             return "kernel"
+        return None
+
+    def resolve_backend(self, problem: Problem) -> str:
+        pinned = self._pinned_backend(problem)
+        if pinned is not None:
+            return pinned
         if self.variant in _KERNEL_VARIANTS and _default_backend() == "tpu":
             return "kernel"
         return "jnp"
@@ -202,25 +221,18 @@ class Method:
         ranking, measured micro-run fallback (``measure=False`` stops at
         the model), on-disk cache per shape."""
         from repro.core.autotune import Schedule, resolve_schedule
+        prob = resolve_problem(problem)
         if self.schedule != "auto":
             return Schedule(variant=self.variant,
-                            backend=self.resolve_backend(),
+                            backend=self.resolve_backend(prob),
                             block_n=self.block_n,
                             sync_every=self.sync_every, source="fixed")
-        kernel_ok = None
-        if self.backend == "jnp":
-            kernel_ok = False
-        elif self.backend == "kernel" or self.telemetry:
-            kernel_ok = True
+        pinned = self._pinned_backend(prob)
+        kernel_ok = None if pinned is None else pinned == "kernel"
         return resolve_schedule(
             problem, d, n, iters, dtype=dtype, batch=batch,
             hetero_table=hetero_table, record_history=self.record_history,
             measure=measure, kernel_ok=kernel_ok, rule=self.rule)
-
-    def resolve_interpret(self) -> bool:
-        if self.interpret is not None:
-            return self.interpret
-        return _default_backend() != "tpu"
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -322,11 +334,11 @@ def _jnp_async_blocks(m: Method, n: int) -> Optional[int]:
 
 
 def _make_method(method: Optional[Method], variant, backend, sync_every,
-                 block_n, interpret, record_history=None,
+                 block_n, record_history=None,
                  schedule=None, rule=None, topology=None,
                  telemetry=None) -> Method:
     explicit = dict(variant=variant, backend=backend, sync_every=sync_every,
-                    block_n=block_n, interpret=interpret,
+                    block_n=block_n,
                     record_history=record_history, schedule=schedule,
                     rule=rule, topology=topology, telemetry=telemetry)
     given = {k: v for k, v in explicit.items() if v is not None}
@@ -360,7 +372,6 @@ def solve(problem: Union[str, Problem], *,
           method: Optional[Method] = None,
           variant: Optional[str] = None, backend: Optional[str] = None,
           sync_every: Optional[int] = None, block_n: Optional[int] = None,
-          interpret: Optional[bool] = None,
           w: Optional[float] = None, c1: Optional[float] = None,
           c2: Optional[float] = None, dtype: str = "float32",
           min_pos=None, max_pos=None, max_v=None,
@@ -378,8 +389,7 @@ def solve(problem: Union[str, Problem], *,
     """
     prob = resolve_problem(problem)
     m = _make_method(method, variant, backend, sync_every, block_n,
-                     interpret, record_history, schedule, rule, topology,
-                     telemetry)
+                     record_history, schedule, rule, topology, telemetry)
     cfg = _make_config(prob, dim, particles, w, c1, c2, dtype,
                        min_pos, max_pos, max_v, m)
     m = _effective_method(m, prob, cfg, iters)
@@ -412,10 +422,9 @@ def _run_islands(prob: Problem, cfg: PSOConfig, seed: int, iters: int,
             f"device(s)")
     mesh = Mesh(_np.asarray(devs[:m.islands]), ("data",))
     local_step = None
-    if m.variant != "async" and m.resolve_backend() == "kernel":
+    if m.variant != "async" and m.resolve_backend(prob) == "kernel":
         from repro.kernels.ops import make_fused_local_step
-        local_step = make_fused_local_step(
-            block_n=m.block_n, interpret=m.resolve_interpret())
+        local_step = make_fused_local_step(block_n=m.block_n)
     state = init_sharded_swarm(cfg, seed, mesh)
 
     def run_seg(cfg_k: PSOConfig, s: SwarmState, seg_iters: int):
@@ -527,7 +536,7 @@ def _run_segmented(prob: Problem, cfg: PSOConfig, state: SwarmState,
 def _run_state(cfg: PSOConfig, state: SwarmState, iters: int, m: Method):
     """One static-weight segment -> (state, history-or-None,
     KernelCounters-or-None)."""
-    if m.resolve_backend() == "kernel":
+    if m.resolve_backend(cfg.problem) == "kernel":
         return _run_state_kernel(cfg, state, iters, m)
     if m.record_history:
         state, (its, fits, viols) = run_with_history(
@@ -553,15 +562,14 @@ def _run_state_kernel(cfg: PSOConfig, state: SwarmState, iters: int,
     per-chunk counts sum to the uninterrupted run's."""
     from repro.kernels.ops import (run_queue_lock_fused,
                                    run_queue_lock_fused_async)
-    interp = m.resolve_interpret()
 
     def launch(s, k):
         if m.variant == "async":
             return run_queue_lock_fused_async(
                 cfg, s, k, sync_every=m.sync_every, block_n=m.block_n,
-                interpret=interp, telemetry=m.telemetry)
+                telemetry=m.telemetry)
         return run_queue_lock_fused(cfg, s, k, block_n=m.block_n,
-                                    interpret=interp, telemetry=m.telemetry)
+                                    telemetry=m.telemetry)
 
     if not m.record_history:
         if m.telemetry:
@@ -598,7 +606,6 @@ def solve_many(problem: Union[str, Problem, None] = None,
                variant: Optional[str] = None, backend: Optional[str] = None,
                sync_every: Optional[int] = None,
                block_n: Optional[int] = None,
-               interpret: Optional[bool] = None,
                coeffs: Optional[Tuple] = None,
                w: Optional[float] = None, c1: Optional[float] = None,
                c2: Optional[float] = None, dtype: str = "float32",
@@ -626,8 +633,7 @@ def solve_many(problem: Union[str, Problem, None] = None,
     envelope).
     """
     m = _make_method(method, variant, backend, sync_every, block_n,
-                     interpret, record_history, schedule, rule, topology,
-                     telemetry)
+                     record_history, schedule, rule, topology, telemetry)
     if m.islands:
         raise ValueError("islands shard ONE swarm over devices; use solve()"
                          " — solve_many batches independent swarms instead")
@@ -708,7 +714,7 @@ def _solve_many_hetero(problems, seeds, m: Method, dim, particles, iters,
                           hetero_table=len({p.cache_key() for p in probs}))
     seeds_arr = np.asarray(seeds, dtype=np.int64)
     hists, cnts = [], []
-    if m.resolve_backend() == "kernel":
+    if m.resolve_backend(probs[0]) == "kernel":
         if coeffs is not None:
             raise ValueError("per-swarm coeffs are a jnp-backend feature")
         from repro.core.multi_swarm import problem_rows
@@ -768,7 +774,7 @@ def _run_batch(cfg: PSOConfig, batch: SwarmBatch, iters: int, m: Method,
                coeffs):
     """One static-weight batched segment -> (batch, history-or-None,
     [S, 3] counter rows or None)."""
-    if m.resolve_backend() == "kernel":
+    if m.resolve_backend(cfg.problem) == "kernel":
         if coeffs is not None:
             raise ValueError("per-swarm coeffs are a jnp-backend feature")
         return _run_batch_kernel(cfg, batch, iters, m)
@@ -793,18 +799,16 @@ def _run_batch_kernel(cfg: PSOConfig, batch: SwarmBatch, iters: int,
     heterogeneous (per-row ``lax.switch`` objective dispatch)."""
     from repro.kernels.ops import (run_queue_lock_fused_batch,
                                    run_queue_lock_fused_async_batch)
-    interp = m.resolve_interpret()
     fids = None if rows is None else rows.fid
 
     def launch(b, k):
         if m.variant == "async":
             return run_queue_lock_fused_async_batch(
                 cfg, b, k, sync_every=m.sync_every, block_n=m.block_n,
-                interpret=interp, fids=fids, table=table,
-                telemetry=m.telemetry)
+                fids=fids, table=table, telemetry=m.telemetry)
         return run_queue_lock_fused_batch(
-            cfg, b, k, block_n=m.block_n, interpret=interp, fids=fids,
-            table=table, telemetry=m.telemetry)
+            cfg, b, k, block_n=m.block_n, fids=fids, table=table,
+            telemetry=m.telemetry)
 
     if not m.record_history:
         if m.telemetry:

@@ -306,17 +306,15 @@ def measure_schedule(sched: Schedule, problem, d: int, n: int,
     if sched.backend == "kernel":
         from repro.kernels.ops import (run_queue_lock_fused,
                                        run_queue_lock_fused_async)
-        interpret = not _kernel_ok()
         if sched.variant == "async":
             def go():
                 return run_queue_lock_fused_async(
                     cfg, state, iters, sync_every=sched.sync_every,
-                    block_n=sched.block_n, interpret=interpret)
+                    block_n=sched.block_n)
         else:
             def go():
                 return run_queue_lock_fused(cfg, state, iters,
-                                            block_n=sched.block_n,
-                                            interpret=interpret)
+                                            block_n=sched.block_n)
     else:
         n_blocks = (n // sched.block_n
                     if sched.variant == "async" and sched.block_n else None)
@@ -351,7 +349,8 @@ def resolve_schedule(problem, d: int, n: int, iters: int, *,
     schedule is ALWAYS among the measured candidates, and a challenger
     must beat it by ``MEASURE_NOISE_MARGIN`` to displace it: the tuned
     pick is never worse than the fixed rule, and within-noise ties keep
-    the default."""
+    the default. A kernel candidate that raises on a TPU propagates: on
+    the chip that is a defect to surface, not a schedule that lost."""
     cache = cache or default_cache()
     if kernel_ok is None:
         kernel_ok = _kernel_ok() and not record_history
@@ -390,6 +389,8 @@ def resolve_schedule(problem, d: int, n: int, iters: int, *,
                                        s, problem, d, n, dtype,
                                        rule=rule)))
         except Exception:
+            if s.backend == "kernel" and _kernel_ok():
+                raise   # a kernel that fails on the chip is a defect
             continue    # an unmeasurable candidate just drops out
     if not timed:
         return ranked[0]

@@ -70,24 +70,9 @@ from .pso import (ASYNC_SYNC_EVERY, PSOConfig, STEP_FNS, SwarmState,
 
 Array = jnp.ndarray
 
-# jax moved shard_map to the top level and renamed check_rep -> check_vma in
-# newer releases — and not necessarily in the same release, so resolve the
-# function and the kwarg spelling independently.
-if hasattr(jax, "shard_map"):
-    _shard_map_fn = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-
-import inspect as _inspect
-
-_SM_CHECK_KW = ("check_vma" if "check_vma"
-                in _inspect.signature(_shard_map_fn).parameters
-                else "check_rep")
-
-
 def _shard_map(f, mesh, in_specs, out_specs):
-    return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, **{_SM_CHECK_KW: False})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def swarm_pspec(particle_axes, with_locals: bool = False) -> SwarmState:

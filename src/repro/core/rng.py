@@ -5,9 +5,10 @@ The paper uses cuRAND's ``curand_uniform_double`` (§5.4) because a stateful
 hand-rolled RNG is not thread-safe on GPU. The TPU-native adaptation is a
 *counter-based* generator: a 32-bit mixing hash of ``(seed, iteration,
 stream, element index)``. It is stateless (no RNG state to carry, checkpoint
-or shard), identical inside and outside Pallas (the body is plain jnp ops on
-uint32, which lower in both contexts), and reproducible across any device
-layout — resharding a swarm never changes its trajectory.
+or shard), identical inside and outside Pallas (the body is plain jnp
+integer ops that Mosaic and XLA both lower; the one conversion to float goes
+through int32, because Mosaic has no uint32 -> float cast), and reproducible
+across any device layout — resharding a swarm never changes its trajectory.
 
 The mixer is two rounds of the murmur3/splitmix finalizer over a Weyl-summed
 counter. It passes the birthday/equidistribution sanity checks in
@@ -55,8 +56,10 @@ def hash_u32(seed, iteration, stream, index) -> jnp.ndarray:
 def uniform(seed, iteration, stream, index, dtype=jnp.float32) -> jnp.ndarray:
     """Uniform in [0, 1) with 24 bits of mantissa entropy."""
     bits = hash_u32(seed, iteration, stream, index)
+    # bits >> 8 < 2**24 fits int32 exactly, so the int32 hop changes no bit
+    # of the result; Mosaic refuses a direct uint32 -> float cast.
     # python-float scale: folds at trace time, keeps dtype via weak promotion
-    return (bits >> 8).astype(dtype) * (1.0 / (1 << 24))
+    return (bits >> 8).astype(jnp.int32).astype(dtype) * (1.0 / (1 << 24))
 
 
 def uniform_grid(seed, iteration, stream, n, d, dtype=jnp.float32) -> jnp.ndarray:

@@ -32,7 +32,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams as _CompilerParams
+from .pso_step import default_interpret
 
 _CLAMP = 20.0
 
@@ -76,7 +76,7 @@ def _gla_kernel(q_ref, k_ref, v_ref, ld_ref, li_ref, y_ref, h_scratch,
 
 
 def gla_forward_call(bh: int, s: int, n: int, p: int, chunk: int, dtype,
-                     interpret: bool = True):
+                     interpret=None):
     """Build the pallas_call. Inputs: q,k [BH,S,N]; v [BH,S,P]; ld,li
     [BH,S]. Returns y [BH,S,P]. S must be a multiple of chunk."""
     assert s % chunk == 0, (s, chunk)
@@ -92,15 +92,14 @@ def gla_forward_call(bh: int, s: int, n: int, p: int, chunk: int, dtype,
         out_specs=mat(p),
         out_shape=jax.ShapeDtypeStruct((bh, s, p), dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY)),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
         name="gla_chunked_fwd",
     )
 
 
-def gla_forward(q, k, v, log_decay, log_inc, chunk: int = 128,
-                interpret: bool = True):
+def gla_forward(q, k, v, log_decay, log_inc, chunk: int = 128):
     """Drop-in (forward-only) replacement for models.ssm.gla_chunked.
 
     q,k: [B,S,H,N]; v: [B,S,H,P]; gates [B,S,H]. Returns y [B,S,H,P].
@@ -120,8 +119,7 @@ def gla_forward(q, k, v, log_decay, log_inc, chunk: int = 128,
     qf, kf, vf = fold(q), fold(k), fold(v)
     ldf = log_decay.transpose(0, 2, 1).reshape(b * h, sp)
     lif = log_inc.transpose(0, 2, 1).reshape(b * h, sp)
-    call = gla_forward_call(b * h, sp, n, p, min(chunk, sp), v.dtype,
-                            interpret=interpret)
+    call = gla_forward_call(b * h, sp, n, p, min(chunk, sp), v.dtype)
     y = call(qf, kf, vf, ldf, lif)
     y = y.reshape(b, h, sp, p).transpose(0, 2, 1, 3)
     return y[:, :s]

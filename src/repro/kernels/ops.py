@@ -5,9 +5,9 @@ D-major kernel layout), block-size selection, the queue algorithm's tiny
 cross-block second stage, and SwarmState plumbing so kernels are drop-in
 replacements for the ``repro.core.pso`` step functions.
 
-``interpret`` defaults to True: this container is CPU-only and the kernels
-TARGET TPU; on a real TPU pass interpret=False (the pallas_calls carry
-TPU-valid BlockSpecs, dtypes and memory spaces).
+No wrapper takes an ``interpret`` flag: the builders compile the kernels
+with Mosaic on a TPU and run them in the Pallas interpreter on any other
+backend (``pso_step.default_interpret``).
 """
 from __future__ import annotations
 
@@ -55,6 +55,17 @@ def unpack_dmajor(arr, d: int):
     return arr[:d, :].T
 
 
+def pack_columns(x, d: int):
+    """[K, D] -> [K, Dpad, 1]: one zero-padded column per row (per-swarm
+    gbests, per-block local bests), the column index on the leading axis."""
+    return jnp.zeros((x.shape[0], pad_dim(d), 1), x.dtype).at[:, :d, 0].set(x)
+
+
+def unpack_columns(arr, d: int):
+    """[K, Dpad, 1] -> [K, D]."""
+    return arr[:, :d, 0]
+
+
 def _cfg_kwargs(cfg: PSOConfig):
     """Static kernel parameters from a config. ``fitness`` stays a
     str | Problem (resolved to the d-major callable by the call builders
@@ -89,10 +100,9 @@ def kernel_to_state(s: SwarmState, d: int, pos, vel, pbp, pbf, gp, gf,
         lbest_pos=None, lbest_fit=None)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "block_n", "interpret"))
-def queue_step(cfg: PSOConfig, s: SwarmState, block_n: Optional[int] = None,
-               interpret: bool = True) -> SwarmState:
+@functools.partial(jax.jit, static_argnames=("cfg", "block_n"))
+def queue_step(cfg: PSOConfig, s: SwarmState,
+               block_n: Optional[int] = None) -> SwarmState:
     """One PSO iteration via the queue kernel + jnp cross-block epilogue.
 
     Semantics match ``repro.core.pso.step_queue`` (stale-gbest comparison).
@@ -101,8 +111,7 @@ def queue_step(cfg: PSOConfig, s: SwarmState, block_n: Optional[int] = None,
     n, d = s.pos.shape
     bn = _resolve_block(n, block_n)
     scal, pos, vel, pbp, pbf, gp, gf = state_to_kernel(s, d)
-    call = queue_step_call(n, d, bn, s.pos.dtype, interpret=interpret,
-                           **_cfg_kwargs(cfg))
+    call = queue_step_call(n, d, bn, s.pos.dtype, **_cfg_kwargs(cfg))
     pos, vel, pbp, pbf, aux_fit, aux_idx = call(
         scal, gp, gf, pos, vel, pbp, pbf)
     # --- 2nd kernel (paper Fig. 1), shrunk to an O(nblocks) jnp epilogue.
@@ -117,11 +126,10 @@ def queue_step(cfg: PSOConfig, s: SwarmState, block_n: Optional[int] = None,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("cfg", "iters", "block_n", "interpret",
-                                    "telemetry"))
+                   static_argnames=("cfg", "iters", "block_n", "telemetry"))
 def run_queue_lock_fused(cfg: PSOConfig, s: SwarmState, iters: int,
                          block_n: Optional[int] = None,
-                         interpret: bool = True, telemetry: bool = False):
+                         telemetry: bool = False):
     """``iters`` iterations in ONE pallas_call (fused queue-lock, §4.2+).
 
     On TPU this is the roofline-relevant path: state stays resident, the
@@ -137,8 +145,8 @@ def run_queue_lock_fused(cfg: PSOConfig, s: SwarmState, iters: int,
     n, d = s.pos.shape
     bn = _resolve_block(n, block_n)
     scal, pos, vel, pbp, pbf, gp, gf = state_to_kernel(s, d)
-    call = fused_call(n, d, iters, bn, s.pos.dtype, interpret=interpret,
-                      telemetry=telemetry, **_cfg_kwargs(cfg))
+    call = fused_call(n, d, iters, bn, s.pos.dtype, telemetry=telemetry,
+                      **_cfg_kwargs(cfg))
     if telemetry:
         cnt = jnp.zeros((3,), jnp.int32)
         pos, vel, pbp, pbf, gp, gf, cnt = call(scal, pos, vel, pbp, pbf,
@@ -149,15 +157,15 @@ def run_queue_lock_fused(cfg: PSOConfig, s: SwarmState, iters: int,
 
 
 def pack_dmajor_batch(x, d: int):
-    """[S, N, D] -> [Dpad, S*N] (swarm s owns columns [s*N, (s+1)*N))."""
+    """[S, N, D] -> [S, Dpad, N] (zero-padded sublanes)."""
     s_cnt, n, _ = x.shape
-    return pack_dmajor(x.reshape(s_cnt * n, d), d)
+    out = jnp.zeros((s_cnt, pad_dim(d), n), x.dtype)
+    return out.at[:, :d, :].set(jnp.swapaxes(x, 1, 2))
 
 
-def unpack_dmajor_batch(arr, s_cnt: int, d: int):
-    """[Dpad, S*N] -> [S, N, D]."""
-    n = arr.shape[1] // s_cnt
-    return unpack_dmajor(arr, d).reshape(s_cnt, n, d)
+def unpack_dmajor_batch(arr, d: int):
+    """[S, Dpad, N] -> [S, N, D]."""
+    return jnp.swapaxes(arr[:, :d, :], 1, 2)
 
 
 def _hetero_members(cfg: PSOConfig, table):
@@ -173,11 +181,10 @@ def _hetero_members(cfg: PSOConfig, table):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("cfg", "iters", "block_n", "interpret",
-                                    "table", "telemetry"))
+                   static_argnames=("cfg", "iters", "block_n", "table",
+                                    "telemetry"))
 def run_queue_lock_fused_batch(cfg: PSOConfig, batch: SwarmBatch, iters: int,
-                               block_n: Optional[int] = None,
-                               interpret: bool = True, fids=None,
+                               block_n: Optional[int] = None, fids=None,
                                table=None, telemetry: bool = False):
     """S independent swarms x ``iters`` iterations in ONE pallas_call.
 
@@ -200,15 +207,13 @@ def run_queue_lock_fused_batch(cfg: PSOConfig, batch: SwarmBatch, iters: int,
     pos = pack_dmajor_batch(batch.pos, d)
     vel = pack_dmajor_batch(batch.vel, d)
     pbp = pack_dmajor_batch(batch.pbest_pos, d)
-    pbf = batch.pbest_fit.reshape(1, s_cnt * n)
-    gp = jnp.zeros((pad_dim(d), s_cnt), batch.pos.dtype).at[:d].set(
-        batch.gbest_pos.T)
+    pbf = batch.pbest_fit.reshape(s_cnt, 1, n)
+    gp = pack_columns(batch.gbest_pos, d)
     gf = batch.gbest_fit
     cnt = jnp.zeros((3 * s_cnt,), jnp.int32) if telemetry else None
     if fids is None:
         call = fused_batch_call(s_cnt, n, d, iters, bn, batch.pos.dtype,
-                                interpret=interpret, telemetry=telemetry,
-                                **_cfg_kwargs(cfg))
+                                telemetry=telemetry, **_cfg_kwargs(cfg))
         if telemetry:
             pos, vel, pbp, pbf, gp, gf, cnt = call(
                 seeds, its, pos, vel, pbp, pbf, gp, gf, cnt)
@@ -223,7 +228,7 @@ def run_queue_lock_fused_batch(cfg: PSOConfig, batch: SwarmBatch, iters: int,
         call = hetero_fused_batch_call(
             s_cnt, n, d, iters, bn, batch.pos.dtype, w=rcfg.w, c1=rcfg.c1,
             c2=rcfg.c2, members=_hetero_members(cfg, table),
-            rule=rcfg.update_rule, interpret=interpret, telemetry=telemetry)
+            rule=rcfg.update_rule, telemetry=telemetry)
         if telemetry:
             pos, vel, pbp, pbf, gp, gf, cnt = call(
                 seeds, its, fids.astype(jnp.int32), pos, vel, pbp, pbf,
@@ -234,11 +239,11 @@ def run_queue_lock_fused_batch(cfg: PSOConfig, batch: SwarmBatch, iters: int,
                 gp, gf)
     pbf = pbf.reshape(s_cnt, n)
     out = batch._replace(
-        pos=unpack_dmajor_batch(pos, s_cnt, d),
-        vel=unpack_dmajor_batch(vel, s_cnt, d),
+        pos=unpack_dmajor_batch(pos, d),
+        vel=unpack_dmajor_batch(vel, d),
         fit=pbf,  # kernels do not retain raw fit; pbest_fit >= fit
-        pbest_pos=unpack_dmajor_batch(pbp, s_cnt, d), pbest_fit=pbf,
-        gbest_pos=gp[:d].T, gbest_fit=gf,
+        pbest_pos=unpack_dmajor_batch(pbp, d), pbest_fit=pbf,
+        gbest_pos=unpack_columns(gp, d), gbest_fit=gf,
         iteration=batch.iteration + iters,
         lbest_pos=None, lbest_fit=None)
     if telemetry:
@@ -269,11 +274,10 @@ def _async_spans(iters: int, sync_every: int):
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "iters", "sync_every", "block_n",
-                                    "interpret", "telemetry"))
+                                    "telemetry"))
 def run_queue_lock_fused_async(cfg: PSOConfig, s: SwarmState, iters: int,
                                sync_every: int = ASYNC_SYNC_EVERY,
                                block_n: Optional[int] = None,
-                               interpret: bool = True,
                                telemetry: bool = False):
     """``iters`` iterations of the ASYNC queue-lock in one pallas_call.
 
@@ -301,16 +305,16 @@ def run_queue_lock_fused_async(cfg: PSOConfig, s: SwarmState, iters: int,
     if s.lbest_fit is not None and s.lbest_fit.shape == (nb,):
         # resume the externalized block-local bests (checkpoint/resume
         # keeps the staleness window instead of restarting it)
-        lp = pack_dmajor(s.lbest_pos, d)
+        lp = pack_columns(s.lbest_pos, d)
         lf = s.lbest_fit
     else:
-        lp = jnp.tile(gp, (1, nb))             # local bests seeded from gbest
+        lp = jnp.tile(gp[None], (nb, 1, 1))    # local bests seeded from gbest
         lf = jnp.tile(gf, nb)
     cnt = jnp.zeros((3,), jnp.int32) if telemetry else None
     for off, span, chunk in _async_spans(iters, sync_every):
         call = fused_async_call(n, d, span, bn, chunk, s.pos.dtype,
-                                topology=cfg.topology, interpret=interpret,
-                                telemetry=telemetry, **_cfg_kwargs(cfg))
+                                topology=cfg.topology, telemetry=telemetry,
+                                **_cfg_kwargs(cfg))
         args = (scal + jnp.array([0, off], jnp.int32),
                 pos, vel, pbp, pbf, gp, gf, lp, lf)
         if telemetry:
@@ -318,7 +322,7 @@ def run_queue_lock_fused_async(cfg: PSOConfig, s: SwarmState, iters: int,
         else:
             pos, vel, pbp, pbf, gp, gf, lp, lf = call(*args)
     out = kernel_to_state(s, d, pos, vel, pbp, pbf, gp, gf, iters)
-    out = out._replace(lbest_pos=unpack_dmajor(lp, d), lbest_fit=lf)
+    out = out._replace(lbest_pos=unpack_columns(lp, d), lbest_fit=lf)
     if telemetry:
         return out, cnt
     return out
@@ -326,13 +330,13 @@ def run_queue_lock_fused_async(cfg: PSOConfig, s: SwarmState, iters: int,
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "iters", "sync_every", "block_n",
-                                    "interpret", "table", "telemetry"))
+                                    "table", "telemetry"))
 def run_queue_lock_fused_async_batch(cfg: PSOConfig, batch: SwarmBatch,
                                      iters: int,
                                      sync_every: int = ASYNC_SYNC_EVERY,
                                      block_n: Optional[int] = None,
-                                     interpret: bool = True, fids=None,
-                                     table=None, telemetry: bool = False):
+                                     fids=None, table=None,
+                                     telemetry: bool = False):
     """S independent swarms through the async queue-lock in one pallas_call.
 
     Grid ``(swarms, blocks, iter_chunks)``: per-swarm gbest buffers and
@@ -352,15 +356,14 @@ def run_queue_lock_fused_async_batch(cfg: PSOConfig, batch: SwarmBatch,
     pos = pack_dmajor_batch(batch.pos, d)
     vel = pack_dmajor_batch(batch.vel, d)
     pbp = pack_dmajor_batch(batch.pbest_pos, d)
-    pbf = batch.pbest_fit.reshape(1, s_cnt * n)
-    gp = jnp.zeros((pad_dim(d), s_cnt), batch.pos.dtype).at[:d].set(
-        batch.gbest_pos.T)
+    pbf = batch.pbest_fit.reshape(s_cnt, 1, n)
+    gp = pack_columns(batch.gbest_pos, d)
     gf = batch.gbest_fit
     if batch.lbest_fit is not None and batch.lbest_fit.shape == (s_cnt, nb):
-        lp = pack_dmajor(batch.lbest_pos.reshape(s_cnt * nb, d), d)
+        lp = pack_columns(batch.lbest_pos.reshape(s_cnt * nb, d), d)
         lf = batch.lbest_fit.reshape(s_cnt * nb)
     else:
-        lp = jnp.repeat(gp, nb, axis=1)        # [Dpad, S*nb], swarm-major
+        lp = jnp.repeat(gp, nb, axis=0)        # [S*nb, Dpad, 1], swarm-major
         lf = jnp.repeat(gf, nb)
     cnt = jnp.zeros((3 * s_cnt,), jnp.int32) if telemetry else None
     for off, span, chunk in _async_spans(iters, sync_every):
@@ -368,7 +371,6 @@ def run_queue_lock_fused_async_batch(cfg: PSOConfig, batch: SwarmBatch,
             call = fused_async_batch_call(s_cnt, n, d, span, bn, chunk,
                                           batch.pos.dtype,
                                           topology=cfg.topology,
-                                          interpret=interpret,
                                           telemetry=telemetry,
                                           **_cfg_kwargs(cfg))
             args = (seeds, its + jnp.int32(off), pos, vel, pbp, pbf, gp,
@@ -379,7 +381,7 @@ def run_queue_lock_fused_async_batch(cfg: PSOConfig, batch: SwarmBatch,
                 s_cnt, n, d, span, bn, chunk, batch.pos.dtype, w=rcfg.w,
                 c1=rcfg.c1, c2=rcfg.c2, members=_hetero_members(cfg, table),
                 rule=rcfg.update_rule, topology=cfg.topology,
-                interpret=interpret, telemetry=telemetry)
+                telemetry=telemetry)
             args = (seeds, its + jnp.int32(off), fids.astype(jnp.int32),
                     pos, vel, pbp, pbf, gp, gf, lp, lf)
         if telemetry:
@@ -388,23 +390,21 @@ def run_queue_lock_fused_async_batch(cfg: PSOConfig, batch: SwarmBatch,
             pos, vel, pbp, pbf, gp, gf, lp, lf = call(*args)
     pbf = pbf.reshape(s_cnt, n)
     out = batch._replace(
-        pos=unpack_dmajor_batch(pos, s_cnt, d),
-        vel=unpack_dmajor_batch(vel, s_cnt, d),
+        pos=unpack_dmajor_batch(pos, d),
+        vel=unpack_dmajor_batch(vel, d),
         fit=pbf,  # kernels do not retain raw fit; pbest_fit >= fit
-        pbest_pos=unpack_dmajor_batch(pbp, s_cnt, d), pbest_fit=pbf,
-        gbest_pos=gp[:d].T, gbest_fit=gf,
+        pbest_pos=unpack_dmajor_batch(pbp, d), pbest_fit=pbf,
+        gbest_pos=unpack_columns(gp, d), gbest_fit=gf,
         iteration=batch.iteration + iters,
-        lbest_pos=unpack_dmajor(lp, d).reshape(s_cnt, nb, d),
+        lbest_pos=unpack_columns(lp, d).reshape(s_cnt, nb, d),
         lbest_fit=lf.reshape(s_cnt, nb))
     if telemetry:
         return out, cnt.reshape(s_cnt, 3)
     return out
 
 
-def make_fused_local_step(iters_per_call: int = 1, block_n=None,
-                          interpret: bool = True):
+def make_fused_local_step(iters_per_call: int = 1, block_n=None):
     """Adapter: fused kernel as a ``local_step_fn`` for distributed swarms."""
     def step(cfg: PSOConfig, s: SwarmState) -> SwarmState:
-        return run_queue_lock_fused(cfg, s, iters_per_call,
-                                    block_n=block_n, interpret=interpret)
+        return run_queue_lock_fused(cfg, s, iters_per_call, block_n=block_n)
     return step

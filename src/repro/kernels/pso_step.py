@@ -60,9 +60,9 @@ The seven pallas_call builders:
 
 ``fused_batch`` (queue-lock, grid = (swarms, iterations, particle blocks))
     The multi-swarm extension of ``fused``: one ``pallas_call`` advances S
-    *independent* swarms x iters. State is packed ``[Dpad, S*N]`` (swarm s
-    owns columns [s*N, (s+1)*N)); each swarm has its own gbest column in a
-    ``[Dpad, S]`` buffer, its own SMEM gbest_fit slot, and its own
+    *independent* swarms x iters. State is packed ``[S, Dpad, N]``; each
+    swarm has its own gbest column in a ``[S, Dpad, 1]`` buffer, its own
+    SMEM gbest_fit slot, and its own
     ``(seed, iteration)`` RNG counters, so swarm s is bit-identical to a
     standalone ``fused`` run with the same seed and block size. The grid is
     swarm-major: a swarm's gbest buffers stay resident across all its
@@ -89,7 +89,7 @@ The seven pallas_call builders:
     tile fetched/flushed once for its entire iteration span, not per
     iteration) and runs ``sync_every`` iterations per grid step against a
     *block-local* best carried in the fori-loop registers and persisted in
-    small ``[Dpad, nb]``/SMEM ``[nb]`` side buffers. The shared ``[Dpad,1]``
+    small ``[nb, Dpad, 1]``/SMEM ``[nb]`` side buffers. The shared ``[Dpad,1]``
     + SMEM gbest is touched only at chunk boundaries: a pull (read) at chunk
     entry and a predicated publish (write) at chunk exit — the lock
     acquisition shrinks from every (iteration x block) to every
@@ -133,9 +133,18 @@ violation, ``repro.core.constraints.deb_improved``) via the d-major
 ``repro.core.pso.deb_selection_fn``; ``penalty`` mode and unconstrained
 problems keep the raw ``fit > pbest`` fold bit-for-bit.
 
-Validated in ``interpret=True`` mode against ``ref.py`` (same counter RNG ⇒
+Validated in interpret mode against ``ref.py`` (same counter RNG ⇒
 bit-exact trajectories) over shape/dtype sweeps in tests/test_kernels.py
 and tests/test_async.py; custom-objective parity in tests/test_problem.py.
+tests/test_tpu_compile.py compiles every builder but ``queue_step_call``
+with Mosaic for a described TPU v5e.
+
+Mosaic layout rule: a VMEM block's last two dims must each equal the
+array's or be a multiple of the (8, 128) tile. So per-swarm and per-block
+column buffers are rank 3 with the column index on the leading, untiled
+axis — batched ``gbest_pos`` is ``[S, Dpad, 1]`` and ``local_pos`` is
+``[S*nb, Dpad, 1]`` — and the batched state is ``[S, Dpad, N]``, so a block
+of a swarm with fewer than 128 particles still spans its whole lane axis.
 """
 from __future__ import annotations
 
@@ -157,10 +166,25 @@ from repro.core.problem import Problem
 from repro.core.topology import kernel_neighbor_ids
 from repro.core.update_rules import resolve_rule
 
-from .compat import CompilerParams as _CompilerParams
-
 SUBLANE = 8
 _BIG_I32 = np.int32(2 ** 30)
+
+
+def default_interpret() -> bool:
+    """Pallas interpret mode, decided once from the platform: the kernels
+    compile with Mosaic exactly when JAX's default backend is a TPU, and run
+    in the interpreter everywhere else. Every builder below resolves
+    ``interpret=None`` through here; no layer above them chooses."""
+    return jax.default_backend() != "tpu"
+
+
+def _interpret(interpret) -> bool:
+    return default_interpret() if interpret is None else interpret
+
+
+def _arbitrary(ndim: int):
+    return pltpu.CompilerParams(
+        dimension_semantics=(pltpu.ARBITRARY,) * ndim)
 
 
 def pad_dim(d: int) -> int:
@@ -184,10 +208,13 @@ def _fitness_dmajor(name: str, pos, dmask, d_real: int):
         s = jnp.sum(jnp.where(dmask, v, zero), axis=0, keepdims=True)
         return -(10.0 * d_real + s)
     if name == "griewank":
-        dsub = lax.broadcasted_iota(jnp.float32, pos.shape, 0) + 1.0
+        dsub = lax.broadcasted_iota(jnp.int32, pos.shape, 0).astype(pos.dtype) + 1.0
         s = jnp.sum(jnp.where(dmask, pos * pos, zero), axis=0, keepdims=True) / 4000.0
         c = jnp.cos(pos / jnp.sqrt(dsub))
-        p = jnp.prod(jnp.where(dmask, c, jnp.ones_like(c)), axis=0, keepdims=True)
+        # Mosaic has no reduce_prod: multiply the real sublanes row by row.
+        p = c[0:1]
+        for i in range(1, d_real):
+            p = p * c[i:i + 1]
         return -(s - p + 1.0)
     if name == "ackley":
         s1 = jnp.sqrt(jnp.sum(jnp.where(dmask, pos * pos, zero), axis=0,
@@ -305,6 +332,16 @@ def kernel_projection(fitness):
     return lifted
 
 
+def tpu_lowerable(fitness) -> bool:
+    """False when the kernels cannot compile ``fitness`` for a TPU: a
+    projection-mode constraint set projects inside the advance
+    (``kernel_projection``), its operators sort along the dimension axis
+    (``project_simplex``), and Mosaic has no lowering for sort. Such
+    problems run on the jnp engines (``repro.api.Method.resolve_backend``);
+    the interpreter still runs their kernels for the oracle tests."""
+    return kernel_projection(fitness) is None
+
+
 def kernel_violation(fitness):
     """Resolve a Problem's aggregate constraint violation to the d-major
     tile form ``(pos [Dpad, bn], d_real) -> viol [1, bn]``, or None when
@@ -390,13 +427,15 @@ class _Slot:
 
 
 def lower_statics(fitness, *, d, dpad, bn, dtype,
-                  min_pos, max_pos, max_v):
+                  min_pos, max_pos, max_v, interpret=True):
     """Lower (fitness, bounds) statics to (statics dict, const arrays).
 
     ``consts`` must be appended, in order, to the pallas_call operands
     (specs from ``_const_specs``). ``statics`` entries are Python scalars,
     ``_Slot`` markers pointing into the const values, and the fitness
     callable (plus its own const slots when closure conversion ran).
+    ``interpret`` is the builder's mode: only the interpreter pins the
+    advance outputs (see ``_resolve_statics``).
     """
     consts = []
 
@@ -471,6 +510,8 @@ def lower_statics(fitness, *, d, dpad, bn, dtype,
         st["viol_slots"] = tuple(slot(jnp.asarray(c))
                                  for c in vclosed.consts)
     st["n_consts"] = len(consts)
+    st["pin"] = interpret and (st["fit_slots"] is not None
+                               or st["proj"] is not None)
     return st, tuple(consts)
 
 
@@ -479,15 +520,17 @@ def _resolve_statics(st, const_vals):
     (min_pos, max_pos, max_v, fitfn, proj, viol, pin) with
     fitfn(pos, dmask, d_real), proj(pos) and viol(pos) (each or None).
 
-    ``pin`` is True for converted (non-hand-tuned) objectives and whenever
-    a feasibility projection is present: the kernel body must pass the
-    advance outputs through ``_pin`` before storing or evaluating fitness.
-    Without it, XLA:CPU fuses the user objective into the velocity/position
-    chain and re-derives a differently-rounded ``pos`` per consumer,
-    drifting 1 ulp from the eager ``ref.py`` oracles and breaking the
-    bit-exact validation contract. The barrier is a no-op eagerly and is
-    skipped entirely for the hand-tuned built-in forms, whose jaxprs (and
-    compiled bits) stay exactly the seed kernels'.
+    ``pin`` is True in interpret mode for converted (non-hand-tuned)
+    objectives and whenever a feasibility projection is present: the kernel
+    body must pass the advance outputs through ``_pin`` before storing or
+    evaluating fitness. Without it, XLA:CPU fuses the user objective into
+    the velocity/position chain and re-derives a differently-rounded
+    ``pos`` per consumer, drifting 1 ulp from the eager ``ref.py`` oracles
+    and breaking the bit-exact validation contract. The barrier is a no-op
+    eagerly and is skipped entirely for the hand-tuned built-in forms,
+    whose jaxprs (and compiled bits) stay exactly the seed kernels'. Mosaic
+    has no lowering for the barrier and compiles the body as written, so a
+    kernel built for the chip never pins.
     """
     def get(v):
         return const_vals[v.index] if isinstance(v, _Slot) else v
@@ -521,7 +564,7 @@ def _resolve_statics(st, const_vals):
             return _pure(pos, *_extra)
 
     return (get(st["min_pos"]), get(st["max_pos"]), get(st["max_v"]), fit,
-            proj, viol, st["fit_slots"] is not None or proj is not None)
+            proj, viol, st["pin"])
 
 
 def _pin(pin, pos, vel):
@@ -629,6 +672,47 @@ def _gather_winner(pos, dmask, lane, bidx):
                    axis=1, keepdims=True)
 
 
+def _copy_smem(dst, src, n: int):
+    """Copy the first ``n`` slots of an SMEM ref (seeding a resident
+    output from its aliased input)."""
+    def body(i, carry):
+        dst[i] = src[i]
+        return carry
+    lax.fori_loop(0, n, body, 0)
+
+
+def _block_copy(srcs, dsts, sem):
+    """Copy each ``srcs[i]`` into ``dsts[i]`` (HBM <-> VMEM) and wait."""
+    copies = [pltpu.make_async_copy(a, b, sem.at[i])
+              for i, (a, b) in enumerate(zip(srcs, dsts))]
+    for c in copies:
+        c.start()
+    for c in copies:
+        c.wait()
+
+
+def _sync_state_specs(dpad, block_n, nb, dtype, batched):
+    """(specs of pos/vel/pbest_pos/pbest_fit, scratch, dma) for the
+    synchronous kernels: HBM refs plus VMEM block scratch and DMA
+    semaphores when a swarm spans several blocks, else one resident window
+    per swarm (see ``_make_sync_kernel``)."""
+    if nb > 1:
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = [pltpu.VMEM((dpad, block_n), dtype),
+                   pltpu.VMEM((dpad, block_n), dtype),
+                   pltpu.VMEM((dpad, block_n), dtype),
+                   pltpu.VMEM((1, block_n), dtype),
+                   pltpu.SemaphoreType.DMA((4,))]
+        return [hbm] * 4, scratch, True
+    if batched:
+        mat = pl.BlockSpec((None, dpad, block_n), lambda s, *_: (s, 0, 0))
+        row = pl.BlockSpec((None, 1, block_n), lambda s, *_: (s, 0, 0))
+    else:
+        mat = pl.BlockSpec((dpad, block_n), lambda *_: (0, 0))
+        row = pl.BlockSpec((1, block_n), lambda *_: (0, 0))
+    return [mat, mat, mat, row], [], False
+
+
 # --------------------------------------------------------------------------
 # THE synchronous scaffold: one generator, four kernel bodies.
 # --------------------------------------------------------------------------
@@ -663,54 +747,65 @@ def _make_sync_kernel(*, queue=False, batched=False, hetero=False,
     ``repro.telemetry.counters``) and accumulates into it per grid step.
     The gate is Python-level, so a telemetry-off body traces to exactly
     the pre-telemetry jaxpr (the bit-identity pins never see it).
+
+    State traffic (``dma``, chosen by the builders from the block count):
+    with several blocks per swarm the particle state stays in HBM
+    (``pl.ANY``) and each grid step copies its block into VMEM scratch and
+    back, waiting on both copies — a block is revisited every iteration,
+    and automatic pipelining would prefetch that revisit from HBM before
+    the previous write-back of the same block had landed. With one block
+    per swarm the block is a resident output window instead, seeded from
+    its input at the swarm's first iteration (a DMA slice of a swarm
+    narrower than one 128-lane tile would be refused). The gbest buffers
+    and counters are resident outputs too, seeded on first visit: Mosaic
+    does not load an output block from HBM, aliased or not.
     """
     if queue and telemetry:
         raise ValueError("the two-kernel queue variant publishes via the "
                          "jnp epilogue; count there, not in-kernel")
 
-    def kernel(*refs, w, c1, c2, d_real, rule, statics):
-        # --- scalar prefix / aliased-input placeholders / const + out refs
+    def kernel(*refs, w, c1, c2, d_real, rule, statics, dma):
+        # --- operands: scalar prefix, aliased state inputs, consts,
+        # counts input, outputs, then the VMEM block scratch + semaphores
+        npre = 3 if (queue or hetero) else 2 if batched else 1
+        nstate = 4 if queue else 6
+        ins = refs[npre:npre + nstate]
+        k = npre + nstate
+        nc = 0 if hetero else statics["n_consts"]
+        const_vals = tuple(r[...] for r in refs[k:k + nc])
+        k += nc
+        cnt_in = refs[k] if telemetry else None
+        k += int(telemetry)
+        if dma:
+            outs = refs[k:-5]
+            pos_ref, vel_ref, pbp_ref, pbf_ref, sem = refs[-5:]
+        else:
+            outs = refs[k:]
+            pos_ref, vel_ref, pbp_ref, pbf_ref = outs[:4]
         if queue:
-            scal_ref, gp_in_ref, gf_in_ref = refs[:3]
-            rest = refs[3 + 4:]              # 4 aliased state inputs
-        elif hetero:
+            scal_ref, gp_ref, gf_in_ref = refs[:3]
+            aux_fit_ref, aux_idx_ref = outs[4:6]
+        else:
+            gp_ref, gf_ref = outs[4:6]
+            gp_in, gf_in = ins[4:6]
+            cnt_ref = outs[6] if telemetry else None
+        if hetero:
             seeds_ref, its_ref, fids_ref = refs[:3]
-            rest = refs[3 + 6:]
+            branches = statics
         elif batched:
             seeds_ref, its_ref = refs[:2]
-            rest = refs[2 + 6:]
-        else:
+        elif not queue:
             scal_ref = refs[0]
-            rest = refs[1 + 6:]
-        if hetero:
-            branches = statics
-            if telemetry:
-                # rest[0] is the aliased counts-input placeholder
-                (pos_ref, vel_ref, pbp_ref, pbf_ref, gp_ref, gf_ref,
-                 cnt_ref) = rest[1:]
-            else:
-                pos_ref, vel_ref, pbp_ref, pbf_ref, gp_ref, gf_ref = rest
-        else:
-            nc = statics["n_consts"]
-            const_vals = tuple(r[...] for r in rest[:nc])
-            if queue:
-                (pos_ref, vel_ref, pbp_ref, pbf_ref,
-                 aux_fit_ref, aux_idx_ref) = rest[nc:]
-            elif telemetry:
-                # rest[nc] is the aliased counts-input placeholder
-                (pos_ref, vel_ref, pbp_ref, pbf_ref,
-                 gp_ref, gf_ref, cnt_ref) = rest[nc + 1:]
-            else:
-                (pos_ref, vel_ref, pbp_ref, pbf_ref,
-                 gp_ref, gf_ref) = rest[nc:]
+        if not hetero:
             min_pos, max_pos, max_v, fitness, proj, viol, pin = \
                 _resolve_statics(statics, const_vals)
         # --- grid coordinates and RNG counters
         if batched or hetero:
             s = pl.program_id(0)
+            t = pl.program_id(1)
             b = pl.program_id(2)
             seed = seeds_ref[s]
-            it = its_ref[s] + pl.program_id(1) + 1
+            it = its_ref[s] + t + 1
             slot = s
         elif queue:
             b = pl.program_id(0)
@@ -718,13 +813,42 @@ def _make_sync_kernel(*, queue=False, batched=False, hetero=False,
             it = scal_ref[1] + 1
             slot = 0
         else:
+            t = pl.program_id(0)
             b = pl.program_id(1)
             seed = scal_ref[0]
-            it = scal_ref[1] + pl.program_id(0) + 1
+            it = scal_ref[1] + t + 1
             slot = 0
         bn = pos_ref.shape[1]
         base = b * bn      # block base LOCAL to the swarm: RNG indices
                            # match a standalone swarm bit-for-bit
+        if not queue:
+            first = (t == 0) & (b == 0)     # this swarm's first grid step
+
+            @pl.when(first)
+            def _seed_gbest_column():
+                gp_ref[...] = gp_in[...]
+
+            nslots = gf_in.shape[0]
+
+            @pl.when(first & (slot == 0))   # the kernel's first grid step
+            def _seed_smem():
+                _copy_smem(gf_ref, gf_in, nslots)
+                if telemetry:
+                    _copy_smem(cnt_ref, cnt_in, 3 * nslots)
+
+        vmem = (pos_ref, vel_ref, pbp_ref, pbf_ref)
+        if dma:        # outs[:4] are the aliased HBM state, updated in place
+            views = [h.at[s] if (batched or hetero) else h for h in outs[:4]]
+            views = [v.at[:, pl.ds(base, bn)] for v in views]
+            _block_copy(views, vmem, sem)
+        elif queue:
+            for o, i in zip(vmem, ins[:4]):
+                o[...] = i[...]
+        else:
+            @pl.when(t == 0)
+            def _seed_state():
+                for o, i in zip(vmem, ins[:4]):
+                    o[...] = i[...]
         # --- advance + objective
         if hetero:
             def mk(st):
@@ -752,10 +876,9 @@ def _make_sync_kernel(*, queue=False, batched=False, hetero=False,
             lane = lax.broadcasted_iota(jnp.int32, (dpad, bn), 1)
             viol = None
         else:
-            gp_src = gp_in_ref if queue else gp_ref
             pos, vel, dmask, lane = _advance_block(
                 seed, it, pos_ref[...], vel_ref[...], pbp_ref[...],
-                gp_src[...], base, w=w, c1=c1, c2=c2, min_pos=min_pos,
+                gp_ref[...], base, w=w, c1=c1, c2=c2, min_pos=min_pos,
                 max_pos=max_pos, max_v=max_v, d_real=d_real, project=proj,
                 rule=rule)
             pos, vel = _pin(pin, pos, vel)
@@ -764,6 +887,8 @@ def _make_sync_kernel(*, queue=False, batched=False, hetero=False,
         imp = _fold_pbest(fit, pos, pbf_ref, pbp_ref, viol)
         pos_ref[...] = pos
         vel_ref[...] = vel
+        if dma:
+            _block_copy(vmem, views, sem)
         # --- publication
         if queue:
             # Candidates are lanes improving on the (stale) global best;
@@ -821,7 +946,7 @@ _hetero_fused_batch_kernel_tel = _make_sync_kernel(batched=True, hetero=True,
 
 def queue_step_call(n: int, d: int, block_n: int, dtype, *,
                     w, c1, c2, min_pos, max_pos, max_v, fitness,
-                    rule="pso", interpret=True):
+                    rule="pso", interpret=None):
     """Build the pallas_call for one queue iteration.
 
     Args (runtime): scal[2]i32, gbest_pos[Dpad,1], gbest_fit[1],
@@ -829,15 +954,17 @@ def queue_step_call(n: int, d: int, block_n: int, dtype, *,
     Returns: (pos, vel, pbest_pos, pbest_fit, aux_fit[nb], aux_idx[nb])
     """
     assert n % block_n == 0, (n, block_n)
+    interpret = _interpret(interpret)
     nb = n // block_n
     dpad = pad_dim(d)
     st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=block_n,
                                dtype=dtype, min_pos=min_pos,
-                               max_pos=max_pos, max_v=max_v)
+                               max_pos=max_pos, max_v=max_v,
+                               interpret=interpret)
     kern = functools.partial(_queue_kernel, w=w, c1=c1, c2=c2, d_real=d,
                              rule=_kernel_rule(rule), statics=st)
-    mat = pl.BlockSpec((dpad, block_n), lambda b: (0, b))
-    row = pl.BlockSpec((1, block_n), lambda b: (0, b))
+    state, scratch, dma = _sync_state_specs(dpad, block_n, nb, dtype, False)
+    kern = functools.partial(kern, dma=dma)
     call = pl.pallas_call(
         kern,
         grid=(nb,),
@@ -845,10 +972,8 @@ def queue_step_call(n: int, d: int, block_n: int, dtype, *,
             pl.BlockSpec(memory_space=pltpu.SMEM),            # scal
             pl.BlockSpec((dpad, 1), lambda b: (0, 0)),        # gbest_pos
             pl.BlockSpec(memory_space=pltpu.SMEM),            # gbest_fit
-            mat, mat, mat, row,                               # pos vel pbp pbf
-        ] + _const_specs(consts),
-        out_specs=[
-            mat, mat, mat, row,
+        ] + state + _const_specs(consts),
+        out_specs=state + [
             pl.BlockSpec((1,), lambda b: (b,), memory_space=pltpu.SMEM),
             pl.BlockSpec((1,), lambda b: (b,), memory_space=pltpu.SMEM),
         ],
@@ -861,6 +986,7 @@ def queue_step_call(n: int, d: int, block_n: int, dtype, *,
             jax.ShapeDtypeStruct((nb,), jnp.int32),           # aux_idx
         ],
         input_output_aliases={3: 0, 4: 1, 5: 2, 6: 3},
+        scratch_shapes=scratch,
         interpret=interpret,
         name="cupso_queue_step",
     )
@@ -873,7 +999,7 @@ def queue_step_call(n: int, d: int, block_n: int, dtype, *,
 
 def fused_call(n: int, d: int, iters: int, block_n: int, dtype, *,
                w, c1, c2, min_pos, max_pos, max_v, fitness,
-               rule="pso", interpret=True, telemetry=False):
+               rule="pso", interpret=None, telemetry=False):
     """Build the fused multi-iteration queue-lock pallas_call.
 
     Args (runtime): scal[2]i32, pos/vel/pbest_pos [Dpad,N], pbest_fit [1,N],
@@ -883,21 +1009,23 @@ def fused_call(n: int, d: int, iters: int, block_n: int, dtype, *,
     result) accumulating the contention counters — see repro.telemetry.
     """
     assert n % block_n == 0, (n, block_n)
+    interpret = _interpret(interpret)
     nb = n // block_n
     dpad = pad_dim(d)
     st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=block_n,
                                dtype=dtype, min_pos=min_pos,
-                               max_pos=max_pos, max_v=max_v)
+                               max_pos=max_pos, max_v=max_v,
+                               interpret=interpret)
     body = _fused_kernel_tel if telemetry else _fused_kernel
     kern = functools.partial(body, w=w, c1=c1, c2=c2, d_real=d,
                              rule=_kernel_rule(rule), statics=st)
-    mat = pl.BlockSpec((dpad, block_n), lambda t, b: (0, b))
-    row = pl.BlockSpec((1, block_n), lambda t, b: (0, b))
+    state, scratch, dma = _sync_state_specs(dpad, block_n, nb, dtype, False)
+    kern = functools.partial(kern, dma=dma)
     gpc = pl.BlockSpec((dpad, 1), lambda t, b: (0, 0))
     gfs = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM),        # scal
-                mat, mat, mat, row, gpc, gfs] + _const_specs(consts)
-    out_specs = [mat, mat, mat, row, gpc, gfs]
+                *state, gpc, gfs] + _const_specs(consts)
+    out_specs = [*state, gpc, gfs]
     out_shape = [
         jax.ShapeDtypeStruct((dpad, n), dtype),               # pos
         jax.ShapeDtypeStruct((dpad, n), dtype),               # vel
@@ -919,8 +1047,8 @@ def fused_call(n: int, d: int, iters: int, block_n: int, dtype, *,
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=_CompilerParams(
-            dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY)),
+        scratch_shapes=scratch,
+        compiler_params=_arbitrary(2),
         interpret=interpret,
         name="cupso_fused_queue_lock",
     )
@@ -937,12 +1065,12 @@ def fused_call(n: int, d: int, iters: int, block_n: int, dtype, *,
 
 def fused_batch_call(s_cnt: int, n: int, d: int, iters: int, block_n: int,
                      dtype, *, w, c1, c2, min_pos, max_pos, max_v, fitness,
-                     rule="pso", interpret=True, telemetry=False):
+                     rule="pso", interpret=None, telemetry=False):
     """Build the batched fused queue-lock pallas_call (S swarms x iters).
 
     Args (runtime): seeds[S]i32, iterations[S]i32,
-                    pos/vel/pbest_pos [Dpad, S*N], pbest_fit [1, S*N],
-                    gbest_pos [Dpad, S], gbest_fit [S]
+                    pos/vel/pbest_pos [S, Dpad, N], pbest_fit [S, 1, N],
+                    gbest_pos [S, Dpad, 1], gbest_fit [S]
     Returns the same six state arrays after ``iters`` iterations of every
     swarm. Swarm-major grid: the per-swarm gbest column and SMEM fitness
     slot are revisited only within one swarm's iteration span.
@@ -950,27 +1078,29 @@ def fused_batch_call(s_cnt: int, n: int, d: int, iters: int, block_n: int,
     contention counters — see repro.telemetry).
     """
     assert n % block_n == 0, (n, block_n)
+    interpret = _interpret(interpret)
     nb = n // block_n
     dpad = pad_dim(d)
     st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=block_n,
                                dtype=dtype, min_pos=min_pos,
-                               max_pos=max_pos, max_v=max_v)
+                               max_pos=max_pos, max_v=max_v,
+                               interpret=interpret)
     body = _fused_batch_kernel_tel if telemetry else _fused_batch_kernel
     kern = functools.partial(body, w=w, c1=c1, c2=c2,
                              d_real=d, rule=_kernel_rule(rule), statics=st)
-    mat = pl.BlockSpec((dpad, block_n), lambda s, t, b: (0, s * nb + b))
-    row = pl.BlockSpec((1, block_n), lambda s, t, b: (0, s * nb + b))
-    gpc = pl.BlockSpec((dpad, 1), lambda s, t, b: (0, s))
+    state, scratch, dma = _sync_state_specs(dpad, block_n, nb, dtype, True)
+    kern = functools.partial(kern, dma=dma)
+    gpc = pl.BlockSpec((None, dpad, 1), lambda s, t, b: (s, 0, 0))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [smem, smem,                                   # seeds, iters
-                mat, mat, mat, row, gpc, smem] + _const_specs(consts)
-    out_specs = [mat, mat, mat, row, gpc, smem]
+                *state, gpc, smem] + _const_specs(consts)
+    out_specs = [*state, gpc, smem]
     out_shape = [
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # pos
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # vel
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # pbest_pos
-        jax.ShapeDtypeStruct((1, s_cnt * n), dtype),          # pbest_fit
-        jax.ShapeDtypeStruct((dpad, s_cnt), dtype),           # gbest_pos
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # pos
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # vel
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # pbest_pos
+        jax.ShapeDtypeStruct((s_cnt, 1, n), dtype),           # pbest_fit
+        jax.ShapeDtypeStruct((s_cnt, dpad, 1), dtype),        # gbest_pos
         jax.ShapeDtypeStruct((s_cnt,), dtype),                # gbest_fit
     ]
     aliases = {2: 0, 3: 1, 4: 2, 5: 3, 6: 4, 7: 5}
@@ -986,9 +1116,8 @@ def fused_batch_call(s_cnt: int, n: int, d: int, iters: int, block_n: int,
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=_CompilerParams(
-            dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY,
-                                 pltpu.ARBITRARY)),
+        scratch_shapes=scratch,
+        compiler_params=_arbitrary(3),
         interpret=interpret,
         name="cupso_fused_queue_lock_batch",
     )
@@ -1013,7 +1142,7 @@ def fused_batch_call(s_cnt: int, n: int, d: int, iters: int, block_n: int,
 # ``lower_statics`` consts would need per-branch operand plumbing).
 # --------------------------------------------------------------------------
 
-def _hetero_branches(members, *, d, dpad, bn, dtype):
+def _hetero_branches(members, *, d, dpad, bn, dtype, interpret):
     """Per-member kernel statics for a hetero dispatch table.
 
     ``members`` is a tuple of ``(fitness, min_pos, max_pos, max_v)``; each
@@ -1024,7 +1153,7 @@ def _hetero_branches(members, *, d, dpad, bn, dtype):
     for fitness, mn, mx, mv in members:
         st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=bn,
                                    dtype=dtype, min_pos=mn, max_pos=mx,
-                                   max_v=mv)
+                                   max_v=mv, interpret=interpret)
         if consts:
             raise ValueError(
                 "heterogeneous kernel dispatch requires const-free table "
@@ -1035,7 +1164,7 @@ def _hetero_branches(members, *, d, dpad, bn, dtype):
 
 def hetero_fused_batch_call(s_cnt: int, n: int, d: int, iters: int,
                             block_n: int, dtype, *, w, c1, c2, members,
-                            rule="pso", interpret=True, telemetry=False):
+                            rule="pso", interpret=None, telemetry=False):
     """Batched fused queue-lock with a per-swarm problem (kernel 3h).
 
     Args (runtime): seeds[S]i32, iterations[S]i32, fids[S]i32, then the six
@@ -1044,28 +1173,29 @@ def hetero_fused_batch_call(s_cnt: int, n: int, d: int, iters: int,
     to. ``telemetry`` appends an aliased counts[3*S]i32 operand.
     """
     assert n % block_n == 0, (n, block_n)
+    interpret = _interpret(interpret)
     nb = n // block_n
     dpad = pad_dim(d)
     branches = _hetero_branches(members, d=d, dpad=dpad, bn=block_n,
-                                dtype=dtype)
+                                dtype=dtype, interpret=interpret)
     body = (_hetero_fused_batch_kernel_tel if telemetry
             else _hetero_fused_batch_kernel)
     kern = functools.partial(body, w=w, c1=c1, c2=c2,
                              d_real=d, rule=_kernel_rule(rule),
                              statics=branches)
-    mat = pl.BlockSpec((dpad, block_n), lambda s, t, b: (0, s * nb + b))
-    row = pl.BlockSpec((1, block_n), lambda s, t, b: (0, s * nb + b))
-    gpc = pl.BlockSpec((dpad, 1), lambda s, t, b: (0, s))
+    state, scratch, dma = _sync_state_specs(dpad, block_n, nb, dtype, True)
+    kern = functools.partial(kern, dma=dma)
+    gpc = pl.BlockSpec((None, dpad, 1), lambda s, t, b: (s, 0, 0))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [smem, smem, smem,                      # seeds, iters, fids
-                mat, mat, mat, row, gpc, smem]
-    out_specs = [mat, mat, mat, row, gpc, smem]
+                *state, gpc, smem]
+    out_specs = [*state, gpc, smem]
     out_shape = [
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # pos
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # vel
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # pbest_pos
-        jax.ShapeDtypeStruct((1, s_cnt * n), dtype),          # pbest_fit
-        jax.ShapeDtypeStruct((dpad, s_cnt), dtype),           # gbest_pos
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # pos
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # vel
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # pbest_pos
+        jax.ShapeDtypeStruct((s_cnt, 1, n), dtype),           # pbest_fit
+        jax.ShapeDtypeStruct((s_cnt, dpad, 1), dtype),        # gbest_pos
         jax.ShapeDtypeStruct((s_cnt,), dtype),                # gbest_fit
     ]
     aliases = {3: 0, 4: 1, 5: 2, 6: 3, 7: 4, 8: 5}
@@ -1081,9 +1211,8 @@ def hetero_fused_batch_call(s_cnt: int, n: int, d: int, iters: int,
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=_CompilerParams(
-            dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY,
-                                 pltpu.ARBITRARY)),
+        scratch_shapes=scratch,
+        compiler_params=_arbitrary(3),
         interpret=interpret,
         name="cupso_hetero_fused_queue_lock_batch",
     )
@@ -1168,7 +1297,8 @@ def _make_async_kernel(*, batched=False, hetero=False, telemetry=False):
     paper's star, compiled exactly as before — while ``"ring"`` /
     ``"vonneumann"`` fold the NEIGHBOR blocks' local slots instead
     (``kernel_neighbor_ids``; ``lp_ref`` is whole-array blocked in this
-    mode so neighbor columns are addressable), so swarm knowledge diffuses
+    mode and neighbor columns are read by a dynamic index on its leading,
+    untiled axis — never along lanes), so swarm knowledge diffuses
     hop by hop while the shared gbest remains a monitoring/final-answer
     flush target only.
 
@@ -1179,35 +1309,27 @@ def _make_async_kernel(*, batched=False, hetero=False, telemetry=False):
     """
     def kernel(*refs, nb, sync_every, w, c1, c2, d_real, rule, topology,
                statics):
-        # --- scalar prefix / aliased-input placeholders / const + out refs
+        # --- operands: scalar prefix, aliased state inputs, consts,
+        # counts input, then the outputs
+        npre = 3 if hetero else 2 if batched else 1
+        ins = refs[npre:npre + 8]
+        k = npre + 8
+        nc = 0 if hetero else statics["n_consts"]
+        const_vals = tuple(r[...] for r in refs[k:k + nc])
+        k += nc
+        cnt_in = refs[k] if telemetry else None
+        k += int(telemetry)
+        (pos_ref, vel_ref, pbp_ref, pbf_ref, gp_ref, gf_ref,
+         lp_ref, lf_ref) = refs[k:k + 8]
+        cnt_ref = refs[k + 8] if telemetry else None
         if hetero:
             seeds_ref, its_ref, fids_ref = refs[:3]
-            rest = refs[3 + 8:]
+            branches = statics
         elif batched:
             seeds_ref, its_ref = refs[:2]
-            rest = refs[2 + 8:]
         else:
             scal_ref = refs[0]
-            rest = refs[1 + 8:]
-        if hetero:
-            branches = statics
-            if telemetry:
-                # rest[0] is the aliased counts-input placeholder
-                (pos_ref, vel_ref, pbp_ref, pbf_ref, gp_ref, gf_ref,
-                 lp_ref, lf_ref, cnt_ref) = rest[1:]
-            else:
-                (pos_ref, vel_ref, pbp_ref, pbf_ref, gp_ref, gf_ref,
-                 lp_ref, lf_ref) = rest
-        else:
-            nc = statics["n_consts"]
-            const_vals = tuple(r[...] for r in rest[:nc])
-            if telemetry:
-                # rest[nc] is the aliased counts-input placeholder
-                (pos_ref, vel_ref, pbp_ref, pbf_ref, gp_ref, gf_ref,
-                 lp_ref, lf_ref, cnt_ref) = rest[nc + 1:]
-            else:
-                (pos_ref, vel_ref, pbp_ref, pbf_ref, gp_ref, gf_ref,
-                 lp_ref, lf_ref) = rest[nc:]
+        if not hetero:
             min_pos, max_pos, max_v, fitness, proj, viol, pin = \
                 _resolve_statics(statics, const_vals)
         # --- grid coordinates, RNG counters, local/global slots
@@ -1228,6 +1350,14 @@ def _make_async_kernel(*, batched=False, hetero=False, telemetry=False):
             slot = b
         bn = pos_ref.shape[1]
         base = b * bn      # swarm-local: RNG matches a standalone run
+        # --- first visits: seed the resident outputs from their aliased
+        # inputs (Mosaic does not load an output block from HBM). A block's
+        # state stays resident for all its chunks, so it is read once.
+        _seed_async_outputs(ins, (pos_ref, vel_ref, pbp_ref, pbf_ref, gp_ref,
+                                  gf_ref, lp_ref, lf_ref),
+                            cnt_in, cnt_ref, b=b, c=c, slot=slot,
+                            swarm=gslot,
+                            lbest=topology != "gbest")
         # --- chunk entry: refresh the block-local best (the read half of
         # the paper's lock).
         lf = lf_ref[slot]
@@ -1244,13 +1374,13 @@ def _make_async_kernel(*, batched=False, hetero=False, telemetry=False):
             # lbest: fold the neighbor blocks' local slots instead — the
             # shared gbest is never read back, so swarm knowledge diffuses
             # hop by hop (classic lbest dynamics at block granularity).
-            lp = lp_ref[:, pl.ds(slot, 1)]
+            lp = lp_ref[slot]
             for nbr in kernel_neighbor_ids(b, nb, topology):
                 nslot = s * nb + nbr if (batched or hetero) else nbr
                 nf = lf_ref[nslot]
                 take = nf > lf
                 lf = jnp.where(take, nf, lf)
-                lp = jnp.where(take, lp_ref[:, pl.ds(nslot, 1)], lp)
+                lp = jnp.where(take, lp_ref[nslot], lp)
         # --- the resident chunk: sync_every iterations vs the local best
         if hetero:
             def mk(st):
@@ -1292,7 +1422,7 @@ def _make_async_kernel(*, batched=False, hetero=False, telemetry=False):
         if topology == "gbest":
             lp_ref[...] = lp
         else:
-            lp_ref[:, pl.ds(slot, 1)] = lp
+            lp_ref[slot] = lp
         lf_ref[slot] = lf
 
         # --- chunk boundary: the ONLY cross-block write, and only on the
@@ -1323,6 +1453,43 @@ def _make_async_kernel(*, batched=False, hetero=False, telemetry=False):
     return kernel
 
 
+def _seed_async_outputs(ins, outs, cnt_in, cnt_ref, *, b, c, slot, swarm,
+                        lbest):
+    """Seed the async kernels' resident outputs on first visit: the block's
+    state and local slot at its first chunk, the swarm's gbest column at
+    its first block, and the whole SMEM buffers (plus, under an lbest
+    topology, the whole local-best array) at the kernel's first step."""
+    (pos_in, vel_in, pbp_in, pbf_in, gp_in, gf_in, lp_in, lf_in) = ins
+    (pos_ref, vel_ref, pbp_ref, pbf_ref, gp_ref, gf_ref, lp_ref,
+     lf_ref) = outs
+    first_block_step = c == 0
+    first_swarm_step = first_block_step & (b == 0)
+
+    @pl.when(first_block_step)
+    def _state():
+        pos_ref[...] = pos_in[...]
+        vel_ref[...] = vel_in[...]
+        pbp_ref[...] = pbp_in[...]
+        pbf_ref[...] = pbf_in[...]
+        if not lbest:
+            lp_ref[...] = lp_in[...]
+            lf_ref[slot] = lf_in[slot]
+
+    @pl.when(first_swarm_step)
+    def _gbest_column():
+        gp_ref[...] = gp_in[...]
+
+    @pl.when(first_swarm_step & (swarm == 0))
+    def _whole():
+        nslots = gf_in.shape[0]
+        _copy_smem(gf_ref, gf_in, nslots)
+        if cnt_ref is not None:
+            _copy_smem(cnt_ref, cnt_in, 3 * nslots)
+        if lbest:
+            lp_ref[...] = lp_in[...]
+            _copy_smem(lf_ref, lf_in, lf_in.shape[0])
+
+
 # The three asynchronous kernel bodies: instantiations of the scaffold,
 # plus their telemetry (counter-carrying) twins.
 _fused_async_kernel = _make_async_kernel()
@@ -1337,24 +1504,24 @@ _hetero_fused_async_batch_kernel_tel = _make_async_kernel(
 
 
 def _async_local_spec(topology, dpad, nb_total, index_map_own):
-    """BlockSpec for the ``local_pos`` buffer: the block's own [Dpad, 1]
-    column under the star topology (the seed kernels' spec, untouched), or
-    the whole [Dpad, nb_total] array under an lbest topology so neighbor
-    columns are dynamically addressable."""
+    """BlockSpec for the ``[nb_total, Dpad, 1]`` ``local_pos`` buffer: the
+    block's own column (leading axis squeezed) under the star topology, or
+    the whole array under an lbest topology so neighbor columns are
+    addressable by a dynamic leading-axis index."""
     if topology == "gbest":
-        return pl.BlockSpec((dpad, 1), index_map_own)
-    return pl.BlockSpec((dpad, nb_total), lambda *g: (0, 0))
+        return pl.BlockSpec((None, dpad, 1), index_map_own)
+    return pl.BlockSpec((nb_total, dpad, 1), lambda *g: (0, 0, 0))
 
 
 def fused_async_call(n: int, d: int, iters: int, block_n: int,
                      sync_every: int, dtype, *, w, c1, c2, min_pos, max_pos,
                      max_v, fitness, rule="pso", topology="gbest",
-                     interpret=True, telemetry=False):
+                     interpret=None, telemetry=False):
     """Build the asynchronous queue-lock pallas_call (grid (blocks, chunks)).
 
     Args (runtime): scal[2]i32, pos/vel/pbest_pos [Dpad,N], pbest_fit [1,N],
                     gbest_pos [Dpad,1], gbest_fit [1],
-                    local_pos [Dpad,nb], local_fit [nb]
+                    local_pos [nb,Dpad,1], local_fit [nb]
     Returns the same eight state arrays after ``iters`` iterations. The
     caller seeds local_pos/local_fit from the shared gbest (one column/slot
     per block); ``iters`` must be a multiple of ``sync_every`` (the ops
@@ -1362,13 +1529,15 @@ def fused_async_call(n: int, d: int, iters: int, block_n: int,
     an aliased counts[3]i32 operand — see repro.telemetry.
     """
     assert n % block_n == 0, (n, block_n)
+    interpret = _interpret(interpret)
     assert iters % sync_every == 0, (iters, sync_every)
     nb = n // block_n
     chunks = iters // sync_every
     dpad = pad_dim(d)
     st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=block_n,
                                dtype=dtype, min_pos=min_pos,
-                               max_pos=max_pos, max_v=max_v)
+                               max_pos=max_pos, max_v=max_v,
+                               interpret=interpret)
     body = _fused_async_kernel_tel if telemetry else _fused_async_kernel
     kern = functools.partial(body, nb=nb,
                              sync_every=sync_every, w=w, c1=c1, c2=c2,
@@ -1377,7 +1546,7 @@ def fused_async_call(n: int, d: int, iters: int, block_n: int,
     mat = pl.BlockSpec((dpad, block_n), lambda b, c: (0, b))
     row = pl.BlockSpec((1, block_n), lambda b, c: (0, b))
     gpc = pl.BlockSpec((dpad, 1), lambda b, c: (0, 0))
-    lpc = _async_local_spec(topology, dpad, nb, lambda b, c: (0, b))
+    lpc = _async_local_spec(topology, dpad, nb, lambda b, c: (b, 0, 0))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [smem,                                         # scal
                 mat, mat, mat, row, gpc, smem, lpc, smem] \
@@ -1390,7 +1559,7 @@ def fused_async_call(n: int, d: int, iters: int, block_n: int,
         jax.ShapeDtypeStruct((1, n), dtype),                  # pbest_fit
         jax.ShapeDtypeStruct((dpad, 1), dtype),               # gbest_pos
         jax.ShapeDtypeStruct((1,), dtype),                    # gbest_fit
-        jax.ShapeDtypeStruct((dpad, nb), dtype),              # local_pos
+        jax.ShapeDtypeStruct((nb, dpad, 1), dtype),           # local_pos
         jax.ShapeDtypeStruct((nb,), dtype),                   # local_fit
     ]
     aliases = {1: 0, 2: 1, 3: 2, 4: 3, 5: 4, 6: 5, 7: 6, 8: 7}
@@ -1406,8 +1575,7 @@ def fused_async_call(n: int, d: int, iters: int, block_n: int,
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=_CompilerParams(
-            dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY)),
+        compiler_params=_arbitrary(2),
         interpret=interpret,
         name="cupso_fused_queue_lock_async",
     )
@@ -1419,51 +1587,53 @@ def fused_async_call(n: int, d: int, iters: int, block_n: int,
 def fused_async_batch_call(s_cnt: int, n: int, d: int, iters: int,
                            block_n: int, sync_every: int, dtype, *,
                            w, c1, c2, min_pos, max_pos, max_v, fitness,
-                           rule="pso", topology="gbest", interpret=True,
+                           rule="pso", topology="gbest", interpret=None,
                            telemetry=False):
     """Batched async queue-lock: grid (swarms, blocks, chunks).
 
     Args (runtime): seeds[S]i32, iterations[S]i32,
-                    pos/vel/pbest_pos [Dpad, S*N], pbest_fit [1, S*N],
-                    gbest_pos [Dpad, S], gbest_fit [S],
-                    local_pos [Dpad, S*nb], local_fit [S*nb]
+                    pos/vel/pbest_pos [S, Dpad, N], pbest_fit [S, 1, N],
+                    gbest_pos [S, Dpad, 1], gbest_fit [S],
+                    local_pos [S*nb, Dpad, 1], local_fit [S*nb]
     Swarm-major then block-major: swarm s's block b runs its whole iteration
     span while resident, exactly like a standalone ``fused_async_call`` —
     row s is bit-identical to the single-swarm async kernel. ``telemetry``
     appends an aliased counts[3*S]i32 operand.
     """
     assert n % block_n == 0, (n, block_n)
+    interpret = _interpret(interpret)
     assert iters % sync_every == 0, (iters, sync_every)
     nb = n // block_n
     chunks = iters // sync_every
     dpad = pad_dim(d)
     st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=block_n,
                                dtype=dtype, min_pos=min_pos,
-                               max_pos=max_pos, max_v=max_v)
+                               max_pos=max_pos, max_v=max_v,
+                               interpret=interpret)
     body = (_fused_async_batch_kernel_tel if telemetry
             else _fused_async_batch_kernel)
     kern = functools.partial(body, nb=nb,
                              sync_every=sync_every, w=w, c1=c1, c2=c2,
                              d_real=d, rule=_kernel_rule(rule),
                              topology=topology, statics=st)
-    mat = pl.BlockSpec((dpad, block_n), lambda s, b, c: (0, s * nb + b))
-    row = pl.BlockSpec((1, block_n), lambda s, b, c: (0, s * nb + b))
-    gpc = pl.BlockSpec((dpad, 1), lambda s, b, c: (0, s))
+    mat = pl.BlockSpec((None, dpad, block_n), lambda s, b, c: (s, 0, b))
+    row = pl.BlockSpec((None, 1, block_n), lambda s, b, c: (s, 0, b))
+    gpc = pl.BlockSpec((None, dpad, 1), lambda s, b, c: (s, 0, 0))
     lpc = _async_local_spec(topology, dpad, s_cnt * nb,
-                            lambda s, b, c: (0, s * nb + b))
+                            lambda s, b, c: (s * nb + b, 0, 0))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [smem, smem,                                   # seeds, iters
                 mat, mat, mat, row, gpc, smem, lpc, smem] \
         + _const_specs(consts)
     out_specs = [mat, mat, mat, row, gpc, smem, lpc, smem]
     out_shape = [
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # pos
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # vel
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # pbest_pos
-        jax.ShapeDtypeStruct((1, s_cnt * n), dtype),          # pbest_fit
-        jax.ShapeDtypeStruct((dpad, s_cnt), dtype),           # gbest_pos
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # pos
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # vel
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # pbest_pos
+        jax.ShapeDtypeStruct((s_cnt, 1, n), dtype),           # pbest_fit
+        jax.ShapeDtypeStruct((s_cnt, dpad, 1), dtype),        # gbest_pos
         jax.ShapeDtypeStruct((s_cnt,), dtype),                # gbest_fit
-        jax.ShapeDtypeStruct((dpad, s_cnt * nb), dtype),      # local_pos
+        jax.ShapeDtypeStruct((s_cnt * nb, dpad, 1), dtype),   # local_pos
         jax.ShapeDtypeStruct((s_cnt * nb,), dtype),           # local_fit
     ]
     aliases = {2: 0, 3: 1, 4: 2, 5: 3, 6: 4, 7: 5, 8: 6, 9: 7}
@@ -1479,9 +1649,7 @@ def fused_async_batch_call(s_cnt: int, n: int, d: int, iters: int,
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=_CompilerParams(
-            dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY,
-                                 pltpu.ARBITRARY)),
+        compiler_params=_arbitrary(3),
         interpret=interpret,
         name="cupso_fused_queue_lock_async_batch",
     )
@@ -1500,7 +1668,7 @@ def fused_async_batch_call(s_cnt: int, n: int, d: int, iters: int,
 def hetero_fused_async_batch_call(s_cnt: int, n: int, d: int, iters: int,
                                   block_n: int, sync_every: int, dtype, *,
                                   w, c1, c2, members, rule="pso",
-                                  topology="gbest", interpret=True,
+                                  topology="gbest", interpret=None,
                                   telemetry=False):
     """Batched async queue-lock with a per-swarm problem (kernel 4h).
 
@@ -1510,35 +1678,36 @@ def hetero_fused_async_batch_call(s_cnt: int, n: int, d: int, iters: int,
     counts[3*S]i32 operand.
     """
     assert n % block_n == 0, (n, block_n)
+    interpret = _interpret(interpret)
     assert iters % sync_every == 0, (iters, sync_every)
     nb = n // block_n
     chunks = iters // sync_every
     dpad = pad_dim(d)
     branches = _hetero_branches(members, d=d, dpad=dpad, bn=block_n,
-                                dtype=dtype)
+                                dtype=dtype, interpret=interpret)
     body = (_hetero_fused_async_batch_kernel_tel if telemetry
             else _hetero_fused_async_batch_kernel)
     kern = functools.partial(body, nb=nb,
                              sync_every=sync_every, w=w, c1=c1, c2=c2,
                              d_real=d, rule=_kernel_rule(rule),
                              topology=topology, statics=branches)
-    mat = pl.BlockSpec((dpad, block_n), lambda s, b, c: (0, s * nb + b))
-    row = pl.BlockSpec((1, block_n), lambda s, b, c: (0, s * nb + b))
-    gpc = pl.BlockSpec((dpad, 1), lambda s, b, c: (0, s))
+    mat = pl.BlockSpec((None, dpad, block_n), lambda s, b, c: (s, 0, b))
+    row = pl.BlockSpec((None, 1, block_n), lambda s, b, c: (s, 0, b))
+    gpc = pl.BlockSpec((None, dpad, 1), lambda s, b, c: (s, 0, 0))
     lpc = _async_local_spec(topology, dpad, s_cnt * nb,
-                            lambda s, b, c: (0, s * nb + b))
+                            lambda s, b, c: (s * nb + b, 0, 0))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [smem, smem, smem,                      # seeds, iters, fids
                 mat, mat, mat, row, gpc, smem, lpc, smem]
     out_specs = [mat, mat, mat, row, gpc, smem, lpc, smem]
     out_shape = [
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # pos
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # vel
-        jax.ShapeDtypeStruct((dpad, s_cnt * n), dtype),       # pbest_pos
-        jax.ShapeDtypeStruct((1, s_cnt * n), dtype),          # pbest_fit
-        jax.ShapeDtypeStruct((dpad, s_cnt), dtype),           # gbest_pos
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # pos
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # vel
+        jax.ShapeDtypeStruct((s_cnt, dpad, n), dtype),        # pbest_pos
+        jax.ShapeDtypeStruct((s_cnt, 1, n), dtype),           # pbest_fit
+        jax.ShapeDtypeStruct((s_cnt, dpad, 1), dtype),        # gbest_pos
         jax.ShapeDtypeStruct((s_cnt,), dtype),                # gbest_fit
-        jax.ShapeDtypeStruct((dpad, s_cnt * nb), dtype),      # local_pos
+        jax.ShapeDtypeStruct((s_cnt * nb, dpad, 1), dtype),   # local_pos
         jax.ShapeDtypeStruct((s_cnt * nb,), dtype),           # local_fit
     ]
     aliases = {3: 0, 4: 1, 5: 2, 6: 3, 7: 4, 8: 5, 9: 6, 10: 7}
@@ -1554,9 +1723,7 @@ def hetero_fused_async_batch_call(s_cnt: int, n: int, d: int, iters: int,
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=_CompilerParams(
-            dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY,
-                                 pltpu.ARBITRARY)),
+        compiler_params=_arbitrary(3),
         interpret=interpret,
         name="cupso_hetero_fused_queue_lock_async_batch",
     )

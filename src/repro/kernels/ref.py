@@ -433,6 +433,9 @@ def run_fused_async_oracle(seed, base_iter, pos, vel, pbp, pbf, gp, gf,
     the shared gbest, which remains a chunk-exit flush target only —
     mirroring the kernel's block-major diffusion schedule bit-for-bit.
 
+    Returns the kernel's eight state arrays, ``local_pos`` in its
+    ``[nb, Dpad, 1]`` layout.
+
     ``counters`` mirrors ``run_fused_oracle``: ``queue_updates`` counts
     inner iterations with a non-empty block-local queue, ``publications``
     counts chunk-exit shared-gbest writes, ``block_improvements`` counts
@@ -512,7 +515,7 @@ def run_fused_async_oracle(seed, base_iter, pos, vel, pbp, pbf, gp, gf,
                             counters.get("publications", 0) + 1)
                     gf = lf[b]
                     gp = lp[b]
-    lp_arr = jnp.concatenate(lp, axis=1)
+    lp_arr = jnp.stack([jnp.asarray(x) for x in lp])   # [nb, Dpad, 1]
     lf_arr = jnp.stack([jnp.asarray(x).reshape(()) for x in lf])
     return (jnp.asarray(pos), jnp.asarray(vel), jnp.asarray(pbp),
             jnp.asarray(pbf), gp, gf, lp_arr, lf_arr)

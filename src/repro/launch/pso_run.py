@@ -5,7 +5,8 @@ entry point.
         --particles 32768 --iters 1000 --variant queue --kernel \
         --islands 8 --exchange 50 --ckpt-dir /tmp/pso_ckpt
 
---kernel uses the fused Pallas queue-lock kernel (interpret mode on CPU);
+--kernel uses the fused Pallas queue-lock kernel (compiled on a TPU, run
+in the Pallas interpreter elsewhere);
 --islands N runs N shard_map islands over the first N available devices
 (on a pod, particles shard over the data axis; see DESIGN.md §3).
 ``--islands N --variant async`` runs the asynchronous island ring: no
@@ -37,12 +38,14 @@ import time
 import jax
 import numpy as np
 
+from repro.api import Method
 from repro.core import ASYNC_SYNC_EVERY, PSOConfig, init_swarm, run
 from repro.core.constraints import constrain_problem, constraint_set_from_cli
 from repro.core.problem import list_problems, resolve_problem
 from repro.core.distributed import (gather_swarm, init_sharded_swarm,
                                     make_distributed_run)
 from repro.runtime import RunnerConfig, StepRunner
+from repro.serving import use_persistent_cache
 from repro import checkpoint as ckpt
 
 
@@ -96,8 +99,9 @@ def main():
                          "latency + kernel counters) here")
     ap.add_argument("--profile-dir", default="", metavar="DIR",
                     help="also capture a jax.profiler trace into DIR "
-                         "(no-op when the profiler is unavailable)")
+                         "(fails if the trace cannot start)")
     args = ap.parse_args()
+    use_persistent_cache()
 
     if args.fitness not in list_problems():
         ap.error(f"unknown fitness {args.fitness!r}; registered problems: "
@@ -135,6 +139,15 @@ def main():
         # async kernel + ring composition is a TPU-hardware follow-on)
         ap.error("--kernel --islands does not support --variant async; "
                  "drop --kernel (the ring uses the jnp async local loop)")
+    if args.kernel:
+        # the api's rule for problems the kernels cannot compile; islands
+        # run the fused queue_lock kernel as their local step
+        kvariant = "async" if args.variant == "async" else "queue_lock"
+        try:
+            Method(variant=kvariant, backend="kernel").resolve_backend(
+                cfg.problem)
+        except ValueError as e:
+            ap.error(f"--kernel: {e}")
     if args.telemetry and not args.kernel:
         ap.error("--telemetry counts inside the fused Pallas kernels; "
                  "add --kernel (with --variant queue_lock or async)")

@@ -52,6 +52,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
 import time
 from collections import defaultdict
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -250,9 +251,11 @@ class SolveServer:
     """Collects solve requests and dispatches them as padded batches.
 
     ``backend="jnp"`` runs every variant through the vmapped ``solve_many``;
-    ``backend="kernel"`` routes ``queue_lock`` requests through the batched
-    fused Pallas kernel (interpret mode off-TPU) and everything else through
-    the jnp path. ``coalesce_registry`` (default on) merges every registered
+    ``backend="kernel"`` routes ``queue_lock`` and ``async`` requests through
+    the batched fused Pallas kernels (compiled on a TPU, interpreted on any
+    other backend) and everything else through the jnp path. A group whose
+    problem the kernels cannot compile (``Method.resolve_backend``) fails
+    with that error. ``coalesce_registry`` (default on) merges every registered
     built-in problem at the same solve shape into one heterogeneous batch
     (``lax.switch`` row dispatch); off, grouping falls back to the legacy
     per-problem content-hash keys.
@@ -269,7 +272,7 @@ class SolveServer:
     """
 
     def __init__(self, max_batch: int = 64, backend: str = "jnp",
-                 interpret: bool = True, block_n: Optional[int] = None,
+                 block_n: Optional[int] = None,
                  coalesce_registry: bool = True, autotune: bool = False,
                  metrics=None):
         if backend not in ("jnp", "kernel"):
@@ -279,7 +282,6 @@ class SolveServer:
                 f"max_batch={max_batch} < minimum bucket {BUCKETS[0]}")
         self.max_batch = max_batch
         self.backend = backend
-        self.interpret = interpret
         self.block_n = block_n
         self.coalesce_registry = coalesce_registry
         self.autotune = autotune
@@ -365,17 +367,23 @@ class SolveServer:
     def _dispatch_uniform(self, cfg: PSOConfig, seeds: np.ndarray,
                           r0: SolveRequest):
         """Legacy single-problem dispatch (content-hash-keyed groups)."""
-        if self.backend == "kernel" and r0.variant == "queue_lock":
+        kernel = (self.backend == "kernel"
+                  and r0.variant in ("queue_lock", "async"))
+        if kernel:
+            # the api's rule: raises for a problem the kernels cannot compile
+            from repro.api import Method
+            Method(variant=r0.variant, backend="kernel").resolve_backend(
+                cfg.problem)
+        if kernel and r0.variant == "queue_lock":
             from repro.kernels.ops import run_queue_lock_fused_batch
             return run_queue_lock_fused_batch(
                 cfg, init_batch(cfg, seeds), iters=r0.iters,
-                block_n=self.block_n, interpret=self.interpret)
-        if self.backend == "kernel" and r0.variant == "async":
+                block_n=self.block_n)
+        if kernel and r0.variant == "async":
             from repro.kernels.ops import run_queue_lock_fused_async_batch
             return run_queue_lock_fused_async_batch(
                 cfg, init_batch(cfg, seeds), iters=r0.iters,
-                sync_every=r0.sync_every,
-                block_n=self.block_n, interpret=self.interpret)
+                sync_every=r0.sync_every, block_n=self.block_n)
         return solve_many(cfg, seeds, iters=r0.iters, variant=r0.variant,
                           sync_every=r0.sync_every)
 
@@ -391,12 +399,11 @@ class SolveServer:
                 from repro.kernels.ops import run_queue_lock_fused_batch
                 return run_queue_lock_fused_batch(
                     rcfg, batch, iters=r0.iters, block_n=self.block_n,
-                    interpret=self.interpret, fids=rows.fid, table=table)
+                    fids=rows.fid, table=table)
             from repro.kernels.ops import run_queue_lock_fused_async_batch
             return run_queue_lock_fused_async_batch(
                 rcfg, batch, iters=r0.iters, sync_every=r0.sync_every,
-                block_n=self.block_n, interpret=self.interpret,
-                fids=rows.fid, table=table)
+                block_n=self.block_n, fids=rows.fid, table=table)
         return solve_many(cfg, seeds, iters=r0.iters, variant=r0.variant,
                           sync_every=r0.sync_every, problems=probs)
 
@@ -501,6 +508,8 @@ def main() -> int:
                     help="write a Prometheus text exposition of the "
                          "serving metrics here after the flush")
     args = ap.parse_args()
+    from repro.serving import use_persistent_cache
+    use_persistent_cache()
     # A mixed workload: four built-in objectives over two solve shapes. With
     # registry coalescing each shape is ONE heterogeneous dispatch; with
     # --no-coalesce each (shape, problem) pair compiles and runs alone.
@@ -538,7 +547,11 @@ def main() -> int:
           f"({s.hetero_dispatches} heterogeneous, {s.padded_rows} padded "
           f"rows, fill={s.batch_fill:.1f}), wall={dt:.3f}s "
           f"({s.requests / dt:.1f} solves/s)")
-    return 0
+    for r in results:
+        if not r.ok:
+            print(f"FAILED req({r.request.fitness}, dim={r.request.dim}, "
+                  f"seed={r.request.seed}): {r.error!r}", file=sys.stderr)
+    return 1 if s.failed else 0
 
 
 if __name__ == "__main__":

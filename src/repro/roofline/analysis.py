@@ -44,15 +44,8 @@ _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns a one-element list of dicts (per computation), newer
-    returns the dict directly; normalize to the dict.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()``: the compiled program's cost dict."""
+    return compiled.cost_analysis()
 
 
 def _shape_bytes(shape_str: str) -> int:

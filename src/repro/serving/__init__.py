@@ -12,9 +12,9 @@ Three layers over the flush server in ``repro.launch.serve``:
 
 See docs/serving.md for the architecture and the admission invariants.
 """
-from .compile_cache import CompileCache
+from .compile_cache import CompileCache, use_persistent_cache
 from .metrics import LatencyStat, ServingMetrics
 from .scheduler import ContinuousScheduler
 
 __all__ = ["CompileCache", "ContinuousScheduler", "LatencyStat",
-           "ServingMetrics"]
+           "ServingMetrics", "use_persistent_cache"]

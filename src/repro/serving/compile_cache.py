@@ -14,9 +14,11 @@ time-critical tier cannot afford. This module closes it in two layers:
    never runs again. Exported programs replay the captured StableHLO
    bit-for-bit, so the cached program's outputs are bitwise identical to
    the freshly traced one (verified in tests/test_serving.py).
-2. **XLA layer** — ``enable_xla_cache()`` points JAX's persistent
-   compilation cache at ``<dir>/xla`` so even the backend compile of the
-   replayed module is a disk hit on restart.
+2. **XLA layer** — a cache with a directory calls
+   ``use_persistent_cache()``, which places JAX's persistent compilation
+   cache (``JAX_COMPILATION_CACHE_DIR`` where set, else the fixed
+   ``<checkout>/.jax_cache``), so the backend compile of a replayed module
+   is a disk hit on restart too.
 
 Keys are the serving layer's hetero-aware compile keys (strings built
 from ``SolveRequest.group_key``-style tuples) plus the program shape; the
@@ -38,6 +40,31 @@ from typing import Callable, Dict, Optional
 
 CACHE_ENV = "REPRO_COMPILE_CACHE"
 _MANIFEST = "manifest.json"
+XLA_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: <checkout>/.jax_cache — fixed, because the directory is part of what a
+#: cache entry is found by: a path that moved between runs would never hit.
+DEFAULT_XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    os.pardir, ".jax_cache")
+
+
+def use_persistent_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+    sets no directory. Otherwise the cache goes to ``DEFAULT_XLA_CACHE_DIR``.
+    Either way every program is persisted: JAX's default thresholds skip
+    compiles under one second, and most programs here compile faster than
+    that. Entry points call this before their first compile."""
+    import jax
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    env = os.environ.get(XLA_CACHE_ENV)
+    if env:
+        return env
+    path = os.path.normpath(DEFAULT_XLA_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _fingerprint() -> Dict[str, str]:
@@ -76,13 +103,17 @@ class CompileCache:
 
     ``path=None`` reads ``REPRO_COMPILE_CACHE``; if that is unset too the
     cache is memory-only (still deduplicates traces within one process,
-    nothing persists). ``metrics`` is an optional
+    nothing persists). A cache with a directory also places JAX's
+    persistent compilation cache (``use_persistent_cache``). ``metrics`` is
+    an optional
     ``repro.serving.metrics.ServingMetrics`` sink for the hit/miss/trace
     counters (kept locally as well, so the cache is usable standalone).
     """
 
     def __init__(self, path: Optional[str] = None, metrics=None):
         self.path = path if path is not None else os.environ.get(CACHE_ENV)
+        if self.path:
+            use_persistent_cache()
         self.metrics = metrics
         self._mem: Dict[str, Callable] = {}
         self.aot_hits = 0
@@ -203,7 +234,7 @@ class CompileCache:
         """Deserialize every on-disk blob into the in-process memo (replica
         startup). Returns how many programs are now servable without a
         trace; backend compiles of the replayed modules additionally hit
-        the XLA persistent cache when ``enable_xla_cache`` ran."""
+        JAX's persistent compilation cache."""
         import jax
         from jax import export
 
@@ -222,26 +253,6 @@ class CompileCache:
             except Exception:
                 continue
         return len(self._mem)
-
-    def enable_xla_cache(self) -> bool:
-        """Point JAX's persistent compilation cache at ``<dir>/xla`` so the
-        backend compile of replayed modules is a disk hit too. Safe to call
-        repeatedly; returns False when the cache is memory-only or the
-        config knobs are unavailable."""
-        if not self.path:
-            return False
-        import jax
-        try:
-            os.makedirs(os.path.join(self.path, "xla"), exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir",
-                              os.path.join(self.path, "xla"))
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            return True
-        except Exception:
-            return False
 
     def snapshot(self) -> dict:
         return {"path": self.path, "programs": len(self._mem),

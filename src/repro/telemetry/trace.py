@@ -139,27 +139,17 @@ class TraceWriter:
 def profiler_session(logdir: Optional[str]):
     """Optionally bracket a region with a ``jax.profiler`` trace.
 
-    Yields True when a profiler session actually started (logdir given and
-    the backend cooperated), else False. Never raises: on CPU test rigs
-    and in environments without the profiler plugin this must stay a
-    no-op so telemetry code paths are portable.
+    Without a ``logdir`` this is a no-op that yields False. With one it
+    yields True once the trace has started, and raises when the trace
+    cannot start: a caller that asked for a trace must not get a run
+    without one.
     """
     if not logdir:
         yield False
         return
-    started = False
-    try:
-        from jax import profiler
-        profiler.start_trace(logdir)
-        started = True
-    except Exception:
-        yield False
-        return
+    from jax import profiler
+    profiler.start_trace(logdir)
     try:
         yield True
     finally:
-        if started:
-            try:
-                profiler.stop_trace()
-            except Exception:
-                pass
+        profiler.stop_trace()

@@ -135,6 +135,24 @@ def test_resolve_noise_margin_keeps_fixed_default(cache, monkeypatch):
     assert s2.variant == "async"            # a 2x win displaces it
 
 
+def test_resolve_kernel_failure_surfaces_on_tpu(cache, monkeypatch):
+    """On a TPU a kernel candidate that raises is a defect to surface, not
+    a schedule that quietly loses to jnp; elsewhere it just drops out."""
+    def kernel_breaks(sched, *a, **k):
+        if sched.backend == "kernel":
+            raise RuntimeError("Mosaic refused the kernel")
+        return 10.0
+    monkeypatch.setattr(at, "measure_schedule", kernel_breaks)
+    monkeypatch.setattr(at, "rank_schedules", lambda cands, *a, **k: [
+        Schedule("queue_lock", "kernel", block_n=128, source="model",
+                 predicted_us=1.0)])
+    s = resolve_schedule("sphere", 4, 256, 64, cache=cache, kernel_ok=True)
+    assert s.backend == "jnp"
+    monkeypatch.setattr(at, "_kernel_ok", lambda: True)
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        resolve_schedule("sphere", 4, 512, 64, cache=cache, kernel_ok=True)
+
+
 def test_resolve_cache_hit_skips_measurement(cache, monkeypatch):
     calls = {"n": 0}
     real = at.measure_schedule
@@ -224,16 +242,17 @@ def test_auto_history_no_longer_forces_jnp():
     # warning); the kernel backend now records history by chunking its
     # launch at sync points, so auto resolves by the plain device rule and
     # never warns.
+    cubic = repro.resolve_problem("cubic")
     m = repro.Method(backend="auto", variant="queue_lock",
                      record_history=True)
     import warnings
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        assert m.resolve_backend() in ("jnp", "kernel")
+        assert m.resolve_backend(cubic) in ("jnp", "kernel")
     # the explicit kernel pin is accepted now, too
     mk = repro.Method(backend="kernel", variant="queue_lock",
                       record_history=True)
-    assert mk.resolve_backend() == "kernel"
+    assert mk.resolve_backend(cubic) == "kernel"
 
 
 # --------------------------------------------------------------------------

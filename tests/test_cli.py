@@ -54,3 +54,15 @@ def test_report_renderer():
     # sanity on the source json itself
     data = json.load(open(path))
     assert sum(1 for v in data.values() if v.get("status") == "fail") == 0
+
+
+def test_chip_smoke_refuses_cpu():
+    """Without a TPU the smoke run fails with a clear message and prints
+    no result line; it never falls back to the CPU."""
+    env = dict(ENV, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       cwd=REPO, env=env, capture_output=True, text=True,
+                       timeout=300)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert '"ok"' not in r.stdout

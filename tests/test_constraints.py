@@ -731,3 +731,42 @@ def test_history_run_with_history_matches_plain_run_async():
     assert float(plain.gbest_fit) == float(st.gbest_fit)
     assert its == (4, 8, 12, 16, 20, 22)
     assert float(fits[-1]) == float(st.gbest_fit)
+
+
+def test_projection_problems_resolve_to_jnp():
+    """A projection-mode constraint set sorts inside the advance, which
+    Mosaic cannot lower: such problems resolve to jnp whatever the
+    platform, and an explicit kernel request fails with a clear error
+    before any lowering."""
+    prob = get_problem("sphere_simplex")
+    assert Method(variant="async").resolve_backend(prob) == "jnp"
+    assert Method(variant="async").resolve_backend(
+        get_problem("sphere_simplex_pen")) in ("jnp", "kernel")
+    with pytest.raises(ValueError, match="projection-mode"):
+        Method(variant="queue_lock", backend="kernel").resolve_backend(prob)
+    with pytest.raises(ValueError, match="projection-mode"):
+        repro.solve(prob, dim=4, particles=64, iters=2,
+                    method=Method(variant="async", telemetry=True))
+    res = repro.solve(prob, dim=4, particles=64, iters=4, variant="async")
+    assert res.feasible
+
+
+def test_projection_problems_refused_by_kernel_front_ends(monkeypatch):
+    """The flush server and the PSO CLI ask ``Method.resolve_backend``
+    before a kernel dispatch, so a projection-mode problem on the kernel
+    backend fails with the api's error, not inside lowering."""
+    from repro.launch import pso_run
+    from repro.launch.serve import SolveRequest, SolveServer
+    prob = get_problem("sphere_simplex")
+    [res] = SolveServer(backend="kernel").solve_all(
+        [SolveRequest(dim=4, particle_cnt=64, fitness=prob, seed=0,
+                      iters=2, variant="queue_lock")])
+    assert not res.ok and "projection-mode" in str(res.error)
+    monkeypatch.setattr(pso_run, "use_persistent_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", [
+        "pso_run", "--dim", "4", "--particles", "64", "--iters", "2",
+        "--variant", "queue_lock", "--kernel", "--constraint", "simplex",
+        "--constraint-mode", "projection"])
+    with pytest.raises(SystemExit) as e:
+        pso_run.main()
+    assert e.value.code == 2

@@ -323,17 +323,23 @@ def test_custom_problem_jnp_vs_kernel_agree():
 # Facade
 # --------------------------------------------------------------------------
 
-def test_method_validation():
+def test_method_validation(monkeypatch):
     with pytest.raises(ValueError, match="unknown variant"):
         Method(variant="warp")
     with pytest.raises(ValueError, match="unknown backend"):
         Method(backend="gpu")
     with pytest.raises(ValueError, match="kernel"):
         Method(variant="queue", backend="kernel")
-    assert Method(variant="queue").resolve_backend() == "jnp"
+    sphere = get_problem("sphere")
+    assert Method(variant="queue").resolve_backend(sphere) == "jnp"
     assert Method(variant="queue_lock",
-                  backend="kernel").resolve_backend() == "kernel"
-    assert Method().resolve_interpret() is (jax.default_backend() != "tpu")
+                  backend="kernel").resolve_backend(sphere) == "kernel"
+    # interpret mode is the kernels' own decision, from the platform alone
+    from repro.kernels.pso_step import default_interpret
+    assert default_interpret() is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert default_interpret() is False
+    assert Method(variant="async").resolve_backend(sphere) == "kernel"
 
 
 def test_solve_rejects_method_plus_loose_kwargs():

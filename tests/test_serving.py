@@ -324,3 +324,74 @@ def test_serve_rule_topology_thread_to_engine():
         assert res.gbest_fit == float(st.gbest_fit)
     # one dispatch per (rule, topology): the compile key split them
     assert srv.stats.dispatches == len(combos)
+
+
+_CACHE_KNOBS = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs",
+                "jax_persistent_cache_min_entry_size_bytes")
+
+
+def test_persistent_cache_dir_fixed_in_checkout(monkeypatch):
+    """Unset JAX_COMPILATION_CACHE_DIR: the cache is <checkout>/.jax_cache,
+    never a temp or per-process name."""
+    import os
+
+    import jax
+    from repro.serving import use_persistent_cache
+    from repro.serving.compile_cache import XLA_CACHE_ENV
+    monkeypatch.delenv(XLA_CACHE_ENV, raising=False)
+    prev = {k: getattr(jax.config, k) for k in _CACHE_KNOBS}
+    try:
+        path = use_persistent_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        # every program persists, however fast it compiled
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+        assert use_persistent_cache() == path        # stable across calls
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+
+
+def test_persistent_cache_dir_env_left_to_jax(monkeypatch, tmp_path):
+    """Set JAX_COMPILATION_CACHE_DIR: JAX's own reading stands and no code
+    sets another directory."""
+    import jax
+    from repro.serving import CompileCache, use_persistent_cache
+    from repro.serving.compile_cache import XLA_CACHE_ENV
+    monkeypatch.setenv(XLA_CACHE_ENV, str(tmp_path / "xla"))
+    prev = jax.config.jax_compilation_cache_dir
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a, **k: updates.append(a))
+    assert use_persistent_cache() == str(tmp_path / "xla")
+    CompileCache(str(tmp_path / "aot"))
+    # the thresholds are still set (they choose no directory); the
+    # directory is not
+    assert {a[0] for a in updates} == {
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes"}
+    assert ("jax_persistent_cache_min_compile_time_secs", 0.0) in updates
+    assert ("jax_persistent_cache_min_entry_size_bytes", -1) in updates
+    assert jax.config.jax_compilation_cache_dir == prev
+
+
+def test_serve_main_exits_nonzero_on_failed_group(monkeypatch, capsys):
+    """A group that raises is isolated into error results by flush(), but
+    the CLI run as a whole must report failure."""
+    import sys
+
+    import repro.serving
+    from repro.launch import serve
+
+    def boom(self, reqs):
+        raise RuntimeError("injected group failure")
+
+    monkeypatch.setattr(repro.serving, "use_persistent_cache", lambda: None)
+    monkeypatch.setattr(serve.SolveServer, "_solve_group", boom)
+    monkeypatch.setattr(sys, "argv", ["serve", "--requests", "4",
+                                      "--iters", "4"])
+    assert serve.main() == 1
+    assert "injected group failure" in capsys.readouterr().err

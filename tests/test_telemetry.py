@@ -145,7 +145,8 @@ def test_telemetry_method_validation():
         Method(variant="queue_lock", islands=2, telemetry=True)
     # telemetry alone resolves to the kernel backend
     m = Method(variant="queue_lock", telemetry=True)
-    assert m.resolve_backend() == "kernel"
+    assert m.resolve_backend(repro.resolve_problem("cubic")) \
+        == "kernel"
 
 
 def test_record_history_on_kernel_backend():
@@ -307,3 +308,21 @@ def test_latency_stat_merge_from_overflow():
     assert 100.0 <= st.p50_us <= 200.0 and 100.0 <= st.p99_us <= 200.0
     a.merge_from(None)                                       # no-op
     assert a.span("x_us").count == 50
+
+
+def test_profiler_session_raises_when_trace_cannot_start(monkeypatch,
+                                                         tmp_path):
+    """Asking for a trace and not getting one is an error, not a silent
+    run without it; no logdir stays a no-op."""
+    from jax import profiler
+
+    from repro.telemetry import profiler_session
+
+    def refuse(logdir, *a, **k):
+        raise RuntimeError("profiler unavailable")
+    monkeypatch.setattr(profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with profiler_session(str(tmp_path)):
+            pass
+    with profiler_session(None) as started:
+        assert started is False
