@@ -57,7 +57,7 @@ from repro.core.problem import Problem, resolve_problem
 from repro.core.pso import (ASYNC_SYNC_EVERY, PSOConfig, SwarmState,
                             VARIANTS, init_swarm, run, run_with_history)
 from repro.core.update_rules import (TOPOLOGIES, resolve_rule, rule_names)
-from repro.telemetry import KernelCounters
+from repro.telemetry import SPAN_NAMES, KernelCounters, span
 
 _KERNEL_VARIANTS = ("queue_lock", "async")
 
@@ -272,16 +272,19 @@ class Result:
 
     @property
     def best_fit(self) -> float:
-        return float(self.problem.user_value(self.state.gbest_fit))
+        with span(SPAN_NAMES.readback):
+            return float(self.problem.user_value(self.state.gbest_fit))
 
     @property
     def best_pos(self) -> np.ndarray:
-        return np.asarray(self.state.gbest_pos)
+        with span(SPAN_NAMES.readback):
+            return np.asarray(self.state.gbest_pos)
 
     @property
     def gbest_fit(self) -> float:
         """Canonical (maximized) fitness, as the engine tracks it."""
-        return float(self.state.gbest_fit)
+        with span(SPAN_NAMES.readback):
+            return float(self.state.gbest_fit)
 
     @property
     def violation(self) -> float:
@@ -387,20 +390,25 @@ def solve(problem: Union[str, Problem], *,
     ``schedule="auto"`` lets the roofline autotuner pick the execution
     schedule for this shape (see ``Method``).
     """
-    prob = resolve_problem(problem)
-    m = _make_method(method, variant, backend, sync_every, block_n,
-                     record_history, schedule, rule, topology, telemetry)
-    cfg = _make_config(prob, dim, particles, w, c1, c2, dtype,
-                       min_pos, max_pos, max_v, m)
-    m = _effective_method(m, prob, cfg, iters)
-    if m.islands:
-        state = _run_islands(prob, cfg, seed, iters, m)
-        hist, tel = None, None
-    else:
-        state = init_swarm(cfg, seed)
-        state, hist, tel = _run_segmented(prob, cfg, state, iters, m)
-    return Result(problem=prob, config=cfg, method=m, iters=iters,
-                  state=state, history=hist, telemetry=tel)
+    with span(SPAN_NAMES.solve):
+        with span(SPAN_NAMES.prepare):
+            prob = resolve_problem(problem)
+            m = _make_method(method, variant, backend, sync_every, block_n,
+                             record_history, schedule, rule, topology,
+                             telemetry)
+            cfg = _make_config(prob, dim, particles, w, c1, c2, dtype,
+                               min_pos, max_pos, max_v, m)
+            m = _effective_method(m, prob, cfg, iters)
+        if m.islands:
+            state = _run_islands(prob, cfg, seed, iters, m)
+            hist, tel = None, None
+        else:
+            with span(SPAN_NAMES.init):
+                state = init_swarm(cfg, seed)
+            with span(SPAN_NAMES.launch):
+                state, hist, tel = _run_segmented(prob, cfg, state, iters, m)
+        return Result(problem=prob, config=cfg, method=m, iters=iters,
+                      state=state, history=hist, telemetry=tel)
 
 
 def _run_islands(prob: Problem, cfg: PSOConfig, seed: int, iters: int,
@@ -425,7 +433,8 @@ def _run_islands(prob: Problem, cfg: PSOConfig, seed: int, iters: int,
     if m.variant != "async" and m.resolve_backend(prob) == "kernel":
         from repro.kernels.ops import make_fused_local_step
         local_step = make_fused_local_step(block_n=m.block_n)
-    state = init_sharded_swarm(cfg, seed, mesh)
+    with span(SPAN_NAMES.init):
+        state = init_sharded_swarm(cfg, seed, mesh)
 
     def run_seg(cfg_k: PSOConfig, s: SwarmState, seg_iters: int):
         runner = make_distributed_run(
@@ -441,7 +450,8 @@ def _run_islands(prob: Problem, cfg: PSOConfig, seed: int, iters: int,
         return _reweight_state(cfg_k, s._replace(lbest_pos=None,
                                                  lbest_fit=None))
 
-    state, _ = _ramp_loop(prob, cfg, state, iters, run_seg, reweight)
+    with span(SPAN_NAMES.launch):
+        state, _ = _ramp_loop(prob, cfg, state, iters, run_seg, reweight)
     return state
 
 
@@ -632,39 +642,48 @@ def solve_many(problem: Union[str, Problem, None] = None,
     ``repro.core.pso``'s heterogeneous-dispatch notes for the full
     envelope).
     """
-    m = _make_method(method, variant, backend, sync_every, block_n,
-                     record_history, schedule, rule, topology, telemetry)
-    if m.islands:
-        raise ValueError("islands shard ONE swarm over devices; use solve()"
-                         " — solve_many batches independent swarms instead")
-    if (problem is None) == (problems is None):
-        raise ValueError(
-            "pass exactly one of problem= (homogeneous batch) or "
-            "problems= (one problem per seed)")
-    if problems is not None:
-        return _solve_many_hetero(problems, seeds, m, dim, particles, iters,
-                                  coeffs, w, c1, c2, dtype,
-                                  min_pos, max_pos, max_v)
-    prob = resolve_problem(problem)
-    cfg = _make_config(prob, dim, particles, w, c1, c2, dtype,
-                       min_pos, max_pos, max_v, m)
-    m = _effective_method(m, prob, cfg, iters, batch=len(seeds))
-    batch = init_batch(cfg, np.asarray(seeds, dtype=np.int64))
-    cnts = []
+    with span(SPAN_NAMES.solve_many):
+        with span(SPAN_NAMES.prepare):
+            m = _make_method(method, variant, backend, sync_every, block_n,
+                             record_history, schedule, rule, topology,
+                             telemetry)
+            if m.islands:
+                raise ValueError(
+                    "islands shard ONE swarm over devices; use solve()"
+                    " — solve_many batches independent swarms instead")
+            if (problem is None) == (problems is None):
+                raise ValueError(
+                    "pass exactly one of problem= (homogeneous batch) or "
+                    "problems= (one problem per seed)")
+            if problems is None:
+                prob = resolve_problem(problem)
+                cfg = _make_config(prob, dim, particles, w, c1, c2, dtype,
+                                   min_pos, max_pos, max_v, m)
+                m = _effective_method(m, prob, cfg, iters, batch=len(seeds))
+        if problems is not None:
+            return _solve_many_hetero(problems, seeds, m, dim, particles,
+                                      iters, coeffs, w, c1, c2, dtype,
+                                      min_pos, max_pos, max_v)
+        with span(SPAN_NAMES.init):
+            batch = init_batch(cfg, np.asarray(seeds, dtype=np.int64))
+        cnts = []
 
-    def seg(c, b, k):
-        b, h, cnt = _run_batch(c, b, k, m, coeffs)
-        if cnt is not None:
-            cnts.append(cnt)
-        return b, h
+        def seg(c, b, k):
+            b, h, cnt = _run_batch(c, b, k, m, coeffs)
+            if cnt is not None:
+                cnts.append(cnt)
+            return b, h
 
-    batch, hists = _ramp_loop(prob, cfg, batch, iters, seg, _reweight_batch)
-    rows_hist = _row_histories(hists, batch.swarm_cnt)
-    rows_tel = _row_counters(cnts, batch.swarm_cnt)
-    return [Result(problem=prob, config=cfg, method=m, iters=iters,
-                   state=batch_row(batch, s), history=rows_hist[s],
-                   telemetry=rows_tel[s])
-            for s in range(batch.swarm_cnt)]
+        with span(SPAN_NAMES.launch):
+            batch, hists = _ramp_loop(prob, cfg, batch, iters, seg,
+                                      _reweight_batch)
+        with span(SPAN_NAMES.rows):
+            rows_hist = _row_histories(hists, batch.swarm_cnt)
+            rows_tel = _row_counters(cnts, batch.swarm_cnt)
+            return [Result(problem=prob, config=cfg, method=m, iters=iters,
+                           state=batch_row(batch, s), history=rows_hist[s],
+                           telemetry=rows_tel[s])
+                    for s in range(batch.swarm_cnt)]
 
 
 def _row_histories(hists, s_cnt: int) -> List[Optional[History]]:
@@ -692,66 +711,69 @@ def _row_counters(cnts, s_cnt: int) -> List[Optional[KernelCounters]]:
 def _solve_many_hetero(problems, seeds, m: Method, dim, particles, iters,
                        coeffs, w, c1, c2, dtype,
                        min_pos, max_pos, max_v) -> List[Result]:
-    """``solve_many(problems=[...])``: per-row problem dispatch."""
+    """``solve_many(problems=[...])``: per-row problem dispatch, every
+    backend from one ``problem_rows`` descriptor set and one
+    ``init_batch``."""
+    from repro.core.multi_swarm import problem_rows
     from repro.core.pso import hetero_member_config
-    if min_pos is not None or max_pos is not None or max_v is not None:
-        raise ValueError("heterogeneous batches take bounds from each "
-                         "row's problem; drop min_pos/max_pos/max_v")
-    probs = [resolve_problem(p) for p in problems]
-    if len(probs) != len(seeds):
-        raise ValueError(f"{len(probs)} problems for {len(seeds)} seeds")
-    # cfg.fitness is a canonical placeholder: the rows carry the real
-    # objectives, and a fixed value lets every mix share one compiled
-    # program. Bounds stay unset — the core validates that.
-    kw = dict(dim=dim if dim is not None else 1, particle_cnt=particles,
-              fitness="cubic", dtype=dtype,
-              update_rule=m.rule, topology=m.topology)
-    for key, v in (("w", w), ("c1", c1), ("c2", c2)):
-        if v is not None:
-            kw[key] = v
-    cfg = PSOConfig(**kw)
-    m = _effective_method(m, probs[0], cfg, iters, batch=len(seeds),
-                          hetero_table=len({p.cache_key() for p in probs}))
-    seeds_arr = np.asarray(seeds, dtype=np.int64)
-    hists, cnts = [], []
-    if m.resolve_backend(probs[0]) == "kernel":
-        if coeffs is not None:
+    with span(SPAN_NAMES.prepare):
+        if min_pos is not None or max_pos is not None or max_v is not None:
+            raise ValueError("heterogeneous batches take bounds from each "
+                             "row's problem; drop min_pos/max_pos/max_v")
+        probs = [resolve_problem(p) for p in problems]
+        if len(probs) != len(seeds):
+            raise ValueError(
+                f"{len(probs)} problems for {len(seeds)} seeds")
+        # cfg.fitness is a canonical placeholder: the rows carry the real
+        # objectives, and a fixed value lets every mix share one compiled
+        # program. Bounds stay unset — the core validates that.
+        kw = dict(dim=dim if dim is not None else 1, particle_cnt=particles,
+                  fitness="cubic", dtype=dtype,
+                  update_rule=m.rule, topology=m.topology)
+        for key, v in (("w", w), ("c1", c1), ("c2", c2)):
+            if v is not None:
+                kw[key] = v
+        cfg = PSOConfig(**kw)
+        m = _effective_method(
+            m, probs[0], cfg, iters, batch=len(seeds),
+            hetero_table=len({p.cache_key() for p in probs}))
+        kernel = m.resolve_backend(probs[0]) == "kernel"
+        if kernel and coeffs is not None:
             raise ValueError("per-swarm coeffs are a jnp-backend feature")
-        from repro.core.multi_swarm import problem_rows
         rows, table = problem_rows(probs, cfg.dim, cfg.dtype)
         rcfg = cfg.resolved()
-        batch = init_batch(rcfg, seeds_arr, rows=rows, table=table)
-        batch, hist, cnt = _run_batch_kernel(rcfg, batch, iters, m,
-                                             rows=rows, table=table)
-        if hist is not None:
-            hists.append(hist)
-        if cnt is not None:
-            cnts.append(cnt)
-    elif m.record_history:
-        from repro.core.multi_swarm import problem_rows
-        rows, table = problem_rows(probs, cfg.dim, cfg.dtype)
-        rcfg = cfg.resolved()
-        batch = init_batch(rcfg, seeds_arr, rows=rows, table=table)
-        batch, (its, fits, viols) = run_many_with_history(
-            rcfg, batch, iters, m.variant, coeffs,
-            sync_every=m.sync_every, rows=rows, table=table,
-            n_blocks=_jnp_async_blocks(m, cfg.particle_cnt))
-        hists.append((np.asarray(its, dtype=np.int64), np.asarray(fits),
-                      None if viols is None else np.asarray(viols)))
-    else:
-        from repro.core.multi_swarm import solve_many as _core_solve_many
-        batch = _core_solve_many(cfg, seeds_arr, iters=iters,
-                                 variant=m.variant, coeffs=coeffs,
-                                 sync_every=m.sync_every, problems=probs,
-                                 n_blocks=_jnp_async_blocks(
-                                     m, cfg.particle_cnt))
-    rows_hist = _row_histories(hists, batch.swarm_cnt)
-    rows_tel = _row_counters(cnts, batch.swarm_cnt)
-    return [Result(problem=probs[s],
-                   config=hetero_member_config(cfg, probs[s]),
-                   method=m, iters=iters, state=batch_row(batch, s),
-                   history=rows_hist[s], telemetry=rows_tel[s])
-            for s in range(batch.swarm_cnt)]
+    with span(SPAN_NAMES.init):
+        batch = init_batch(rcfg, np.asarray(seeds, dtype=np.int64),
+                           rows=rows, table=table)
+    hists, cnts = [], []
+    with span(SPAN_NAMES.launch):
+        if kernel:
+            batch, hist, cnt = _run_batch_kernel(rcfg, batch, iters, m,
+                                                 rows=rows, table=table)
+            if hist is not None:
+                hists.append(hist)
+            if cnt is not None:
+                cnts.append(cnt)
+        elif m.record_history:
+            batch, (its, fits, viols) = run_many_with_history(
+                rcfg, batch, iters, m.variant, coeffs,
+                sync_every=m.sync_every, rows=rows, table=table,
+                n_blocks=_jnp_async_blocks(m, cfg.particle_cnt))
+            hists.append((np.asarray(its, dtype=np.int64),
+                          np.asarray(fits),
+                          None if viols is None else np.asarray(viols)))
+        else:
+            batch = run_many(rcfg, batch, iters, m.variant, coeffs,
+                             sync_every=m.sync_every, rows=rows, table=table,
+                             n_blocks=_jnp_async_blocks(m, cfg.particle_cnt))
+    with span(SPAN_NAMES.rows):
+        rows_hist = _row_histories(hists, batch.swarm_cnt)
+        rows_tel = _row_counters(cnts, batch.swarm_cnt)
+        return [Result(problem=probs[s],
+                       config=hetero_member_config(cfg, probs[s]),
+                       method=m, iters=iters, state=batch_row(batch, s),
+                       history=rows_hist[s], telemetry=rows_tel[s])
+                for s in range(batch.swarm_cnt)]
 
 
 def _reweight_batch(cfg: PSOConfig, batch: SwarmBatch) -> SwarmBatch:

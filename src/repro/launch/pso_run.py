@@ -46,6 +46,8 @@ from repro.core.distributed import (gather_swarm, init_sharded_swarm,
                                     make_distributed_run)
 from repro.runtime import RunnerConfig, StepRunner
 from repro.serving import use_persistent_cache
+from repro.telemetry import (SPAN_NAMES, KernelCounters, TraceWriter,
+                             profiler_session, span)
 from repro import checkpoint as ckpt
 
 
@@ -155,30 +157,14 @@ def main():
         ap.error("--telemetry is single-device; drop --islands")
     trace = metrics = tel = None
     if args.trace_out:
-        from repro.telemetry import TraceWriter
         trace = TraceWriter()
     if args.metrics_out:
         from repro.serving import ServingMetrics
         metrics = ServingMetrics()
 
-    def note_chunk(done, n, t_start):
-        """Record one solve chunk on the trace / metrics sinks."""
-        if trace is None and metrics is None:
-            return
-        jax.block_until_ready(state.gbest_fit)
-        dur_us = (time.perf_counter() - t_start) * 1e6
-        if trace is not None:
-            trace.complete(f"chunk @{done}", t_start * 1e6, dur_us,
-                           process="solver", thread="chunks", cat="solve",
-                           args={"iters": n, "variant": args.variant})
-        if metrics is not None:
-            metrics.observe("chunk_us", dur_us)
-            metrics.inc("chunks")
-
     import contextlib
     prof = contextlib.ExitStack()
     if args.profile_dir:
-        from repro.telemetry import profiler_session
         prof.enter_context(profiler_session(args.profile_dir))
     t0 = time.time()
     if args.islands:
@@ -210,34 +196,32 @@ def main():
             else:
                 step_chunk = lambda st, k: run_queue_lock_fused(
                     cfg, st, iters=k, telemetry=args.telemetry)
-            chunk = args.ckpt_every or args.iters
-            done = 0
-            while done < args.iters:
-                n = min(chunk, args.iters - done)
-                tc = time.perf_counter()
+        else:
+            step_chunk = lambda st, k: run(cfg, st, k, args.variant,
+                                           sync_every=args.sync_every)
+        chunk = args.ckpt_every or args.iters
+        done = 0
+        while done < args.iters:
+            n = min(chunk, args.iters - done)
+            # the sinks time each chunk to its answer on the host
+            with span(SPAN_NAMES.chunk, trace=trace, metrics=metrics,
+                      metric="chunk_us",
+                      event=lambda: (f"chunk @{done + n}", dict(
+                          process="solver", thread="chunks", cat="solve",
+                          args={"iters": n, "variant": args.variant}))):
                 if args.telemetry:
-                    from repro.telemetry import KernelCounters
                     state, cnt = step_chunk(state, n)
                     c = KernelCounters.from_array(cnt)
                     tel = c if tel is None else tel + c
                 else:
                     state = step_chunk(state, n)
-                done += n
-                note_chunk(done, n, tc)
-                if args.ckpt_dir:
-                    ckpt.save(args.ckpt_dir, done, gather_swarm(state))
-        else:
-            chunk = args.ckpt_every or args.iters
-            done = 0
-            while done < args.iters:
-                n = min(chunk, args.iters - done)
-                tc = time.perf_counter()
-                state = run(cfg, state, n, args.variant,
-                            sync_every=args.sync_every)
-                done += n
-                note_chunk(done, n, tc)
-                if args.ckpt_dir:
-                    ckpt.save(args.ckpt_dir, done, gather_swarm(state))
+                if trace is not None or metrics is not None:
+                    jax.block_until_ready(state.gbest_fit)
+            if metrics is not None:
+                metrics.inc("chunks")
+            done += n
+            if args.ckpt_dir:
+                ckpt.save(args.ckpt_dir, done, gather_swarm(state))
     prof.close()
     gf = float(state.gbest_fit)
     dt = time.time() - t0

@@ -63,7 +63,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
@@ -78,13 +77,10 @@ from repro.core.pso import (HeteroRow, PSOConfig, init_swarm_async,
                             run_async, solve)
 from repro.launch.serve import (_HETERO, _HETERO_CANONICAL_FITNESS,
                                 SolveRequest, SolveResult, request_error)
+from repro.telemetry.trace import SPAN_NAMES, now_us, span
 
 from .compile_cache import CompileCache
 from .metrics import ServingMetrics
-
-
-def _now_us() -> float:
-    return time.perf_counter() * 1e6
 
 
 @dataclasses.dataclass
@@ -159,6 +155,12 @@ class ContinuousScheduler:
     row's gbest at its chunk boundaries onto ``SolveResult.history``
     (lane-riding async requests only; standalone fallbacks report None).
 
+    Profiler spans (``repro.telemetry.span``): ``sched_step`` and
+    ``sched_dispatch`` on every step, ``sched_admit`` while requests
+    wait, ``sched_eject``/``sched_finish`` per row leaving a lane. The
+    chunk and standalone events and the ``dispatch_us``/``compile_us``
+    samples are fed from those spans' clock reads.
+
     Single-threaded and synchronous like ``SolveServer``: ``submit`` +
     ``step``/``drain`` (or one-shot ``run``).
     """
@@ -190,7 +192,7 @@ class ContinuousScheduler:
         self._ticket += 1
         self.metrics.inc("submitted")
         self._pending.append(_Active(ticket=t, request=req,
-                                     submitted_us=_now_us()))
+                                     submitted_us=now_us()))
         return t
 
     def _tuned(self, r: SolveRequest) -> SolveRequest:
@@ -299,7 +301,7 @@ class ContinuousScheduler:
             if lane.hetero:
                 lane.rows = set_problem_row(lane.rows, slot, one)
         a.request = r
-        a.admitted_us = _now_us()
+        a.admitted_us = now_us()
         self.metrics.observe("queue_us", a.admitted_us - a.submitted_us)
         self.metrics.inc("admitted")
         if lane.chunks_dispatched:
@@ -316,19 +318,17 @@ class ContinuousScheduler:
 
     # -- standalone fallbacks ---------------------------------------------
     def _solve_standalone(self, a: _Active, r: SolveRequest) -> None:
-        a.admitted_us = _now_us()
+        a.admitted_us = now_us()
         self.metrics.observe("queue_us", a.admitted_us - a.submitted_us)
         cfg = PSOConfig(dim=r.dim, particle_cnt=r.particle_cnt,
                         fitness=r.fitness, dtype=r.dtype,
                         update_rule=r.rule, topology=r._topology_key())
-        t0 = _now_us()
-        st = solve(cfg, r.seed, r.iters, r.variant, r.sync_every)
-        if self.trace is not None:
-            self.trace.complete(
-                f"standalone t{a.ticket}", t0, _now_us() - t0,
-                process="serving", thread="standalone", cat="solve",
-                args={"fitness": str(r.fitness), "variant": r.variant,
-                      "iters": r.iters})
+        with span(SPAN_NAMES.sched_standalone, trace=self.trace,
+                  event=lambda: (f"standalone t{a.ticket}", dict(
+                      process="serving", thread="standalone", cat="solve",
+                      args={"fitness": str(r.fitness), "variant": r.variant,
+                            "iters": r.iters}))):
+            st = solve(cfg, r.seed, r.iters, r.variant, r.sync_every)
         self.metrics.inc("standalone_solves")
         self._finish(a, float(st.gbest_fit), np.asarray(st.gbest_pos),
                      batch_size=1)
@@ -352,7 +352,7 @@ class ContinuousScheduler:
             a.history.append((a.request.iters, float(st.gbest_fit)))
         if self.trace is not None:
             self.trace.instant(
-                f"eject t{a.ticket}", _now_us(), process="serving",
+                f"eject t{a.ticket}", now_us(), process="serving",
                 thread=f"lane {lane.uid}", cat="admission",
                 args={"slot": slot, "remainder": rem})
         self._finish(a, float(st.gbest_fit), np.asarray(st.gbest_pos),
@@ -360,7 +360,7 @@ class ContinuousScheduler:
 
     def _finish(self, a: _Active, gf: float, gp: np.ndarray,
                 batch_size: int) -> None:
-        now = _now_us()
+        now = now_us()
         self.metrics.observe("solve_us", now - a.admitted_us)
         self.metrics.observe("e2e_us", now - a.submitted_us)
         self.metrics.inc("completed")
@@ -401,35 +401,34 @@ class ContinuousScheduler:
         if self.compile_cache is None:
             lane.program = build
         else:
-            t0 = _now_us()
-            lane.program = self.compile_cache.get(
-                lane.program_key(), build, *args)
-            self.metrics.observe("compile_us", _now_us() - t0)
+            with span(SPAN_NAMES.sched_compile, metrics=self.metrics,
+                      metric="compile_us"):
+                lane.program = self.compile_cache.get(
+                    lane.program_key(), build, *args)
         return lane.program
+
+    def _chunk_event(self, lane: _Lane):
+        """The ``TraceWriter`` event of the lane's next dispatch."""
+        return (f"chunk {lane.chunks_dispatched + 1}", dict(
+            process="serving", thread=f"lane {lane.uid}", cat="dispatch",
+            args={"active": lane.active_count, "width": lane.width,
+                  "sync_every": lane.sync_every}))
 
     def _dispatch(self, lane: _Lane) -> None:
         program = self._lane_program(lane)
-        t0 = _now_us()
-        if lane.hetero:
-            out = program(lane.batch, lane.rows)
-        else:
-            out = program(lane.batch)
-        out.gbest_fit.block_until_ready()
-        dur = _now_us() - t0
-        self.metrics.observe("dispatch_us", dur)
+        args = (lane.batch, lane.rows) if lane.hetero else (lane.batch,)
+        with span(SPAN_NAMES.sched_dispatch, metrics=self.metrics,
+                  metric="dispatch_us", trace=self.trace,
+                  event=lambda: self._chunk_event(lane)) as sp:
+            out = program(*args)
+            out.gbest_fit.block_until_ready()
         lane.batch = out
         lane.chunks_dispatched += 1
         self.metrics.inc("dispatches")
         self.metrics.inc("lane_slots", lane.width)
         self.metrics.inc("lane_active_slots", lane.active_count)
         if self.trace is not None:
-            self.trace.complete(
-                f"chunk {lane.chunks_dispatched}", t0, dur,
-                process="serving", thread=f"lane {lane.uid}",
-                cat="dispatch",
-                args={"active": lane.active_count, "width": lane.width,
-                      "sync_every": lane.sync_every})
-            self.trace.counter(f"lane {lane.uid} fill", t0,
+            self.trace.counter(f"lane {lane.uid} fill", sp.t0_us,
                                {"active": lane.active_count,
                                 "idle": lane.width - lane.active_count})
         for i, a in enumerate(lane.slots):
@@ -444,27 +443,34 @@ class ContinuousScheduler:
         """One scheduling round: admit at the boundary, advance every
         active lane one chunk, harvest completions. Returns the results
         that completed this round (also retained for ``drain``/``run``)."""
-        before = set(self._results)
-        self._admit()
-        for lane in list(self._lanes.values()):
-            # Boundary bookkeeping first: rows whose remainder is shorter
-            # than a chunk leave now (standalone finish, proven resume).
-            for i, a in enumerate(lane.slots):
-                if a is None:
+        with span(SPAN_NAMES.sched_step):
+            before = set(self._results)
+            if self._pending:
+                with span(SPAN_NAMES.sched_admit):
+                    self._admit()
+            for lane in list(self._lanes.values()):
+                # Boundary bookkeeping first: rows whose remainder is
+                # shorter than a chunk leave now (standalone finish,
+                # proven resume).
+                for i, a in enumerate(lane.slots):
+                    if a is None:
+                        continue
+                    rem = a.request.iters - a.done
+                    if 0 < rem < lane.sync_every:
+                        with span(SPAN_NAMES.sched_eject):
+                            self._eject(lane, i, rem)
+                if lane.active_count == 0:
                     continue
-                rem = a.request.iters - a.done
-                if 0 < rem < lane.sync_every:
-                    self._eject(lane, i, rem)
-            if lane.active_count == 0:
-                continue
-            self._dispatch(lane)
-            for i, a in enumerate(lane.slots):
-                if a is not None and a.done >= a.request.iters:
-                    gf = float(lane.batch.gbest_fit[i])
-                    gp = np.asarray(lane.batch.gbest_pos[i])
-                    lane.slots[i] = None
-                    self._finish(a, gf, gp, batch_size=lane.width)
-        return {t: r for t, r in self._results.items() if t not in before}
+                self._dispatch(lane)
+                for i, a in enumerate(lane.slots):
+                    if a is not None and a.done >= a.request.iters:
+                        with span(SPAN_NAMES.sched_finish):
+                            gf = float(lane.batch.gbest_fit[i])
+                            gp = np.asarray(lane.batch.gbest_pos[i])
+                            lane.slots[i] = None
+                            self._finish(a, gf, gp, batch_size=lane.width)
+            return {t: r for t, r in self._results.items()
+                    if t not in before}
 
     @property
     def busy(self) -> bool:

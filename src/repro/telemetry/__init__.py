@@ -3,7 +3,7 @@
 The paper's contention story — queue-lock publication vs reduction memory
 traffic — is invisible from outside a fused kernel: the host sees one
 dispatch, not the per-iteration gbest races it resolved. This package
-makes every engine report what it actually did, at three levels:
+makes every engine report what it actually did, at four levels:
 
 1. **Kernel counters** (``counters``): the fused Pallas kernels optionally
    emit per-swarm int32 event counts (queue-best updates, gbest
@@ -30,12 +30,17 @@ makes every engine report what it actually did, at three levels:
    ``pso_run`` CLI (``--telemetry`` / ``--trace-out`` /
    ``--metrics-out``).
 
+4. **Profiler spans** (``trace.span``, names in ``SPAN_NAMES``): every
+   layer boundary of the engines and the scheduler opens a
+   ``jax.profiler.TraceAnnotation``, recorded on the device trace's
+   clock whenever a profiler session runs, and nothing otherwise.
+
 docs/observability.md documents the counter semantics and trace schema.
 """
 from .counters import (COUNTER_NAMES, SLOTS_PER_SWARM, KernelCounters,
                        zero_counts)
 from .prometheus import prometheus_text
-from .trace import TraceWriter, profiler_session
+from .trace import SPAN_NAMES, TraceWriter, profiler_session, span
 
 __all__ = [
     "COUNTER_NAMES",
@@ -43,6 +48,8 @@ __all__ = [
     "KernelCounters",
     "zero_counts",
     "prometheus_text",
+    "SPAN_NAMES",
     "TraceWriter",
     "profiler_session",
+    "span",
 ]

@@ -1,10 +1,20 @@
-"""Chrome trace-event (Perfetto-loadable) writer for solver timelines.
+"""Host spans on the profiler's clock, and a Chrome trace-event
+(Perfetto-loadable) writer for solver timelines.
 
-Emits the JSON object format of the Trace Event spec — a ``traceEvents``
-list of phase-coded events — which both ``chrome://tracing`` and
-https://ui.perfetto.dev load directly. The writer keeps its own
-process/thread registries so callers name rows semantically ("serving" /
-"lane 0") instead of juggling pid/tid integers:
+``span(name, ...)`` is the one way the program opens a host span. It is
+always a ``jax.profiler.TraceAnnotation``, so while a profiler session
+runs (``pso_run --profile-dir``, ``jax.profiler.trace``) the span lands in
+the profiler's host plane, on the same clock as the device trace; with no
+session it records nothing and costs about a microsecond. Its names are
+``SPAN_NAMES``. A call site that also feeds a ``ServingMetrics`` span or a
+``TraceWriter`` event passes them to ``span``, which feeds both from one
+pair of ``perf_counter`` reads.
+
+``TraceWriter`` emits the JSON object format of the Trace Event spec — a
+``traceEvents`` list of phase-coded events — which both
+``chrome://tracing`` and https://ui.perfetto.dev load directly. The
+writer keeps its own process/thread registries so callers name rows
+semantically ("serving" / "lane 0") instead of juggling pid/tid integers:
 
 - ``complete(name, ts_us, dur_us, ...)`` — a span (ph "X"): serving
   requests, lane dispatches, solve chunks.
@@ -12,27 +22,96 @@ process/thread registries so callers name rows semantically ("serving" /
   ejections, publications.
 - ``counter(name, ts_us, values)`` — a counter track (ph "C"): lane fill,
   per-chunk gbest.
-- ``span(name, ...)`` — context manager wrapping a host-side region with
-  ``time.perf_counter`` stamps.
 
-Timestamps are microseconds on any monotonic base; ``to_dict()`` rebases
-them to zero so the timeline starts at t=0 regardless of the clock.
+Timestamps are microseconds on any monotonic base (the program stamps
+``now_us()``); ``to_dict()`` rebases them to zero so the timeline starts
+at t=0 regardless of the clock.
 
 ``profiler_session(logdir)`` optionally brackets a region with a
-``jax.profiler`` trace (XLA-level events alongside ours); it degrades to
-a no-op when the profiler backend is unavailable, so callers never gate
-on it.
+``jax.profiler`` trace, and raises when the trace cannot start.
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 
-def _now_us() -> float:
+def now_us() -> float:
+    """The program's host clock: ``perf_counter`` in microseconds."""
     return time.perf_counter() * 1e6
+
+
+class SpanNames(NamedTuple):
+    """Every host span the program opens, from the entry calls down.
+    Nesting: ``prepare``, ``init``, ``launch`` and ``rows`` inside
+    ``solve``/``solve_many``; ``readback`` in ``Result``'s properties,
+    after the call; ``sched_*`` inside ``sched_step``, except
+    ``sched_standalone``, inside ``sched_admit``."""
+    solve: str = "repro.solve"                # the whole repro.solve call
+    solve_many: str = "repro.solve_many"      # the whole repro.solve_many
+    prepare: str = "repro.prepare"            # problem, method, config
+    init: str = "repro.init"                  # init_swarm / init_batch
+    launch: str = "repro.launch"              # the segment runners
+    rows: str = "repro.rows"                  # solve_many's per-row Results
+    readback: str = "repro.readback"          # Result gbest/best reads
+    sched_step: str = "repro.sched.step"      # ContinuousScheduler.step
+    sched_admit: str = "repro.sched.admit"    # admission of the pending
+    sched_standalone: str = "repro.sched.standalone"  # a fallback solve
+    sched_compile: str = "repro.sched.compile"  # a lane program's build
+    sched_dispatch: str = "repro.sched.dispatch"  # one lane dispatch
+    sched_eject: str = "repro.sched.eject"    # one tail ejection
+    sched_finish: str = "repro.sched.finish"  # one finished row's harvest
+    chunk: str = "repro.chunk"                # one pso_run chunk
+
+
+SPAN_NAMES = SpanNames()
+
+
+class span:
+    """A host span named ``name`` (one of ``SPAN_NAMES``).
+
+    ``metrics``/``metric`` feed a ``ServingMetrics`` span, ``trace`` a
+    ``TraceWriter`` complete event; both from the same two clock reads,
+    and only for a region that did not raise. ``event`` is called at exit,
+    only when ``trace`` is given, so the call site formats nothing on the
+    untraced path; without it the event takes ``name``. After exit,
+    ``t0_us`` and ``dur_us`` hold the reading when a sink was given."""
+
+    __slots__ = ("_name", "_ann", "_metrics", "_metric", "_trace", "_event",
+                 "t0_us", "dur_us")
+
+    def __init__(self, name: str, *, metrics=None, metric: str = "",
+                 trace: Optional["TraceWriter"] = None,
+                 event: Optional[Callable[[], Tuple[str, Dict[str, Any]]]]
+                 = None):
+        self._name = name
+        self._ann = TraceAnnotation(name)
+        self._metrics, self._metric = metrics, metric
+        self._trace, self._event = trace, event
+        self.t0_us = self.dur_us = None
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        if self._metrics is not None or self._trace is not None:
+            self.t0_us = now_us()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self.t0_us is not None:
+            self.dur_us = now_us() - self.t0_us
+        self._ann.__exit__(exc_type, exc, tb)
+        if self.t0_us is None or exc_type is not None:
+            return
+        if self._metrics is not None:
+            self._metrics.observe(self._metric, self.dur_us)
+        if self._trace is not None:
+            name, kw = (self._event() if self._event is not None
+                        else (self._name, {}))
+            self._trace.complete(name, self.t0_us, self.dur_us, **kw)
 
 
 class TraceWriter:
@@ -100,18 +179,6 @@ class TraceWriter:
             "pid": self._pid(process), "tid": 0,
             "args": {k: float(v) for k, v in values.items()},
         })
-
-    @contextlib.contextmanager
-    def span(self, name: str, *, process: str = "solver",
-             thread: str = "main", cat: str = "solve",
-             args: Optional[Dict[str, Any]] = None):
-        """Wrap a host-side region as a complete event."""
-        t0 = _now_us()
-        try:
-            yield self
-        finally:
-            self.complete(name, t0, _now_us() - t0, process=process,
-                          thread=thread, cat=cat, args=args)
 
     @property
     def event_count(self) -> int:

@@ -32,6 +32,29 @@ def test_pso_run_cli_islands_with_checkpoint(tmp_path):
     assert "gbest_fit=" in r.stdout
 
 
+def test_pso_run_cli_sinks_and_profile_spans(tmp_path):
+    """--trace-out/--metrics-out get one event and one sample per chunk,
+    --profile-dir one repro.chunk span per chunk, from the same span."""
+    trace, metrics, prof = (tmp_path / "trace.json", tmp_path / "m.txt",
+                            tmp_path / "prof")
+    r = _run(["-m", "repro.launch.pso_run", "--dim", "2", "--particles",
+              "128", "--iters", "8", "--ckpt-every", "4", "--trace-out",
+              str(trace), "--metrics-out", str(metrics), "--profile-dir",
+              str(prof)])
+    assert r.returncode == 0, r.stderr[-2000:]
+    evs = json.load(open(trace))["traceEvents"]
+    assert [e["name"] for e in evs if e["ph"] == "X"] == ["chunk @4",
+                                                          "chunk @8"]
+    text = open(metrics).read()
+    assert "repro_chunks_total 2" in text
+    assert 'repro_span_latency_microseconds_count{span="chunk_us"} 2' in text
+    from jax.profiler import ProfileData
+    (pb,) = prof.glob("**/*.xplane.pb")
+    names = [ev.name for p in ProfileData.from_file(str(pb)).planes
+             for line in p.lines for ev in line.events]
+    assert names.count("repro.chunk") == 2
+
+
 @pytest.mark.slow
 def test_train_cli_smoke():
     r = _run(["-m", "repro.launch.train", "--arch", "stablelm-3b",

@@ -326,3 +326,136 @@ def test_profiler_session_raises_when_trace_cannot_start(monkeypatch,
             pass
     with profiler_session(None) as started:
         assert started is False
+
+
+# ------------------------------------------------------------ profiler spans
+
+def _profiled_spans(logdir):
+    """``{name: [(start_ns, end_ns), ...]}`` of the ``repro.*`` host events
+    of the one profile under ``logdir``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    files = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1
+    out = {}
+    for p in ProfileData.from_file(files[0]).planes:
+        for line in p.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    out.setdefault(ev.name, []).append(
+                        (int(ev.start_ns), int(ev.end_ns)))
+    return out
+
+
+def _inside(spans, inner, *outer):
+    """Every ``inner`` span lies inside some span named in ``outer``."""
+    around = [iv for name in outer for iv in spans.get(name, ())]
+    return all(any(s0 <= s and e <= e0 for s0, e0 in around)
+               for s, e in spans[inner])
+
+
+def _span_paths():
+    """A kernel and a jnp solve, a hetero ``solve_many`` and three
+    scheduler steps (a row finishing at a chunk boundary, a row ejected
+    with a remainder); returns every answer they gave."""
+    from repro.launch.serve import SolveRequest
+    from repro.serving import ContinuousScheduler
+    out = []
+    for backend in ("kernel", "jnp"):
+        r = solve("cubic", dim=DIM, particles=N, iters=4, seed=SEED,
+                  variant="queue_lock", backend=backend)
+        out += [r.gbest_fit, r.best_fit, r.best_pos]
+    for r in solve_many(problems=["sphere", "rastrigin"], seeds=(1, 2),
+                        dim=DIM, particles=N, iters=4, variant="async",
+                        backend="jnp", sync_every=2):
+        out += [r.best_fit, r.best_pos]
+    sched = ContinuousScheduler(lane_width=4)
+    for iters, seed in ((8, 1), (6, 2)):
+        sched.submit(SolveRequest(fitness="sphere", dim=DIM, particle_cnt=N,
+                                  iters=iters, seed=seed, variant="async",
+                                  sync_every=4))
+    for _ in range(3):
+        out += [r.gbest_fit for r in sched.step().values()]
+    assert not sched.busy
+    return out
+
+
+def test_profiler_spans_nest_on_the_host_plane(tmp_path):
+    import jax
+    from repro.telemetry import SPAN_NAMES
+    with jax.profiler.trace(str(tmp_path)):
+        traced = _span_paths()
+    spans = _profiled_spans(str(tmp_path))
+    names = SPAN_NAMES
+    taken = {names.solve, names.solve_many, names.prepare, names.init,
+             names.launch, names.rows, names.readback, names.sched_step,
+             names.sched_admit, names.sched_dispatch, names.sched_eject,
+             names.sched_finish}
+    assert set(spans) == taken
+    assert len(spans[names.solve]) == 2 and len(spans[names.solve_many]) == 1
+    assert len(spans[names.sched_step]) == 3
+    for inner in (names.prepare, names.init, names.launch):
+        assert _inside(spans, inner, names.solve, names.solve_many), inner
+    assert _inside(spans, names.rows, names.solve_many)
+    for inner in (names.sched_admit, names.sched_dispatch, names.sched_eject,
+                  names.sched_finish):
+        assert _inside(spans, inner, names.sched_step)
+    # readbacks come after the entry calls, not inside them
+    assert not any(_inside({"r": [iv], **spans}, "r", names.solve,
+                           names.solve_many)
+                   for iv in spans[names.readback])
+    # without a session the same calls give the same answers, and an
+    # empty session afterwards holds nothing of them
+    untraced = _span_paths()
+    for a, b in zip(traced, untraced):
+        np.testing.assert_array_equal(a, b)
+    empty = tmp_path / "empty"
+    with jax.profiler.trace(str(empty)):
+        pass
+    assert _profiled_spans(str(empty)) == {}
+
+
+def test_span_feeds_its_sinks_from_one_reading():
+    from repro.telemetry import SPAN_NAMES, span
+    m, tw = ServingMetrics(), TraceWriter()
+    with span(SPAN_NAMES.sched_dispatch, metrics=m, metric="dispatch_us",
+              trace=tw, event=lambda: ("chunk 1", {"process": "serving"})
+              ) as sp:
+        sum(range(1000))
+    assert m.span("dispatch_us").total_us == sp.dur_us > 0
+    (ev,) = [e for e in tw.to_dict()["traceEvents"] if e["ph"] == "X"]
+    assert ev["name"] == "chunk 1" and ev["dur"] == sp.dur_us
+    with span(SPAN_NAMES.chunk, trace=tw):
+        pass
+    assert [e["name"] for e in tw.to_dict()["traceEvents"]
+            if e["ph"] == "X"] == ["chunk 1", SPAN_NAMES.chunk]
+    with pytest.raises(ValueError):      # a region that raised: no sample
+        with span(SPAN_NAMES.sched_dispatch, metrics=m, metric="dispatch_us"):
+            raise ValueError("boom")
+    assert m.span("dispatch_us").count == 1
+    with span(SPAN_NAMES.init) as bare:  # no sink: no clock reads
+        pass
+    assert bare.t0_us is None and bare.dur_us is None
+
+
+def test_span_names_are_the_programs_only_spans():
+    """Every span the program opens names a ``SPAN_NAMES`` field, and every
+    name starts with the prefix the benchmark's trace reduction reads."""
+    import os
+    import re
+    import repro as pkg
+    from repro.telemetry import SPAN_NAMES
+    assert all(n.startswith("repro.") for n in SPAN_NAMES)
+    assert len(set(SPAN_NAMES)) == len(SPAN_NAMES)
+    root = os.path.dirname(pkg.__file__)
+    used = set()
+    for d, _, files in os.walk(root):
+        for f in files:
+            if f.endswith(".py"):
+                src = open(os.path.join(d, f)).read()
+                assert "TraceAnnotation(" not in src or f == "trace.py", f
+                used |= set(re.findall(r"span\(SPAN_NAMES\.(\w+)", src))
+    assert used == set(SPAN_NAMES._fields)
