@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """Bring-up smoke run: drive the solver's main paths once on a TPU.
 
-    python3 chip_smoke.py            # one chip: phases a-e
-    python3 chip_smoke.py --chips 4  # four chips: the island phase only
+    python3 chip_smoke.py                # one chip: phases a-e, init
+    python3 chip_smoke.py --phase init   # one chip: one phase only
+    python3 chip_smoke.py --chips 4      # four chips: the island phase only
 
 Everything runs in this one process, through the entry points a user calls
 (``repro.solve``, ``solve_many``, ``solve_stream``, ``SolveServer``), at the
@@ -16,6 +17,9 @@ paper's sizes with a fixed seed. Phases on one chip:
      (per-row problem) kernels, against the jnp engine.
   d. ``solve_stream``: 24 mixed requests through the continuous scheduler.
   e. ``SolveServer(backend="kernel")``: one flush of mixed requests.
+  init. The compiled swarm init against its op-by-op body (t4's swarm; the
+     CEC functions standalone and mixed): bit for bit but for the fitness
+     fields, which agree within ``INIT_FIT_RTOL``.
 
 With ``--chips 4`` it runs only ``Method(islands=4)`` with the async ring
 and the barrier ``queue`` variant, each against a one-chip jnp solve of the
@@ -78,6 +82,7 @@ SEED = 0
 SCHEDULE_RTOL = 0.10
 SAME_STEPS_RTOL = 1e-3
 EVAL_RTOL = 1e-5
+INIT_FIT_RTOL = 1e-6    # a few f32 ulps: how far a compiled objective goes
 HORIZON = 8
 FULL_ITERS = 200
 ONE_BLOCK = 512         # particles: pick_block_n gives one block up to here
@@ -509,10 +514,81 @@ def phase_islands(phase="islands", islands=4, dim=120, particles=32768,
               one_chip_fit=short["one_chip"], rel_gap=gap, bound=bound)
 
 
+def phase_init(phase="init", seed=2 ** 31 + 12345):
+    """The compiled swarm init against its op-by-op body (``jax.disable_jit``,
+    one XLA program per op): t4's swarm (1-D ``cubic``, 131,072 particles)
+    and the CEC 2005 functions at D = 10, 40 particles, each standalone and
+    all in one heterogeneous batch (each row against its own problem's
+    op-by-op init). Positions, velocities, the gbest position and the
+    integer fields must agree bit for bit. The fitness must agree within
+    ``INIT_FIT_RTOL`` of its largest magnitude (pbest and gbest fitness are
+    copies of it), and the line says how many elements differ:
+    compiled, XLA turns an objective's division by a constant (ackley's
+    mean over D) into a multiplication by its reciprocal, which op by op,
+    with the divisor a runtime scalar, it does not."""
+    import jax
+    import numpy as np
+    from repro.core.multi_swarm import batch_row, init_batch, problem_rows
+    from repro.core.pso import PSOConfig, init_swarm
+
+    fits = ("fit", "pbest_fit", "gbest_fit")
+
+    def same(tag, a, b):
+        exact = [f for f in a._fields if getattr(a, f) is not None
+                 and f not in fits
+                 and not np.array_equal(np.asarray(getattr(a, f)),
+                                        np.asarray(getattr(b, f)))]
+        x, y = (np.asarray(getattr(a, "fit")), np.asarray(getattr(b, "fit")))
+        # relative to the largest magnitude: a fitness near 0 (the cubic's
+        # sum changes sign) has no relative gap of its own
+        rel = float(np.max(np.abs(x - y)) / np.max(np.abs(y)))
+        near = rel <= INIT_FIT_RTOL
+        _check(not exact, f"{tag}: compiled init differs from op-by-op in "
+                          f"{exact}")
+        _check(near, f"{tag}: compiled init fitness off the op-by-op one "
+                     f"by up to {rel:.3g} (bound {INIT_FIT_RTOL})")
+        _emit(phase=phase, check="init_vs_op_by_op", case=tag,
+              fields_differing=exact + [f for f in fits if not np.array_equal(
+                  np.asarray(getattr(a, f)), np.asarray(getattr(b, f)))],
+              fit_elements_differing=int(np.sum(x != y)),
+              fit_elements=int(x.size), fit_max_rel_diff=rel)
+
+    cec = ("sphere", "rosenbrock", "griewank", "ackley", "rastrigin")
+    for fitness, dim, n in [("cubic", 1, 131072)] + [(f, 10, 40)
+                                                    for f in cec]:
+        cfg = PSOConfig(dim=dim, particle_cnt=n, fitness=fitness)
+        compiled = init_swarm(cfg, seed)
+        with jax.disable_jit():
+            eager = init_swarm(cfg, seed)
+        same(f"{fitness}/d{dim}/n{n}", compiled, eager)
+    probs = [f for f in cec for _ in range(5)]
+    seeds = [seed + 101 * i for i in range(len(probs))]
+    rows, table = problem_rows(probs, 10)
+    batch = init_batch(PSOConfig(dim=10, particle_cnt=40).resolved(), seeds,
+                       rows=rows, table=table)
+    for s, (p, sd) in enumerate(zip(probs, seeds)):
+        with jax.disable_jit():
+            eager = init_swarm(PSOConfig(dim=10, particle_cnt=40,
+                                         fitness=p), sd)
+        same(f"hetero/d10/n40/row{s}/{p}", batch_row(batch, s), eager)
+
+
+ONE_CHIP = {"a": lambda: phase_solve("a", "cubic", 1, 131072),
+            "b": lambda: phase_solve("b", "rastrigin", 120, 32768),
+            "b-ring": lambda: phase_ring(),
+            "c": lambda: phase_solve_many(),
+            "d": lambda: phase_stream(),
+            "e": lambda: phase_server(),
+            "init": lambda: phase_init()}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="1: phases a-e on one chip; 4: the island phase")
+                    help="1: phases a-e and init on one chip; 4: the "
+                         "island phase")
+    ap.add_argument("--phase", choices=sorted(ONE_CHIP), action="append",
+                    help="run only this one-chip phase (repeatable)")
     args = ap.parse_args(argv)
 
     import jax
@@ -541,12 +617,7 @@ def main(argv=None) -> int:
     if args.chips == 4:
         phases = [lambda: phase_islands()]
     else:
-        phases = [lambda: phase_solve("a", "cubic", 1, 131072),
-                  lambda: phase_solve("b", "rastrigin", 120, 32768),
-                  lambda: phase_ring(),
-                  lambda: phase_solve_many(),
-                  lambda: phase_stream(),
-                  lambda: phase_server()]
+        phases = [ONE_CHIP[k] for k in (args.phase or ONE_CHIP)]
     t0 = time.perf_counter()
     for phase in phases:
         try:

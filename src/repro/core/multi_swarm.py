@@ -67,7 +67,8 @@ import numpy as np
 
 from .problem import Problem, resolve_problem
 from .pso import (ASYNC_SYNC_EVERY, HeteroRow, PSOConfig, STEP_FNS,
-                  SwarmState, init_swarm, run_async)
+                  SwarmState, _init_program, count_init_trace,
+                  hetero_member_config, run_async, seed_operand)
 
 Array = jnp.ndarray
 
@@ -218,15 +219,46 @@ def init_batch(cfg: PSOConfig, seeds, rows: Optional[ProblemRows] = None,
 
     Row ``s`` is bit-identical to ``init_swarm(cfg, seeds[s])`` (see module
     docstring: the RNG counters are untouched by the vmap). With
-    ``rows``/``table`` (heterogeneous batch) each row instead initializes
-    against its own problem's bounds and objective.
+    ``rows``/``table`` (heterogeneous batch) row ``s`` is instead the
+    standalone init of its own problem (``hetero_member_config``), bit for
+    bit: XLA:CPU compiles an objective behind a ``lax.switch`` differently.
+
+    One compiled program per config and S, the seeds (as uint32,
+    ``pso.seed_operand``) its operand. A heterogeneous batch runs each
+    present problem's program over all S seeds and merges its rows in
+    (``_merge_rows``, one program per shape): the programs are keyed on
+    the problem and S, never on the mix.
     """
     cfg = cfg.resolved()
-    seeds = jnp.asarray(seeds)
+    seeds = seed_operand(seeds)
     if rows is None:
-        return SwarmBatch(*jax.vmap(lambda sd: init_swarm(cfg, sd))(seeds))
-    fn = jax.vmap(lambda sd, f: init_swarm(cfg, sd, hetero=(table, f)))
-    return SwarmBatch(*fn(seeds, _hetero_rows(rows)))
+        return _init_batch_program(cfg, seeds)
+    fid = np.asarray(rows.fid)
+    batch = None
+    for f in np.unique(fid):
+        part = _init_batch_program(hetero_member_config(cfg, table[f]),
+                                   seeds)
+        batch = part if batch is None else _merge_rows(batch, part,
+                                                       fid == f)
+    return batch
+
+
+@partial(jax.jit, static_argnames=("cfg",))
+def _init_batch_program(cfg: PSOConfig, seeds) -> SwarmBatch:
+    count_init_trace()
+    return SwarmBatch(*jax.vmap(lambda sd: _init_program(
+        cfg, sd, 0, n=cfg.particle_cnt, n_blocks=None))(seeds))
+
+
+@jax.jit
+def _merge_rows(batch: SwarmBatch, part: SwarmBatch, take) -> SwarmBatch:
+    """``batch`` with the rows where ``take`` holds replaced by ``part``'s."""
+    count_init_trace()
+
+    def pick(x, y):
+        return jnp.where(take.reshape(take.shape + (1,) * (x.ndim - 1)),
+                         y, x)
+    return SwarmBatch(*jax.tree_util.tree_map(pick, batch, part))
 
 
 def batch_row(batch: SwarmBatch, s: int) -> SwarmState:

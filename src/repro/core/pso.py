@@ -41,6 +41,7 @@ from typing import Callable, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import rng
 from .blocking import default_block_count
@@ -174,7 +175,7 @@ STREAM_R2 = 3
 
 
 # Heterogeneous dispatch convention: ``hetero=(table, row)`` threads through
-# ``init_swarm``/``_advance``/the step functions, where ``table`` is a static
+# ``_advance``/the step functions (not the init), where ``table`` is a static
 # tuple of ``Problem``s (a trace-time Python constant; the jit entry points
 # in ``multi_swarm`` key their cache on it) and ``row`` is a ``HeteroRow`` of
 # traced per-swarm operands: the [] int32 index into the table plus the
@@ -235,7 +236,7 @@ def hetero_member_config(cfg: PSOConfig, prob: Problem) -> PSOConfig:
 
 
 def init_swarm(cfg: PSOConfig, seed: int, n: Optional[int] = None,
-               index_offset: int = 0, hetero=None) -> SwarmState:
+               index_offset: int = 0) -> SwarmState:
     """Initialize a swarm (paper Alg. 1 step 1).
 
     ``n``/``index_offset`` support sharded construction: a shard owning
@@ -243,50 +244,102 @@ def init_swarm(cfg: PSOConfig, seed: int, n: Optional[int] = None,
     corresponding slice of a monolithic swarm (elastic resharding invariant,
     tested in tests/test_distributed.py).
 
-    ``hetero=(table, row)`` draws from the same streams but takes the box
-    from the row's runtime bound columns and the objective from the table
-    dispatch (the heterogeneous batch engine, ``multi_swarm.solve_many``
-    with per-row problems); ``None`` keeps the exact homogeneous trace.
+    One compiled program per static key (config, ``n``; see
+    ``_init_program``), the seed and ``index_offset`` its operands, so a
+    new seed never retraces. Inside a trace (shard_map's ``per_shard``)
+    the program inlines into the caller's.
     """
     cfg = cfg.resolved()
-    n = cfg.particle_cnt if n is None else n
+    return _init_program(cfg, seed_operand(seed), index_offset,
+                         n=cfg.particle_cnt if n is None else n,
+                         n_blocks=None)
+
+
+@partial(jax.jit, static_argnames=("cfg", "n", "n_blocks"))
+def _init_program(cfg: PSOConfig, seed, index_offset, n: int,
+                  n_blocks: Optional[int]) -> SwarmState:
+    """One swarm's init as one XLA program: RNG draws, box scaling,
+    objective, argmax (and, with ``n_blocks``, the async locals)."""
+    count_init_trace()
     d = cfg.dim
     dt = cfg.jnp_dtype
     idx = (jnp.arange(n * d, dtype=jnp.uint32).reshape(n, d)
-           + jnp.uint32(index_offset * d))
+           + jnp.asarray(index_offset).astype(jnp.uint32) * jnp.uint32(d))
     u_pos = rng.uniform(seed, 0, STREAM_INIT_POS, idx, dtype=dt)
     u_vel = rng.uniform(seed, 0, STREAM_INIT_VEL, idx, dtype=dt)
-    if hetero is not None:
-        lo, hi, mv = hetero[1].lo, hetero[1].hi, hetero[1].mv
-    else:
-        lo = _bound_operand(cfg.min_pos, dt)
-        hi = _bound_operand(cfg.max_pos, dt)
-        mv = _bound_operand(cfg.max_v, dt)
+    lo = _bound_operand(cfg.min_pos, dt)
+    hi = _bound_operand(cfg.max_pos, dt)
+    mv = _bound_operand(cfg.max_v, dt)
     span = hi - lo
-    pos = lo + span * u_pos
-    vel = -mv + 2.0 * mv * u_vel
+    pos = lo + _rounded(span * u_pos)
+    vel = -mv + _rounded(2.0 * mv * u_vel)
     prob = cfg.problem
-    proj = prob.projection_fn if hetero is None else None
-    if proj is not None:
+    if prob.projection_fn is not None:
         # projection mode: start feasible (box draw projected in-place)
-        pos = proj(pos)
-    elif hetero is None and prob.constrained and \
-            prob.constraints.mode == "repair":
+        pos = prob.projection_fn(pos)
+    elif prob.constrained and prob.constraints.mode == "repair":
         # repair mode: resample infeasible draws (attempt-indexed RNG on
         # the init stream; see constraints.repair_init_positions)
         pos = repair_init_positions(
             prob.constraints, prob.violation_fn, pos, lo, span, seed,
             STREAM_INIT_POS, idx, dt)
-    fit = (cfg.fitness_fn(pos) if hetero is None
-           else _hetero_fitness(hetero[0], hetero[1].fid, pos))
+    fit = cfg.fitness_fn(pos)
     best = jnp.argmax(fit)
-    return SwarmState(
+    s = SwarmState(
         pos=pos, vel=vel, fit=fit,
         pbest_pos=pos, pbest_fit=fit,
         gbest_pos=pos[best], gbest_fit=fit[best],
         iteration=jnp.zeros((), jnp.int32),
         seed=jnp.asarray(seed, jnp.uint32),
     )
+    if n_blocks is None:
+        return s
+    lbp, lbf = init_async_locals(s, n_blocks)
+    return s._replace(lbest_pos=lbp, lbest_fit=lbf)
+
+
+def _rounded(x: Array) -> Array:
+    """``x`` itself, as a value no compiler contracts into the add that
+    follows: each init product is rounded on its own, as op-by-op
+    execution and the TPU do. XLA:CPU otherwise fuses ``lo + span * u``
+    into an FMA in one program and not in another (bounds as constants or
+    as runtime columns, the oracles' op-by-op init), and one seed would
+    start from positions an ulp apart. The max with the dtype's lowest
+    finite value is exact for every product that occurs."""
+    return jnp.maximum(x, jnp.finfo(x.dtype).min)
+
+
+_init_traces = 0
+
+
+def init_traces() -> int:
+    """How many times a compiled init program (``_init_program``,
+    ``multi_swarm._init_batch_program`` and ``_merge_rows``) has been
+    traced in this process: one per new static key, so a count that grows
+    with the seeds served means the init cache is missing
+    (``repro.telemetry.init_traces``). The programs' cache is the
+    process's, and so is this count. Under ``jax.disable_jit()`` nothing
+    is compiled and nothing is counted."""
+    return _init_traces
+
+
+def count_init_trace() -> None:
+    """Called at trace time by each compiled init program."""
+    global _init_traces
+    if not jax.config.jax_disable_jit:
+        _init_traces += 1
+
+
+def seed_operand(seed) -> np.ndarray:
+    """A host seed as the uint32 the RNG keys on.
+
+    Exactly the bits of ``jnp.asarray(seed).astype(uint32)`` (two's
+    complement, modulo 2**32), for Python ints, numpy integers of any
+    width and arrays of them alike, as one dtype: the compiled init
+    programs see the same abstract operand for every seed and never
+    retrace for a new value or integer type.
+    """
+    return (np.asarray(seed).astype(np.int64) & 0xFFFFFFFF).astype(np.uint32)
 
 
 def _advance(cfg: PSOConfig, s: SwarmState, index_offset: int = 0,
@@ -493,8 +546,7 @@ def init_async_locals(state: SwarmState, n_blocks: int
 
 
 def init_swarm_async(cfg: PSOConfig, seed: int,
-                     n_blocks: Optional[int] = None,
-                     hetero=None) -> SwarmState:
+                     n_blocks: Optional[int] = None) -> SwarmState:
     """``init_swarm`` with the async block-local bests already attached.
 
     The serving scheduler's admission seam: a freshly admitted request's
@@ -506,12 +558,13 @@ def init_swarm_async(cfg: PSOConfig, seed: int,
     the carried-locals resume path and the fresh-seed path coincide at
     phase 0 — so an admitted row is bit-identical to the standalone
     solve of its request (tests/test_serving.py).
+
+    One compiled program, like ``init_swarm``.
     """
     cfg = cfg.resolved()
-    s = init_swarm(cfg, seed, hetero=hetero)
-    nb = n_blocks or _default_async_blocks(s.pos.shape[0])
-    lbp, lbf = init_async_locals(s, nb)
-    return s._replace(lbest_pos=lbp, lbest_fit=lbf)
+    return _init_program(
+        cfg, seed_operand(seed), 0, n=cfg.particle_cnt,
+        n_blocks=n_blocks or _default_async_blocks(cfg.particle_cnt))
 
 
 def step_async(cfg: PSOConfig, s: SwarmState,

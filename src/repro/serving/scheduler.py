@@ -73,8 +73,9 @@ from repro.core.multi_swarm import (MIN_VALIDATED_SWARMS, ProblemRows,
                                     batch_row, hetero_fid, problem_rows,
                                     run_many, set_batch_row, set_problem_row,
                                     stack_states)
-from repro.core.pso import (HeteroRow, PSOConfig, init_swarm_async,
-                            run_async, solve)
+from repro.core.problem import resolve_problem
+from repro.core.pso import (HeteroRow, PSOConfig, hetero_member_config,
+                            init_swarm_async, run_async, solve)
 from repro.launch.serve import (_HETERO, _HETERO_CANONICAL_FITNESS,
                                 SolveRequest, SolveResult, request_error)
 from repro.telemetry.trace import SPAN_NAMES, now_us, span
@@ -208,7 +209,6 @@ class ContinuousScheduler:
         """Like ``SolveRequest.group_key`` but WITHOUT ``iters`` — per-row
         accounting means mixed budgets share a lane."""
         hetero = self.coalesce_registry and hetero_fid(r.fitness) is not None
-        from repro.core.problem import resolve_problem
         content = _HETERO if hetero else resolve_problem(
             r.fitness).cache_key()
         return (r.dim, r.particle_cnt, r.dtype, r.sync_every,
@@ -279,13 +279,13 @@ class ContinuousScheduler:
         """A fresh row for the lane, locals seeded (phase 0, iteration 0)."""
         if not lane.hetero:
             return init_swarm_async(lane.cfg, r.seed, n_blocks=lane.nb), None
-        one, table = problem_rows([r.fitness], lane.cfg.dim, lane.cfg.dtype)
+        prob = resolve_problem(r.fitness)
+        one, table = problem_rows([prob], lane.cfg.dim, lane.cfg.dtype)
         if lane.table is None:
             lane.table = table
-        hr = HeteroRow(fid=one.fid[0], lo=one.lo[0], hi=one.hi[0],
-                       mv=one.mv[0])
-        return init_swarm_async(lane.cfg, r.seed, n_blocks=lane.nb,
-                                hetero=(table, hr)), one
+        # a row starts as its problem's standalone init (as init_batch's)
+        return init_swarm_async(hetero_member_config(lane.cfg, prob), r.seed,
+                                n_blocks=lane.nb), one
 
     def _splice(self, lane: _Lane, slot: int, a: _Active,
                 r: SolveRequest) -> None:

@@ -34,6 +34,8 @@ makes every engine report what it actually did, at four levels:
    layer boundary of the engines and the scheduler opens a
    ``jax.profiler.TraceAnnotation``, recorded on the device trace's
    clock whenever a profiler session runs, and nothing otherwise.
+   ``init_traces()`` counts the traces of the compiled swarm-init
+   programs: how often their cache missed.
 
 docs/observability.md documents the counter semantics and trace schema.
 """
@@ -42,7 +44,17 @@ from .counters import (COUNTER_NAMES, SLOTS_PER_SWARM, KernelCounters,
 from .prometheus import prometheus_text
 from .trace import SPAN_NAMES, TraceWriter, profiler_session, span
 
+
+def init_traces() -> int:
+    """Traces of the compiled swarm-init programs so far in this process
+    (``repro.core.pso.init_traces``): one per config and shape, so a
+    count that grows with the seeds served means the init cache misses."""
+    from repro.core.pso import init_traces as count
+    return count()
+
+
 __all__ = [
+    "init_traces",
     "COUNTER_NAMES",
     "SLOTS_PER_SWARM",
     "KernelCounters",
