@@ -132,9 +132,14 @@ def run_queue_lock_fused(cfg: PSOConfig, s: SwarmState, iters: int,
                          telemetry: bool = False):
     """``iters`` iterations in ONE pallas_call (fused queue-lock, §4.2+).
 
-    On TPU this is the roofline-relevant path: state stays resident, the
-    global best is published in-kernel under sequential-grid serialization,
-    and there are zero kernel launches or HBM round-trips per iteration.
+    One launch for every iteration: the global best is published in-kernel
+    under sequential-grid serialization and stays on the chip throughout.
+    A swarm of one block keeps its state resident in VMEM for the whole
+    launch. A swarm of several blocks streams it: each grid step copies its
+    block of positions, velocities and pbests HBM -> VMEM and back, waiting
+    on both copies, so a launch moves ``iters x nb x 2 x (3 Dpad + 1) x
+    block_n x itemsize`` bytes. The builder records that count under the
+    kernel's name (``repro.telemetry.kernel_streams()``).
 
     ``telemetry=True`` returns ``(state, counts)`` where ``counts`` is the
     in-kernel contention counter buffer ([3] int32 — see

@@ -165,6 +165,7 @@ from repro.core.pso import STREAM_R1, STREAM_R2
 from repro.core.problem import Problem
 from repro.core.topology import kernel_neighbor_ids
 from repro.core.update_rules import resolve_rule
+from repro.telemetry.streams import record_sync
 
 SUBLANE = 8
 _BIG_I32 = np.int32(2 ** 30)
@@ -1040,9 +1041,12 @@ def fused_call(n: int, d: int, iters: int, block_n: int, dtype, *,
         out_specs.append(gfs)
         out_shape.append(jax.ShapeDtypeStruct((3,), jnp.int32))
         aliases[7 + len(consts)] = 6
+    grid, name = (iters, nb), "cupso_fused_queue_lock"
+    record_sync(name, grid, swarms=1, streamed=dma, dpad=dpad,
+                block_n=block_n, dtype=dtype)
     call = pl.pallas_call(
         kern,
-        grid=(iters, nb),
+        grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -1050,7 +1054,7 @@ def fused_call(n: int, d: int, iters: int, block_n: int, dtype, *,
         scratch_shapes=scratch,
         compiler_params=_arbitrary(2),
         interpret=interpret,
-        name="cupso_fused_queue_lock",
+        name=name,
     )
     if telemetry:
         # counts is the caller's LAST positional arg; consts slot in
@@ -1109,9 +1113,12 @@ def fused_batch_call(s_cnt: int, n: int, d: int, iters: int, block_n: int,
         out_specs.append(smem)
         out_shape.append(jax.ShapeDtypeStruct((3 * s_cnt,), jnp.int32))
         aliases[8 + len(consts)] = 6
+    grid, name = (s_cnt, iters, nb), "cupso_fused_queue_lock_batch"
+    record_sync(name, grid, swarms=s_cnt, streamed=dma, dpad=dpad,
+                block_n=block_n, dtype=dtype)
     call = pl.pallas_call(
         kern,
-        grid=(s_cnt, iters, nb),
+        grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -1119,7 +1126,7 @@ def fused_batch_call(s_cnt: int, n: int, d: int, iters: int, block_n: int,
         scratch_shapes=scratch,
         compiler_params=_arbitrary(3),
         interpret=interpret,
-        name="cupso_fused_queue_lock_batch",
+        name=name,
     )
     if telemetry:
         return lambda *args: call(*args[:-1], *consts, args[-1])
@@ -1204,9 +1211,12 @@ def hetero_fused_batch_call(s_cnt: int, n: int, d: int, iters: int,
         out_specs.append(smem)
         out_shape.append(jax.ShapeDtypeStruct((3 * s_cnt,), jnp.int32))
         aliases[9] = 6
+    grid, name = (s_cnt, iters, nb), "cupso_hetero_fused_queue_lock_batch"
+    record_sync(name, grid, swarms=s_cnt, streamed=dma, dpad=dpad,
+                block_n=block_n, dtype=dtype)
     return pl.pallas_call(
         kern,
-        grid=(s_cnt, iters, nb),
+        grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -1214,7 +1224,7 @@ def hetero_fused_batch_call(s_cnt: int, n: int, d: int, iters: int,
         scratch_shapes=scratch,
         compiler_params=_arbitrary(3),
         interpret=interpret,
-        name="cupso_hetero_fused_queue_lock_batch",
+        name=name,
     )
 
 

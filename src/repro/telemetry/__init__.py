@@ -3,7 +3,7 @@
 The paper's contention story — queue-lock publication vs reduction memory
 traffic — is invisible from outside a fused kernel: the host sees one
 dispatch, not the per-iteration gbest races it resolved. This package
-makes every engine report what it actually did, at four levels:
+makes every engine report what it actually did, at five levels:
 
 1. **Kernel counters** (``counters``): the fused Pallas kernels optionally
    emit per-swarm int32 event counts (queue-best updates, gbest
@@ -37,11 +37,18 @@ makes every engine report what it actually did, at four levels:
    ``init_traces()`` counts the traces of the compiled swarm-init
    programs: how often their cache missed.
 
+5. **Kernel stream accounts** (``streams``): each build of a synchronous
+   fused kernel records, under its kernel name, the grid steps of one
+   launch and the bytes one launch moves between HBM and VMEM
+   (``kernel_streams()``), counted from the layout the builder chose:
+   state streamed block by block every grid step, or resident.
+
 docs/observability.md documents the counter semantics and trace schema.
 """
 from .counters import (COUNTER_NAMES, SLOTS_PER_SWARM, KernelCounters,
                        zero_counts)
 from .prometheus import prometheus_text
+from .streams import StreamAccount, kernel_streams
 from .trace import SPAN_NAMES, TraceWriter, profiler_session, span
 
 
@@ -55,6 +62,8 @@ def init_traces() -> int:
 
 __all__ = [
     "init_traces",
+    "kernel_streams",
+    "StreamAccount",
     "COUNTER_NAMES",
     "SLOTS_PER_SWARM",
     "KernelCounters",
