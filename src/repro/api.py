@@ -51,7 +51,7 @@ from typing import List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from repro.core.multi_swarm import (SwarmBatch, batch_row, init_batch,
+from repro.core.multi_swarm import (SwarmBatch, host_rows, init_batch,
                                     run_many, run_many_with_history)
 from repro.core.problem import Problem, resolve_problem
 from repro.core.pso import (ASYNC_SYNC_EVERY, PSOConfig, SwarmState,
@@ -254,6 +254,9 @@ class History:
 class Result:
     """A finished solve. ``best_fit``/``best_pos`` are in the problem's own
     sense; ``state`` is the raw (canonical-max) SwarmState for resuming.
+    A ``solve_many`` row's ``state`` holds host numpy arrays (the batch is
+    fetched once and split on the host), a ``solve``'s device arrays; the
+    engines and ``stack_states`` take either, with the same results.
 
     Constrained problems additionally report ``feasible``/``violation``
     (the Deb-rule inputs — see ``repro.core.constraints``), and
@@ -641,6 +644,12 @@ def solve_many(problem: Union[str, Problem, None] = None,
     exactness surface is ``gbest_pos``/``gbest_fit`` (see
     ``repro.core.pso``'s heterogeneous-dispatch notes for the full
     envelope).
+
+    The finished batch reaches the host in one transfer and is split
+    there: every row's ``Result.state`` holds numpy arrays, bit-identical
+    to ``repro.core.batch_row`` of the device batch, so ``best_fit``,
+    ``best_pos`` and ``gbest_fit`` read no device. Resuming from such rows
+    (``stack_states`` into ``run_many``) gives the device rows' results.
     """
     with span(SPAN_NAMES.solve_many):
         with span(SPAN_NAMES.prepare):
@@ -678,12 +687,13 @@ def solve_many(problem: Union[str, Problem, None] = None,
             batch, hists = _ramp_loop(prob, cfg, batch, iters, seg,
                                       _reweight_batch)
         with span(SPAN_NAMES.rows):
-            rows_hist = _row_histories(hists, batch.swarm_cnt)
-            rows_tel = _row_counters(cnts, batch.swarm_cnt)
+            states = host_rows(batch)
+            rows_hist = _row_histories(hists, len(states))
+            rows_tel = _row_counters(cnts, len(states))
             return [Result(problem=prob, config=cfg, method=m, iters=iters,
-                           state=batch_row(batch, s), history=rows_hist[s],
+                           state=st, history=rows_hist[s],
                            telemetry=rows_tel[s])
-                    for s in range(batch.swarm_cnt)]
+                    for s, st in enumerate(states)]
 
 
 def _row_histories(hists, s_cnt: int) -> List[Optional[History]]:
@@ -767,13 +777,14 @@ def _solve_many_hetero(problems, seeds, m: Method, dim, particles, iters,
                              sync_every=m.sync_every, rows=rows, table=table,
                              n_blocks=_jnp_async_blocks(m, cfg.particle_cnt))
     with span(SPAN_NAMES.rows):
-        rows_hist = _row_histories(hists, batch.swarm_cnt)
-        rows_tel = _row_counters(cnts, batch.swarm_cnt)
+        states = host_rows(batch)
+        rows_hist = _row_histories(hists, len(states))
+        rows_tel = _row_counters(cnts, len(states))
         return [Result(problem=probs[s],
                        config=hetero_member_config(cfg, probs[s]),
-                       method=m, iters=iters, state=batch_row(batch, s),
+                       method=m, iters=iters, state=st,
                        history=rows_hist[s], telemetry=rows_tel[s])
-                for s in range(batch.swarm_cnt)]
+                for s, st in enumerate(states)]
 
 
 def _reweight_batch(cfg: PSOConfig, batch: SwarmBatch) -> SwarmBatch:

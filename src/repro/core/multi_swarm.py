@@ -59,7 +59,7 @@ compile keys to batch identical custom objectives safely.
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple, Optional, Sequence, Tuple, Union
+from typing import List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -264,6 +264,21 @@ def _merge_rows(batch: SwarmBatch, part: SwarmBatch, take) -> SwarmBatch:
 def batch_row(batch: SwarmBatch, s: int) -> SwarmState:
     """Extract swarm ``s`` as a standalone SwarmState."""
     return SwarmState(*(jax.tree_util.tree_map(lambda a: a[s], tuple(batch))))
+
+
+def host_rows(batch: SwarmBatch) -> List[SwarmState]:
+    """Fetch a finished batch to the host in ONE transfer and split it into
+    standalone swarms whose fields are numpy views of the fetched arrays
+    (``lbest_*`` stay None when the batch has none).
+
+    Row ``s`` holds the same bytes as ``batch_row(batch, s)``, without an
+    eager device slice per field and row: the batched facade's split of a
+    finished call. ``batch_row`` stays the device-side extraction for a
+    batch that keeps running (the scheduler's ejection)."""
+    host = jax.device_get(batch)
+    # a[s, ...] keeps a 0-d array where a[s] would give a numpy scalar
+    return [SwarmState(*(None if a is None else a[s, ...] for a in host))
+            for s in range(host.swarm_cnt)]
 
 
 def stack_states(states: Sequence[SwarmState]) -> SwarmBatch:
