@@ -3,12 +3,11 @@
 The serving claim under test (docs/serving.md): a stream with staggered
 arrivals and MIXED iteration budgets fragments the flush server — its
 group keys include ``iters``, so a wave of async requests at one solve
-shape but four different budgets splits into four padded groups, each
-bucket-padded up to ``MIN_VALIDATED_SWARMS`` rows and each running its
-full budget on mostly-dead rows. The continuous scheduler's lane keys
-DROP ``iters`` (accounting is per row), so the same trace rides one full
-persistent lane and completed rows hand their slot to the next arrival
-at a chunk boundary.
+shape but four different budgets splits into four groups, each padded
+up to its bucket and each running its full budget on mostly-dead rows.
+The continuous scheduler's lane keys DROP ``iters`` (accounting is per
+row), so the same trace rides one full persistent lane and completed
+rows hand their slot to the next arrival at a chunk boundary.
 
 Both legs run the identical trace with the identical wave structure (a
 wave of arrivals, then one scheduling opportunity: ``flush()`` vs

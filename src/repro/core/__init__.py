@@ -12,9 +12,8 @@ from .pso import (ASYNC_SYNC_EVERY, PSOConfig, SwarmState, STEP_FNS,
                   init_swarm, publish_async_locals, run, run_async,
                   run_with_history, solve, step_async, step_queue,
                   step_queue_lock, step_reduction)
-from .multi_swarm import (MIN_VALIDATED_SWARMS, SwarmBatch, batch_row,
-                          best_of_batch, init_batch, run_many, solve_many,
-                          stack_states)
+from .multi_swarm import (SwarmBatch, batch_row, best_of_batch, init_batch,
+                          run_many, solve_many, stack_states)
 from .serial import SerialSwarm, run_serial_fast
 from .topology import block_neighbor_best, grid_dims
 from .tuner import (PSO_COEFF_DIMS, PSOTuner, SearchDim, TunerResult,
@@ -35,7 +34,7 @@ __all__ = [
     "init_async_locals", "publish_async_locals", "flush_async_locals",
     "step_queue", "step_queue_lock", "step_reduction",
     "SwarmBatch", "init_batch", "batch_row", "stack_states", "run_many",
-    "solve_many", "best_of_batch", "MIN_VALIDATED_SWARMS",
+    "solve_many", "best_of_batch",
     "SerialSwarm", "run_serial_fast",
     "block_neighbor_best", "grid_dims",
     "UpdateRule", "UPDATE_RULES", "TOPOLOGIES", "resolve_rule",

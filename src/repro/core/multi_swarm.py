@@ -1,5 +1,5 @@
 """Batched multi-swarm engine: many independent PSO solves in ONE device
-program (DESIGN: the scaling layer on top of the paper's single-swarm
+program (the scaling layer on top of the paper's single-swarm
 queue/queue-lock algorithms).
 
 The paper (arXiv 2205.01313) amortizes aggregation cost *within* one swarm;
@@ -16,28 +16,23 @@ RNG stream convention
 Each swarm carries its own ``seed`` and the counter RNG is keyed by
 ``(seed, iteration, stream, element_index)`` with element indices local to
 the swarm (particle * D + dim, exactly the single-swarm ``index_offset=0``
-convention of ``init_swarm``/``_advance``). Because vmap changes neither the
-counters nor the arithmetic, row ``s`` of a batch is **bit-identical** to a
-standalone ``solve(cfg, seeds[s])`` — batching is a pure scheduling
-transform, never a semantic one. This is asserted exactly (``==`` on
-float bits) in tests/test_multi_swarm.py.
+convention of ``init_swarm``/``_advance``). vmap changes neither the
+counters nor the update, so row ``s`` of a batch computes the trajectory
+of a standalone ``solve(cfg, seeds[s])``: batching is a scheduling
+transform, never a semantic one.
 
-Caveat (CPU backend): XLA:CPU chooses loop-body fusion + FMA contraction
-per compiled shape, and for a few tiny batch shapes the batched program
-rounds the velocity chain one ulp differently from the standalone program,
-which chaotic PSO dynamics then amplify. Root cause (isolated at S=4,
-dim=3, n=64, sphere): ``vel`` diverges on the SECOND iteration inside one
-``fori_loop`` program while separate per-iteration dispatches stay
-bit-identical — i.e. the in-loop fusion, not the vmapped step, makes the
-shape-dependent contraction choice; and pinning the loop carry with
-``optimization_barrier`` merely moves the anomaly to other shapes (S=3).
-The pin therefore lives at the dispatch level: ``run_many`` pads batches
-smaller than ``MIN_VALIDATED_SWARMS`` (= 8) with dead rows and slices the
-result back, so every dispatch runs a validated program shape and the
-serving layer buckets at 4 again. This also constrains step-function
-design: a ``lax.cond`` carrying an [N, D] branch output changes XLA's
-fusion clustering enough to break the identity at *every* batch size (see
-``step_queue_lock``).
+Agreement
+---------
+The batched program and the standalone one are different compiled
+programs. XLA:CPU chooses loop-body fusion and FMA contraction per
+compiled shape, while the chip and the eager oracles round op by op, so a
+row and its standalone solve agree within an ulp or two a step, which PSO's
+chaotic dynamics can grow over a long run. tests/agreement.py states the
+limits (the benchmark's own): positions and velocities within 1e-5 of the
+box, fitness values within 1e-5 relative, and for runs long enough to flip
+a pbest comparison, the outcome (gbest value, and the reported value
+against the objective at the reported position). A batch of any size runs
+as it is; no row is added.
 
 Per-swarm hyper-parameters
 --------------------------
@@ -67,8 +62,8 @@ import numpy as np
 
 from .problem import Problem, resolve_problem
 from .pso import (ASYNC_SYNC_EVERY, HeteroRow, PSOConfig, STEP_FNS,
-                  SwarmState, _init_program, count_init_trace,
-                  hetero_member_config, run_async, seed_operand)
+                  SwarmState, _init_body, count_init_trace, run_async,
+                  seed_operand)
 
 Array = jnp.ndarray
 
@@ -81,8 +76,8 @@ class ProblemRows(NamedTuple):
     ``fid[s]`` indexes the table, and the bound columns replicate exactly
     the arithmetic ``PSOConfig.resolved()`` would have produced for row
     ``s``'s problem (Python-float64 ``0.5 * (hi - lo)`` then a cast), so a
-    heterogeneous row is bit-identical to the standalone solve of its
-    problem. ``sense``/``cmode``/``pweight`` are descriptor metadata —
+    heterogeneous row has the box of the standalone solve of its problem.
+    ``sense``/``cmode``/``pweight`` are descriptor metadata —
     constant for the built-in table (max-sense, unconstrained, weight 0) —
     reserved for the two-tier custom-objective follow-on where penalty-mode
     registry entries join the table.
@@ -162,7 +157,7 @@ def problem_rows(problems: Sequence, dim: int, dtype: str = "float32",
                 "dispatch table; solve it in its own (content-keyed) batch"
             ) from None
         # Exactly the standalone bound resolution (max_v = 0.5 * (hi - lo)
-        # in Python float64, then one cast) — the row-bit-identity contract.
+        # in Python float64, then one cast).
         r = PSOConfig(dim=dim, fitness=prob, dtype=dtype).resolved()
         fid.append(i)
         lo.append(_row_bound(r.min_pos, dim, dt))
@@ -217,48 +212,49 @@ def init_batch(cfg: PSOConfig, seeds, rows: Optional[ProblemRows] = None,
                table: Optional[Tuple[Problem, ...]] = None) -> SwarmBatch:
     """Initialize S swarms, one per entry of ``seeds``.
 
-    Row ``s`` is bit-identical to ``init_swarm(cfg, seeds[s])`` (see module
-    docstring: the RNG counters are untouched by the vmap). With
-    ``rows``/``table`` (heterogeneous batch) row ``s`` is instead the
-    standalone init of its own problem (``hetero_member_config``), bit for
-    bit: XLA:CPU compiles an objective behind a ``lax.switch`` differently.
+    Row ``s`` is the init of ``init_swarm(cfg, seeds[s])``: the RNG counters
+    are untouched by the vmap. With ``rows``/``table`` (heterogeneous
+    batch) row ``s`` takes its box from its row's columns and its objective
+    through the table's switch, as the run does, so its positions are its
+    problem's standalone init.
 
-    One compiled program per config and S, the seeds (as uint32,
-    ``pso.seed_operand``) its operand. A heterogeneous batch runs each
-    present problem's program over all S seeds and merges its rows in
-    (``_merge_rows``, one program per shape): the programs are keyed on
-    the problem and S, never on the mix.
+    One compiled program per config, S and table, the seeds (as uint32,
+    ``pso.seed_operand``) and the rows its operands: a new mix of problems
+    compiles nothing.
     """
     cfg = cfg.resolved()
     seeds = seed_operand(seeds)
-    if rows is None:
-        return _init_batch_program(cfg, seeds)
-    fid = np.asarray(rows.fid)
-    batch = None
-    for f in np.unique(fid):
-        part = _init_batch_program(hetero_member_config(cfg, table[f]),
-                                   seeds)
-        batch = part if batch is None else _merge_rows(batch, part,
-                                                       fid == f)
-    return batch
+    hr = None if rows is None else _hetero_rows(rows)
+    return _init_batch_program(cfg, seeds, hr, table, None)
 
 
-@partial(jax.jit, static_argnames=("cfg",))
-def _init_batch_program(cfg: PSOConfig, seeds) -> SwarmBatch:
+@partial(jax.jit, static_argnames=("cfg", "table", "n_blocks"))
+def _init_batch_program(cfg: PSOConfig, seeds, hr: Optional[HeteroRow],
+                        table, n_blocks: Optional[int]) -> SwarmBatch:
     count_init_trace()
-    return SwarmBatch(*jax.vmap(lambda sd: _init_program(
-        cfg, sd, 0, n=cfg.particle_cnt, n_blocks=None))(seeds))
+    n = cfg.particle_cnt
+    if hr is None:
+        return SwarmBatch(*jax.vmap(lambda sd: _init_body(
+            cfg, sd, 0, n, n_blocks))(seeds))
+    return SwarmBatch(*jax.vmap(lambda sd, r: _init_body(
+        cfg, sd, 0, n, n_blocks, hetero=(table, r)))(seeds, hr))
 
 
-@jax.jit
-def _merge_rows(batch: SwarmBatch, part: SwarmBatch, take) -> SwarmBatch:
-    """``batch`` with the rows where ``take`` holds replaced by ``part``'s."""
-    count_init_trace()
+def init_row(cfg: PSOConfig, seed, row: ProblemRows,
+             table: Tuple[Problem, ...],
+             n_blocks: Optional[int] = None) -> SwarmState:
+    """A one-row hetero ``init_batch``, as a standalone swarm: the same
+    program at S=1 and its row, in one dispatch (the scheduler's hetero
+    admission)."""
+    return _init_row_program(cfg.resolved(), seed_operand([seed]),
+                             _hetero_rows(row), table, n_blocks)
 
-    def pick(x, y):
-        return jnp.where(take.reshape(take.shape + (1,) * (x.ndim - 1)),
-                         y, x)
-    return SwarmBatch(*jax.tree_util.tree_map(pick, batch, part))
+
+@partial(jax.jit, static_argnames=("cfg", "table", "n_blocks"))
+def _init_row_program(cfg: PSOConfig, seeds, hr: HeteroRow, table,
+                      n_blocks: Optional[int]) -> SwarmState:
+    batch = _init_batch_program(cfg, seeds, hr, table, n_blocks)
+    return SwarmState(*(None if a is None else a[0] for a in batch))
 
 
 def batch_row(batch: SwarmBatch, s: int) -> SwarmState:
@@ -427,48 +423,6 @@ def _run_many_stepped_history(cfg: PSOConfig, batch: SwarmBatch, iters: int,
     return batch, fits, viols
 
 
-# Smallest batch row count whose compiled program is covered by the
-# row-bit-identity validation. XLA:CPU picks loop-body fusion (and with it
-# FMA contraction of the velocity chain) per compiled batch shape; for a few
-# tiny batches the choice rounds 1 ulp differently from the standalone
-# program (root-caused at S=4, dim=3, n=64, sphere: `vel` diverges on the
-# second in-loop iteration while separate per-iteration dispatches match).
-# Rather than chase codegen across every tiny shape, sub-validated batches
-# ride the smallest validated shape with dead rows (sliced off afterwards),
-# which also keeps the jit cache to one program for all S < 8.
-MIN_VALIDATED_SWARMS = 8
-
-
-def _pad_rows(batch: SwarmBatch, target: int) -> SwarmBatch:
-    """Pad a batch to ``target`` rows by replicating row 0 (dead rows)."""
-    k = target - batch.swarm_cnt
-    return SwarmBatch(*jax.tree_util.tree_map(
-        lambda a: jnp.concatenate(
-            [a, jnp.broadcast_to(a[:1], (k,) + a.shape[1:])]),
-        tuple(batch)))
-
-
-def _pad_batch_inputs(batch: SwarmBatch, coeffs, rows, target: int):
-    """Pad the batch AND its per-row companions (coeffs, hetero rows) to
-    ``target`` rows (replicating row 0), for the MIN_VALIDATED_SWARMS
-    dead-row dispatch."""
-    s_cnt = batch.swarm_cnt
-    batch = _pad_rows(batch, target)
-    if coeffs is not None:
-        coeffs = tuple(
-            jnp.concatenate([jnp.asarray(c),
-                             jnp.broadcast_to(jnp.asarray(c)[:1],
-                                              (target - s_cnt,))])
-            for c in coeffs)
-    if rows is not None:
-        rows = ProblemRows(*jax.tree_util.tree_map(
-            lambda a: jnp.concatenate(
-                [a, jnp.broadcast_to(a[:1],
-                                     (target - s_cnt,) + a.shape[1:])]),
-            tuple(rows)))
-    return batch, coeffs, rows
-
-
 def run_many(cfg: PSOConfig, batch: SwarmBatch, iters: int,
              variant: str = "queue",
              coeffs: Optional[Tuple[Array, Array, Array]] = None,
@@ -483,25 +437,12 @@ def run_many(cfg: PSOConfig, batch: SwarmBatch, iters: int,
     ``variant="async"`` vmaps the whole ``run_async`` loop nest instead (it
     carries block-local bests across iterations, so it cannot ride the
     per-step registry); ``run_async`` is cond-free, so the vmap is a pure
-    scheduling transform and per-row bit-identity holds like the others.
+    scheduling transform like the others.
     A thin dispatcher over the jitted implementations, so synchronous
     variants never key their jit cache on the (irrelevant) ``sync_every``.
-
-    Batches smaller than ``MIN_VALIDATED_SWARMS`` are padded to it with
-    dead rows and sliced back, so every dispatch runs a program shape whose
-    row-bit-identity is validated (see the constant's comment — the S=4
-    XLA:CPU contraction anomaly), and the serving layer can bucket at 4
-    again.
     """
     cfg = cfg.resolved()
     s_cnt = batch.swarm_cnt
-    if s_cnt < MIN_VALIDATED_SWARMS:
-        batch, coeffs, rows = _pad_batch_inputs(batch, coeffs, rows,
-                                                MIN_VALIDATED_SWARMS)
-        out = run_many(cfg, batch, iters, variant, coeffs, sync_every,
-                       rows, table, n_blocks)
-        return SwarmBatch(*jax.tree_util.tree_map(lambda a: a[:s_cnt],
-                                                  tuple(out)))
     if variant == "async":
         phases = _batch_phases(batch, sync_every)
         uniq = sorted(set(phases))
@@ -509,7 +450,7 @@ def run_many(cfg: PSOConfig, batch: SwarmBatch, iters: int,
             return _run_many_async(cfg, batch, iters, sync_every, coeffs,
                                    uniq[0], rows, table, n_blocks)
         # Mixed resume points (rows checkpointed at different iterations):
-        # phase is static per compiled program, so dispatch one padded
+        # phase is static per compiled program, so dispatch one
         # program per phase group and scatter the rows back in place.
         out_rows = [None] * s_cnt
         for ph in uniq:
@@ -579,17 +520,6 @@ def run_many_with_history(cfg: PSOConfig, batch: SwarmBatch, iters: int,
                        jnp.stack(viols) if constrained else None)
     if batch.lbest_fit is not None:
         batch = batch._replace(lbest_pos=None, lbest_fit=None)
-    s_cnt = batch.swarm_cnt
-    if s_cnt < MIN_VALIDATED_SWARMS:
-        batch, coeffs, rows = _pad_batch_inputs(batch, coeffs, rows,
-                                                MIN_VALIDATED_SWARMS)
-        out, (its, fits, viols) = run_many_with_history(
-            cfg, batch, iters, variant, coeffs, sync_every, rows, table,
-            n_blocks)
-        out = SwarmBatch(*jax.tree_util.tree_map(lambda a: a[:s_cnt],
-                                                 tuple(out)))
-        return out, (its, fits[:, :s_cnt],
-                     None if viols is None else viols[:, :s_cnt])
     start = int(batch.iteration[0])
     batch, fits, viols = _run_many_stepped_history(cfg, batch, iters,
                                                    variant, coeffs, rows,
@@ -609,14 +539,14 @@ def solve_many(cfg: PSOConfig, seeds, iters: int = 1000,
     ``seeds`` is any int sequence/array of length S; ``variant`` is one of
     ``reduction | queue | queue_lock | async``; ``coeffs`` optionally
     supplies per-swarm ``(w, c1, c2)`` arrays; ``sync_every`` is the async
-    variant's publication interval. Row ``s`` of the result is
-    bit-identical to ``solve(cfg, seeds[s], iters, variant)`` when
-    ``coeffs`` is None.
+    variant's publication interval. Row ``s`` of the result computes
+    ``solve(cfg, seeds[s], iters, variant)`` when ``coeffs`` is None
+    (module docstring: "Agreement").
 
     ``problems`` (length S, names or registered built-in ``Problem``s)
     makes the batch *heterogeneous*: row ``s`` solves ``problems[s]`` —
     its own objective (dispatched by ``lax.switch`` inside one compiled
-    program) and its own box bounds — and is bit-identical to
+    program) and its own box bounds — and computes
     ``solve(cfg_s, seeds[s], iters, variant)`` with ``cfg_s`` the same
     config pointed at ``problems[s]``. ``cfg.fitness`` is ignored for the
     rows (it only keys the compile cache — the serving layer pins it to a

@@ -29,9 +29,10 @@ sequential SPSO (Alg. 1), where gbest updates mid-iteration, lives in
 Scaling note: all three step functions are written to vmap cleanly over a
 leading swarm axis — ``repro.core.multi_swarm.solve_many`` batches many
 independent solves (heterogeneous seeds, optionally per-swarm ``coeffs``
-overriding (w, c1, c2)) into one device program with per-row bit-identity
-to the standalone path. Keep step-function ``lax.cond`` branch outputs
-small (scalars / [D]); see ``step_queue_lock`` for why.
+overriding (w, c1, c2)) into one device program whose rows take the
+standalone path's steps (``multi_swarm``: "Agreement"). Keep step-function
+``lax.cond`` branch outputs small (scalars / [D]); see ``step_queue_lock``
+for why.
 """
 from __future__ import annotations
 
@@ -175,33 +176,27 @@ STREAM_R2 = 3
 
 
 # Heterogeneous dispatch convention: ``hetero=(table, row)`` threads through
-# ``_advance``/the step functions (not the init), where ``table`` is a static
-# tuple of ``Problem``s (a trace-time Python constant; the jit entry points
-# in ``multi_swarm`` key their cache on it) and ``row`` is a ``HeteroRow`` of
-# traced per-swarm operands: the [] int32 index into the table plus the
-# row's [D] bound columns. ``hetero=None`` everywhere keeps the exact
-# pre-hetero jaxprs (Python-gated). The dispatch is deliberately as narrow
-# as possible: the RNG draw and raw velocity/position update chain stay
-# OUTSIDE the switch — byte-identical ops to the homogeneous trace, with the
-# bounds as runtime [D] operands instead of inlined constants — and only the
-# objective evaluation goes through ``lax.switch`` over ``Problem.max_fn``
-# branches. Under vmap the batched switch lowers to compute-all-branches +
-# ``select_n``, i.e. a hetero batch costs ``len(table)`` objective
-# evaluations per step (bounded: the table is the built-in registry).
+# ``_advance``, the step functions and ``_init_body``, where ``table`` is a
+# static tuple of ``Problem``s (a trace-time Python constant; the jit entry
+# points in ``multi_swarm`` key their cache on it) and ``row`` is a
+# ``HeteroRow`` of traced per-swarm operands: the [] int32 index into the
+# table plus the row's [D] bound columns. ``hetero=None`` everywhere keeps
+# the exact pre-hetero jaxprs (Python-gated). The dispatch is deliberately
+# as narrow as possible: the RNG draw and raw velocity/position update
+# chain stay OUTSIDE the switch — byte-identical ops to the homogeneous
+# trace, with the bounds as runtime [D] operands instead of inlined
+# constants — and only the objective evaluation goes through
+# ``lax.switch`` over ``Problem.max_fn`` branches. Under vmap the batched
+# switch lowers to compute-all-branches + ``select_n``, i.e. a hetero batch
+# costs ``len(table)`` objective evaluations per step (bounded: the table
+# is the built-in registry).
 #
-# Exactness envelope (asserted in tests/test_hetero.py): every trajectory
-# field of a hetero row — pos, vel, pbest_pos, gbest_pos — is bit-identical
-# to the standalone ``solve`` of that row's problem. The carried fitness
-# values (fit / pbest_fit / gbest_fit) are bit-identical for most
-# (objective, dim) combos but can differ by 1-2 ulp on a few (observed:
-# griewank at d=10/d=3, rastrigin at d=1): XLA:CPU fuses all table branches
-# into one loop-body cluster and re-vectorizes the objective's sum/prod
-# reduction tail, the same per-shape codegen hazard MIN_VALIDATED_SWARMS
-# documents. This is the best achievable form on this backend — both wider
-# dispatches were tried and are strictly worse: wrapping the whole advance
-# in per-problem branches lets cross-branch CSE perturb the shared velocity
-# chain (real trajectory divergence), and scalar-index conditional dispatch
-# changes loop-body fusion even for a content-identical single branch.
+# Agreement (tests/test_hetero.py): a hetero row computes the trajectory of
+# the standalone ``solve`` of its problem, the same RNG counters and the
+# same update. The two are different compiled programs (bounds as runtime
+# columns, the objective behind a vmapped switch), and XLA:CPU chooses FMA
+# contraction and reduction order per program, so they agree within the
+# limits of tests/agreement.py (the benchmark's own), not bit for bit.
 
 
 class HeteroRow(NamedTuple):
@@ -258,32 +253,48 @@ def init_swarm(cfg: PSOConfig, seed: int, n: Optional[int] = None,
 @partial(jax.jit, static_argnames=("cfg", "n", "n_blocks"))
 def _init_program(cfg: PSOConfig, seed, index_offset, n: int,
                   n_blocks: Optional[int]) -> SwarmState:
-    """One swarm's init as one XLA program: RNG draws, box scaling,
-    objective, argmax (and, with ``n_blocks``, the async locals)."""
+    """One swarm's init as one XLA program (``_init_body``)."""
     count_init_trace()
+    return _init_body(cfg, seed, index_offset, n, n_blocks)
+
+
+def _init_body(cfg: PSOConfig, seed, index_offset, n: int,
+               n_blocks: Optional[int], hetero=None) -> SwarmState:
+    """One swarm's init, traced into a compiled program: RNG draws, box
+    scaling, objective, argmax (and, with ``n_blocks``, the async locals).
+    ``hetero=(table, row)`` takes the box from the row's columns and the
+    objective through the table's switch, as ``_advance`` does."""
     d = cfg.dim
     dt = cfg.jnp_dtype
     idx = (jnp.arange(n * d, dtype=jnp.uint32).reshape(n, d)
            + jnp.asarray(index_offset).astype(jnp.uint32) * jnp.uint32(d))
     u_pos = rng.uniform(seed, 0, STREAM_INIT_POS, idx, dtype=dt)
     u_vel = rng.uniform(seed, 0, STREAM_INIT_VEL, idx, dtype=dt)
-    lo = _bound_operand(cfg.min_pos, dt)
-    hi = _bound_operand(cfg.max_pos, dt)
-    mv = _bound_operand(cfg.max_v, dt)
+    if hetero is None:
+        lo = _bound_operand(cfg.min_pos, dt)
+        hi = _bound_operand(cfg.max_pos, dt)
+        mv = _bound_operand(cfg.max_v, dt)
+    else:
+        table, hr = hetero
+        lo, hi, mv = hr.lo, hr.hi, hr.mv
     span = hi - lo
     pos = lo + _rounded(span * u_pos)
     vel = -mv + _rounded(2.0 * mv * u_vel)
-    prob = cfg.problem
-    if prob.projection_fn is not None:
-        # projection mode: start feasible (box draw projected in-place)
-        pos = prob.projection_fn(pos)
-    elif prob.constrained and prob.constraints.mode == "repair":
-        # repair mode: resample infeasible draws (attempt-indexed RNG on
-        # the init stream; see constraints.repair_init_positions)
-        pos = repair_init_positions(
-            prob.constraints, prob.violation_fn, pos, lo, span, seed,
-            STREAM_INIT_POS, idx, dt)
-    fit = cfg.fitness_fn(pos)
+    if hetero is not None:
+        # table members are unconstrained or penalty-mode (problem_rows)
+        fit = _hetero_fitness(table, hr.fid, pos)
+    else:
+        prob = cfg.problem
+        if prob.projection_fn is not None:
+            # projection mode: start feasible (box draw projected in-place)
+            pos = prob.projection_fn(pos)
+        elif prob.constrained and prob.constraints.mode == "repair":
+            # repair mode: resample infeasible draws (attempt-indexed RNG
+            # on the init stream; see constraints.repair_init_positions)
+            pos = repair_init_positions(
+                prob.constraints, prob.violation_fn, pos, lo, span, seed,
+                STREAM_INIT_POS, idx, dt)
+        fit = cfg.fitness_fn(pos)
     best = jnp.argmax(fit)
     s = SwarmState(
         pos=pos, vel=vel, fit=fit,
@@ -301,11 +312,13 @@ def _init_program(cfg: PSOConfig, seed, index_offset, n: int,
 def _rounded(x: Array) -> Array:
     """``x`` itself, as a value no compiler contracts into the add that
     follows: each init product is rounded on its own, as op-by-op
-    execution and the TPU do. XLA:CPU otherwise fuses ``lo + span * u``
-    into an FMA in one program and not in another (bounds as constants or
-    as runtime columns, the oracles' op-by-op init), and one seed would
-    start from positions an ulp apart. The max with the dtype's lowest
-    finite value is exact for every product that occurs."""
+    execution, the eager oracles (``kernels/ref.py``) and the benchmark's
+    reference do. XLA:CPU otherwise fuses ``lo + span * u`` into an FMA,
+    a swarm starts an ulp off the oracles' init, and PSO's chaotic steps
+    grow that past the agreement limits of tests/agreement.py within a
+    dozen iterations (tests/test_topology.py's ring case). The max with
+    the dtype's lowest finite value is exact for every product that
+    occurs."""
     return jnp.maximum(x, jnp.finfo(x.dtype).min)
 
 
@@ -313,10 +326,10 @@ _init_traces = 0
 
 
 def init_traces() -> int:
-    """How many times a compiled init program (``_init_program``,
-    ``multi_swarm._init_batch_program`` and ``_merge_rows``) has been
-    traced in this process: one per new static key, so a count that grows
-    with the seeds served means the init cache is missing
+    """How many times a compiled init program (``_init_program`` and
+    ``multi_swarm._init_batch_program``) has been traced in this process:
+    one per new static key, so a count that grows with the seeds served
+    means the init cache is missing
     (``repro.telemetry.init_traces``). The programs' cache is the
     process's, and so is this count. Under ``jax.disable_jit()`` nothing
     is compiled and nothing is counted."""
@@ -488,11 +501,9 @@ def step_queue_lock(cfg: PSOConfig, s: SwarmState,
     argmax + D-dim gather predicated on the rare ``any(improved)``.
 
     The cond deliberately carries only the small gbest pair ([], [D]) —
-    never an [N, D] operand. A matrix-valued branch output changes how XLA
-    clusters the surrounding element-wise graph, and (on CPU) the float
-    contraction it picks, breaking the multi-swarm engine's row-bit-identity
-    invariant (vmapped select vs single-swarm cond); see
-    tests/test_multi_swarm.py.
+    never an [N, D] operand: under vmap the cond becomes a select of both
+    branches, and an [N, D] branch output would be computed and selected
+    every iteration instead of a [D] gather.
     """
     pos, vel, fit = _advance(cfg, s, coeffs=coeffs, hetero=hetero)
     better = deb_selection_fn(cfg) if hetero is None else None

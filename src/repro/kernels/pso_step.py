@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for cuPSO (DESIGN.md §2) — one scaffold, seven calls.
+"""Pallas TPU kernels for cuPSO — one scaffold, seven calls.
 
 Layout — SoA, D-major (the paper's §5.1 coalescing rule, translated):
 arrays are ``[Dpad, N]`` with the *particle* index on the 128-wide lane
@@ -102,7 +102,7 @@ The seven pallas_call builders:
     whole span. With a single block the local best IS the global best, so
     the trajectory is bit-identical to ``fused`` for every ``sync_every``
     (the sync kernel is the async kernel's special case); with several
-    blocks the schedule is genuinely relaxed and is mirrored bit-exactly by
+    blocks the schedule is genuinely relaxed and is mirrored by
     ``ref.run_fused_async_oracle``. ``fused_async_batch`` adds the leading
     swarm axis (grid (swarms, blocks, chunks)) with per-swarm gbest buffers
     and per-(swarm, block) local-best slots.
@@ -113,16 +113,14 @@ hand-tuned ``_fitness_dmajor`` forms below (bit-identical to the
 pre-Problem-API kernels); any other Problem is lowered automatically by
 ``dmajor_adapter`` (transpose into the user's ``[bn, d]`` view) with its
 captured array constants hoisted into explicit pallas_call operands by
-``lower_statics`` — Pallas forbids captured consts — and its advance
-outputs pinned via ``optimization_barrier`` so interpret-mode runs stay
-bit-comparable to the eager oracles (see ``_resolve_statics``).
+``lower_statics`` — Pallas forbids captured consts.
 Per-dimension bounds ride the same const-threading as ``[Dpad, 1]``
 columns.
 
 Constraints: a Problem carrying a ``repro.core.constraints.ConstraintSet``
 always lowers by conversion — ``penalty`` mode is invisible here (the
 penalty rides ``Problem.max_fn`` like any custom objective), ``projection``
-mode adds a pinned post-clip transform inside ``_advance_block``
+mode adds a post-clip transform inside ``_advance_block``
 (``kernel_projection`` lifts the user operator to the d-major tile layout;
 its captured consts hoist through ``lower_statics`` exactly like objective
 consts), and ``repair`` mode only affects ``init_swarm`` (kernels receive
@@ -133,9 +131,13 @@ violation, ``repro.core.constraints.deb_improved``) via the d-major
 ``repro.core.pso.deb_selection_fn``; ``penalty`` mode and unconstrained
 problems keep the raw ``fit > pbest`` fold bit-for-bit.
 
-Validated in interpret mode against ``ref.py`` (same counter RNG ⇒
-bit-exact trajectories) over shape/dtype sweeps in tests/test_kernels.py
-and tests/test_async.py; custom-objective parity in tests/test_problem.py.
+Validated in interpret mode against the eager ``ref.py`` oracles (same
+counter RNG, same tile math) over shape/dtype sweeps in
+tests/test_kernels.py and tests/test_async.py, custom objectives in
+tests/test_problem.py. A kernel and its oracle are two programs: XLA:CPU
+chooses FMA contraction and reduction order per compiled program, while
+the chip and the oracles round op by op, so they agree within the stated
+limits of tests/agreement.py (the benchmark's own), not bit for bit.
 tests/test_tpu_compile.py compiles every builder but ``queue_step_call``
 with Mosaic for a described TPU v5e.
 
@@ -386,9 +388,8 @@ def _pbest_improved(fit, pos, pbf, pbp, viol):
 def is_converted(fitness) -> bool:
     """True when ``kernel_fitness`` lowers ``fitness`` by conversion (the
     d-major adapter or a user ``kernel_fn``) rather than the hand-tuned
-    ``_fitness_dmajor`` forms. Converted kernels pin their advance outputs
-    (see ``_resolve_statics``); ``ref.py`` keys its matching behavior on
-    this predicate."""
+    ``_fitness_dmajor`` forms: converted objectives hoist their captured
+    arrays through ``lower_statics``."""
     return getattr(kernel_fitness(fitness), "func", None) is not _fitness_dmajor
 
 
@@ -428,15 +429,13 @@ class _Slot:
 
 
 def lower_statics(fitness, *, d, dpad, bn, dtype,
-                  min_pos, max_pos, max_v, interpret=True):
+                  min_pos, max_pos, max_v):
     """Lower (fitness, bounds) statics to (statics dict, const arrays).
 
     ``consts`` must be appended, in order, to the pallas_call operands
     (specs from ``_const_specs``). ``statics`` entries are Python scalars,
     ``_Slot`` markers pointing into the const values, and the fitness
     callable (plus its own const slots when closure conversion ran).
-    ``interpret`` is the builder's mode: only the interpreter pins the
-    advance outputs (see ``_resolve_statics``).
     """
     consts = []
 
@@ -511,27 +510,13 @@ def lower_statics(fitness, *, d, dpad, bn, dtype,
         st["viol_slots"] = tuple(slot(jnp.asarray(c))
                                  for c in vclosed.consts)
     st["n_consts"] = len(consts)
-    st["pin"] = interpret and (st["fit_slots"] is not None
-                               or st["proj"] is not None)
     return st, tuple(consts)
 
 
 def _resolve_statics(st, const_vals):
     """Kernel-side inverse of ``lower_statics``: returns
-    (min_pos, max_pos, max_v, fitfn, proj, viol, pin) with
+    (min_pos, max_pos, max_v, fitfn, proj, viol) with
     fitfn(pos, dmask, d_real), proj(pos) and viol(pos) (each or None).
-
-    ``pin`` is True in interpret mode for converted (non-hand-tuned)
-    objectives and whenever a feasibility projection is present: the kernel
-    body must pass the advance outputs through ``_pin`` before storing or
-    evaluating fitness. Without it, XLA:CPU fuses the user objective into
-    the velocity/position chain and re-derives a differently-rounded
-    ``pos`` per consumer, drifting 1 ulp from the eager ``ref.py`` oracles
-    and breaking the bit-exact validation contract. The barrier is a no-op
-    eagerly and is skipped entirely for the hand-tuned built-in forms,
-    whose jaxprs (and compiled bits) stay exactly the seed kernels'. Mosaic
-    has no lowering for the barrier and compiles the body as written, so a
-    kernel built for the chip never pins.
     """
     def get(v):
         return const_vals[v.index] if isinstance(v, _Slot) else v
@@ -565,12 +550,7 @@ def _resolve_statics(st, const_vals):
             return _pure(pos, *_extra)
 
     return (get(st["min_pos"]), get(st["max_pos"]), get(st["max_v"]), fit,
-            proj, viol, st["pin"])
-
-
-def _pin(pin, pos, vel):
-    """Materialize the advance outputs (see ``_resolve_statics``)."""
-    return lax.optimization_barrier((pos, vel)) if pin else (pos, vel)
+            proj, viol)
 
 
 def _const_specs(consts):
@@ -747,7 +727,7 @@ def _make_sync_kernel(*, queue=False, batched=False, hetero=False,
     per swarm: queue updates / publications / block improvements — see
     ``repro.telemetry.counters``) and accumulates into it per grid step.
     The gate is Python-level, so a telemetry-off body traces to exactly
-    the pre-telemetry jaxpr (the bit-identity pins never see it).
+    the pre-telemetry jaxpr.
 
     State traffic (``dma``, chosen by the builders from the block count):
     with several blocks per swarm the particle state stays in HBM
@@ -798,7 +778,7 @@ def _make_sync_kernel(*, queue=False, batched=False, hetero=False,
         elif not queue:
             scal_ref = refs[0]
         if not hetero:
-            min_pos, max_pos, max_v, fitness, proj, viol, pin = \
+            min_pos, max_pos, max_v, fitness, proj, viol = \
                 _resolve_statics(statics, const_vals)
         # --- grid coordinates and RNG counters
         if batched or hetero:
@@ -853,7 +833,7 @@ def _make_sync_kernel(*, queue=False, batched=False, hetero=False,
         # --- advance + objective
         if hetero:
             def mk(st):
-                min_pos, max_pos, max_v, fitness, proj, viol, pin = \
+                min_pos, max_pos, max_v, fitness, proj, viol = \
                     _resolve_statics(st, ())
                 del viol   # hetero tables are unconstrained/penalty-mode
 
@@ -864,7 +844,6 @@ def _make_sync_kernel(*, queue=False, batched=False, hetero=False,
                         w=w, c1=c1, c2=c2, min_pos=min_pos,
                         max_pos=max_pos, max_v=max_v, d_real=d_real,
                         project=proj, rule=rule)
-                    pos, vel = _pin(pin, pos, vel)
                     return pos, vel, fitness(pos, dmask, d_real)
 
                 return branch
@@ -882,7 +861,6 @@ def _make_sync_kernel(*, queue=False, batched=False, hetero=False,
                 gp_ref[...], base, w=w, c1=c1, c2=c2, min_pos=min_pos,
                 max_pos=max_pos, max_v=max_v, d_real=d_real, project=proj,
                 rule=rule)
-            pos, vel = _pin(pin, pos, vel)
             fit = fitness(pos, dmask, d_real)                # [1, bn]
         # --- pbest fold + state writes
         imp = _fold_pbest(fit, pos, pbf_ref, pbp_ref, viol)
@@ -960,8 +938,7 @@ def queue_step_call(n: int, d: int, block_n: int, dtype, *,
     dpad = pad_dim(d)
     st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=block_n,
                                dtype=dtype, min_pos=min_pos,
-                               max_pos=max_pos, max_v=max_v,
-                               interpret=interpret)
+                               max_pos=max_pos, max_v=max_v)
     kern = functools.partial(_queue_kernel, w=w, c1=c1, c2=c2, d_real=d,
                              rule=_kernel_rule(rule), statics=st)
     state, scratch, dma = _sync_state_specs(dpad, block_n, nb, dtype, False)
@@ -1015,8 +992,7 @@ def fused_call(n: int, d: int, iters: int, block_n: int, dtype, *,
     dpad = pad_dim(d)
     st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=block_n,
                                dtype=dtype, min_pos=min_pos,
-                               max_pos=max_pos, max_v=max_v,
-                               interpret=interpret)
+                               max_pos=max_pos, max_v=max_v)
     body = _fused_kernel_tel if telemetry else _fused_kernel
     kern = functools.partial(body, w=w, c1=c1, c2=c2, d_real=d,
                              rule=_kernel_rule(rule), statics=st)
@@ -1087,8 +1063,7 @@ def fused_batch_call(s_cnt: int, n: int, d: int, iters: int, block_n: int,
     dpad = pad_dim(d)
     st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=block_n,
                                dtype=dtype, min_pos=min_pos,
-                               max_pos=max_pos, max_v=max_v,
-                               interpret=interpret)
+                               max_pos=max_pos, max_v=max_v)
     body = _fused_batch_kernel_tel if telemetry else _fused_batch_kernel
     kern = functools.partial(body, w=w, c1=c1, c2=c2,
                              d_real=d, rule=_kernel_rule(rule), statics=st)
@@ -1149,7 +1124,7 @@ def fused_batch_call(s_cnt: int, n: int, d: int, iters: int, block_n: int,
 # ``lower_statics`` consts would need per-branch operand plumbing).
 # --------------------------------------------------------------------------
 
-def _hetero_branches(members, *, d, dpad, bn, dtype, interpret):
+def _hetero_branches(members, *, d, dpad, bn, dtype):
     """Per-member kernel statics for a hetero dispatch table.
 
     ``members`` is a tuple of ``(fitness, min_pos, max_pos, max_v)``; each
@@ -1160,7 +1135,7 @@ def _hetero_branches(members, *, d, dpad, bn, dtype, interpret):
     for fitness, mn, mx, mv in members:
         st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=bn,
                                    dtype=dtype, min_pos=mn, max_pos=mx,
-                                   max_v=mv, interpret=interpret)
+                                   max_v=mv)
         if consts:
             raise ValueError(
                 "heterogeneous kernel dispatch requires const-free table "
@@ -1184,7 +1159,7 @@ def hetero_fused_batch_call(s_cnt: int, n: int, d: int, iters: int,
     nb = n // block_n
     dpad = pad_dim(d)
     branches = _hetero_branches(members, d=d, dpad=dpad, bn=block_n,
-                                dtype=dtype, interpret=interpret)
+                                dtype=dtype)
     body = (_hetero_fused_batch_kernel_tel if telemetry
             else _hetero_fused_batch_kernel)
     kern = functools.partial(body, w=w, c1=c1, c2=c2,
@@ -1235,7 +1210,7 @@ def hetero_fused_batch_call(s_cnt: int, n: int, d: int, iters: int,
 def _async_chunk_body(scal0, it_base, sync_every, base,
                       pos, vel, pbp, pbf, lp, lf, *,
                       w, c1, c2, min_pos, max_pos, max_v, d_real, fitness,
-                      project=None, viol=None, pin=False, rule=None,
+                      project=None, viol=None, rule=None,
                       counts=False):
     """``sync_every`` iterations of one block against its block-local best.
 
@@ -1260,7 +1235,6 @@ def _async_chunk_body(scal0, it_base, sync_every, base,
             scal0, it_base + tl + 1, pos, vel, pbp, lp, base,
             w=w, c1=c1, c2=c2, min_pos=min_pos, max_pos=max_pos,
             max_v=max_v, d_real=d_real, project=project, rule=rule)
-        pos, vel = _pin(pin, pos, vel)
         fit = fitness(pos, dmask, d_real)
         imp = _pbest_improved(fit, pos, pbf, pbp, viol)
         pbf = jnp.where(imp, fit, pbf)
@@ -1340,7 +1314,7 @@ def _make_async_kernel(*, batched=False, hetero=False, telemetry=False):
         else:
             scal_ref = refs[0]
         if not hetero:
-            min_pos, max_pos, max_v, fitness, proj, viol, pin = \
+            min_pos, max_pos, max_v, fitness, proj, viol = \
                 _resolve_statics(statics, const_vals)
         # --- grid coordinates, RNG counters, local/global slots
         if batched or hetero:
@@ -1394,7 +1368,7 @@ def _make_async_kernel(*, batched=False, hetero=False, telemetry=False):
         # --- the resident chunk: sync_every iterations vs the local best
         if hetero:
             def mk(st):
-                min_pos, max_pos, max_v, fitness, proj, viol, pin = \
+                min_pos, max_pos, max_v, fitness, proj, viol = \
                     _resolve_statics(st, ())
                 del viol   # hetero tables are unconstrained/penalty-mode
 
@@ -1404,7 +1378,7 @@ def _make_async_kernel(*, batched=False, hetero=False, telemetry=False):
                         seed, it0, sync_every, base, pos, vel, pbp, pbf,
                         lp_, lf_, w=w, c1=c1, c2=c2, min_pos=min_pos,
                         max_pos=max_pos, max_v=max_v, d_real=d_real,
-                        fitness=fitness, project=proj, viol=None, pin=pin,
+                        fitness=fitness, project=proj, viol=None,
                         rule=rule, counts=telemetry)
 
                 return branch
@@ -1419,7 +1393,7 @@ def _make_async_kernel(*, batched=False, hetero=False, telemetry=False):
                 pos_ref[...], vel_ref[...], pbp_ref[...], pbf_ref[...],
                 lp, lf, w=w, c1=c1, c2=c2, min_pos=min_pos,
                 max_pos=max_pos, max_v=max_v, d_real=d_real,
-                fitness=fitness, project=proj, viol=viol, pin=pin,
+                fitness=fitness, project=proj, viol=viol,
                 rule=rule, counts=telemetry)
         if telemetry:
             pos, vel, pbp, pbf, lp, lf, nq, nimp = out
@@ -1546,8 +1520,7 @@ def fused_async_call(n: int, d: int, iters: int, block_n: int,
     dpad = pad_dim(d)
     st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=block_n,
                                dtype=dtype, min_pos=min_pos,
-                               max_pos=max_pos, max_v=max_v,
-                               interpret=interpret)
+                               max_pos=max_pos, max_v=max_v)
     body = _fused_async_kernel_tel if telemetry else _fused_async_kernel
     kern = functools.partial(body, nb=nb,
                              sync_every=sync_every, w=w, c1=c1, c2=c2,
@@ -1618,8 +1591,7 @@ def fused_async_batch_call(s_cnt: int, n: int, d: int, iters: int,
     dpad = pad_dim(d)
     st, consts = lower_statics(fitness, d=d, dpad=dpad, bn=block_n,
                                dtype=dtype, min_pos=min_pos,
-                               max_pos=max_pos, max_v=max_v,
-                               interpret=interpret)
+                               max_pos=max_pos, max_v=max_v)
     body = (_fused_async_batch_kernel_tel if telemetry
             else _fused_async_batch_kernel)
     kern = functools.partial(body, nb=nb,
@@ -1694,7 +1666,7 @@ def hetero_fused_async_batch_call(s_cnt: int, n: int, d: int, iters: int,
     chunks = iters // sync_every
     dpad = pad_dim(d)
     branches = _hetero_branches(members, d=d, dpad=dpad, bn=block_n,
-                                dtype=dtype, interpret=interpret)
+                                dtype=dtype)
     body = (_hetero_fused_async_batch_kernel_tel if telemetry
             else _hetero_fused_async_batch_kernel)
     kern = functools.partial(body, nb=nb,

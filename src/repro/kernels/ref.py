@@ -11,26 +11,29 @@ against ``repro.core.pso`` in tests/test_kernels.py.
 All oracles work on the packed D-major layout (see ops.py for pack/unpack).
 ``fitness`` accepts a registered name or a ``repro.core.problem.Problem``;
 it resolves through the SAME ``pso_step.kernel_fitness`` (hand-tuned fast
-path or generic d-major adapter) as the kernels, so custom-objective runs
-compare bit-for-bit too.
+path or generic d-major adapter) as the kernels, so custom objectives are
+checked like the built-ins.
+
+The oracles round op by op, as the chip does; a compiled program on
+XLA:CPU chooses FMA contraction and reduction order per shape. So a
+kernel or engine and its oracle agree within the stated limits of
+tests/agreement.py (the benchmark's own), not bit for bit.
 """
 from __future__ import annotations
 
 from typing import Tuple
 
-import jax.numpy as jnp
-import numpy as np
-
 import functools
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 from repro.core.topology import block_neighbor_best, kernel_neighbor_ids
 from repro.core.update_rules import resolve_rule
 
-from .pso_step import (_advance_block, _pbest_improved, _pin, is_converted,
-                       kernel_fitness, kernel_projection, kernel_violation,
-                       pad_dim)
+from .pso_step import (_advance_block, _pbest_improved, kernel_fitness,
+                       kernel_projection, kernel_violation, pad_dim)
 
 
 def run_islands_ring_oracle(cfg, seed: int, n_shards: int, iters: int,
@@ -137,23 +140,15 @@ def run_constrained_oracle(cfg, seed: int, iters: int,
     penalized canonical fitness, pbest folds, and the variant's gbest
     publication rule — a Python iteration loop with Python-level
     publication conditionals, no ``cond``, ``fori_loop`` or ``pallas_call``
-    anywhere. Only the advance+fitness subgraph runs under ``jit`` (the
-    ``_advance_fn`` precedent: XLA:CPU FMA-contracts the velocity chain
-    inside a compiled program one ulp differently from op-by-op eager
-    execution, so the oracle compiles the SAME subgraph; the pbest/gbest
-    select folds are rounding-free and stay eager).
+    anywhere. Only the advance+fitness subgraph runs under ``jit``, as the
+    engine's does; the pbest/gbest select folds are rounding-free and stay
+    eager.
 
-    Bit-exactness granularity: the jnp engine dispatched one iteration per
-    call (``run(cfg, s, 1)`` / ``run_async(cfg, s, 1)`` — phase-aligned)
-    matches this oracle BIT-EXACTLY, constraints and all
-    (tests/test_constraints.py). A multi-iteration ``fori_loop`` program
-    additionally FMA-fuses ACROSS iterations (the pre-existing XLA:CPU
-    caveat documented in ``repro.core.multi_swarm`` — it applies to
-    unconstrained built-ins equally), so full-loop runs are validated
-    exact on the gbest trajectory and ulp-tight on positions. The kernel
-    backends validate bit-exact against
-    ``run_fused_oracle``/``run_fused_async_oracle``, which thread the same
-    projection/penalty through the d-major tile machinery.
+    The jnp engine, dispatched per iteration or as one loop program, agrees
+    with this oracle within the limits of tests/agreement.py, constraints
+    and all (tests/test_constraints.py). The kernel backends are checked
+    against ``run_fused_oracle``/``run_fused_async_oracle``, which thread
+    the same projection/penalty through the d-major tile machinery.
 
     Returns a ``repro.core.pso.SwarmState``.
     """
@@ -171,7 +166,7 @@ def run_constrained_oracle(cfg, seed: int, iters: int,
     proj = prob.projection_fn
     # Deb-rule pbest selection for projection/repair modes (penalty mode
     # stays on raw canonical fitness) — the engine's deb_selection_fn gate,
-    # mirrored here so the bit-exact comparison stays like-for-like.
+    # mirrored here so the comparison stays like-for-like.
     deb_vf = (prob.violation_fn
               if prob.constrained and prob.constraints.mode != "penalty"
               else None)
@@ -261,34 +256,16 @@ def run_constrained_oracle(cfg, seed: int, iters: int,
 
 
 def _advance_fn(fitness, **kw):
-    """The oracles' advance step.
-
-    Hand-tuned (built-in) objectives: the plain eager ``_advance_block`` —
-    the seed oracle, bit-for-bit. Converted objectives (d-major adapter /
-    user kernel_fn / constrained problems): the kernels pin their advance
-    outputs with an optimization barrier (see
-    ``pso_step._resolve_statics``), and XLA:CPU rounds that pinned advance
-    cluster differently from op-by-op eager execution — so the oracle runs
-    the SAME pinned subgraph under jit, keeping custom-objective validation
-    bit-exact too. A projection-mode constraint set rides the same hook as
-    in the kernels: the d-major ``kernel_projection`` form applied after
+    """The oracles' advance step: the plain eager ``_advance_block`` for
+    every objective. A projection-mode constraint set rides the same hook
+    as in the kernels: the d-major ``kernel_projection`` form applied after
     the box clip inside ``_advance_block``.
     """
     lifted = kernel_projection(fitness)
     if lifted is not None:
         d_real = kw["d_real"]
         kw = dict(kw, project=lambda p: lifted(p, d_real))
-    adv = functools.partial(_advance_block, **kw)
-    if not (is_converted(fitness) or lifted is not None):
-        return adv
-
-    @jax.jit
-    def stepped(seed, it, pos, vel, pbp, gp, base):
-        p, v, dmask, lane = adv(seed, it, pos, vel, pbp, gp, base)
-        p, v = _pin(True, p, v)
-        return p, v, dmask, lane
-
-    return stepped
+    return functools.partial(_advance_block, **kw)
 
 _BIG = np.int32(2 ** 30)
 

@@ -64,13 +64,7 @@ from repro.core.multi_swarm import (hetero_fid, init_batch, problem_rows,
                                     solve_many)
 from repro.core.problem import Problem, resolve_problem
 
-# Minimum bucket restored to 4: the S=4 row-bit-identity anomaly (XLA:CPU
-# loop-body fusion FMA-contracts the velocity chain 1 ulp differently for a
-# few tiny batch shapes — root-caused at S=4/dim=3/n=64) is pinned at the
-# engine level: ``repro.core.multi_swarm.run_many`` runs sub-8 batches on
-# the smallest VALIDATED program shape with dead rows
-# (MIN_VALIDATED_SWARMS), so a bucket-4 dispatch is row-bit-identical to
-# the standalone solve again (tests/test_multi_swarm.py regression test).
+# Buckets bound the jit cache to one program per (key, bucket).
 _MIN_BUCKET = 4
 BUCKETS = (_MIN_BUCKET, 8, 16, 32, 64, 128)
 

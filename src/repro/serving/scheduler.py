@@ -38,12 +38,14 @@ Admission invariants (the whole correctness argument):
    ``run_async`` (the proven resume path — publication schedule
    unchanged). Requests shorter than one chunk never enter a lane.
 
-Consequence: every per-request result is bit-identical to the standalone
-``core.pso.solve(cfg, seed, T, "async", sync_every)`` of that request
-(asserted in tests/test_serving.py), while steady-state throughput beats
-flush batching on mixed traffic — lane compile keys DROP ``iters``
-(accounting is per-row), so traffic that fragments the flush server's
-groups rides one full lane here (benchmarks/loadgen.py).
+Consequence: every per-request result computes the standalone
+``core.pso.solve(cfg, seed, T, "async", sync_every)`` of that request,
+within the agreement limits of tests/agreement.py (a lane program and a
+standalone solve are two compiled programs; tests/test_serving.py),
+while steady-state throughput beats flush batching on mixed traffic —
+lane compile keys DROP ``iters`` (accounting is per-row), so traffic that
+fragments the flush server's groups rides one full lane here
+(benchmarks/loadgen.py).
 
 Heterogeneous lanes: registry built-ins coalesce into one lane per solve
 shape (``lax.switch`` row dispatch, exactly the flush server's two-tier
@@ -69,13 +71,13 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.blocking import default_block_count
-from repro.core.multi_swarm import (MIN_VALIDATED_SWARMS, ProblemRows,
-                                    batch_row, hetero_fid, problem_rows,
-                                    run_many, set_batch_row, set_problem_row,
+from repro.core.multi_swarm import (ProblemRows, batch_row, hetero_fid,
+                                    init_row, problem_rows, run_many,
+                                    set_batch_row, set_problem_row,
                                     stack_states)
 from repro.core.problem import resolve_problem
-from repro.core.pso import (HeteroRow, PSOConfig, hetero_member_config,
-                            init_swarm_async, run_async, solve)
+from repro.core.pso import (HeteroRow, PSOConfig, init_swarm_async,
+                            run_async, solve)
 from repro.launch.serve import (_HETERO, _HETERO_CANONICAL_FITNESS,
                                 SolveRequest, SolveResult, request_error)
 from repro.telemetry.trace import SPAN_NAMES, now_us, span
@@ -139,10 +141,8 @@ class _Lane:
 class ContinuousScheduler:
     """Streaming solve front end over persistent batched async lanes.
 
-    ``lane_width`` rows per lane (floored at the engine's
-    ``MIN_VALIDATED_SWARMS`` so every dispatch runs a validated program
-    shape); ``coalesce_registry`` merges registry built-ins at one solve
-    shape into heterogeneous lanes; ``compile_cache`` (a
+    ``lane_width`` rows per lane; ``coalesce_registry`` merges registry
+    built-ins at one solve shape into heterogeneous lanes; ``compile_cache`` (a
     ``serving.CompileCache``) makes lane programs restart-persistent;
     ``autotune=True`` rewrites async requests' ``sync_every`` to the
     model-tuned value and caps lane width at the autotuner's bucket
@@ -172,7 +172,7 @@ class ContinuousScheduler:
                  autotune: bool = False,
                  metrics: Optional[ServingMetrics] = None,
                  trace=None, record_history: bool = False):
-        self.lane_width = max(MIN_VALIDATED_SWARMS, lane_width)
+        self.lane_width = lane_width
         self.coalesce_registry = coalesce_registry
         self.autotune = autotune
         self.metrics = metrics or ServingMetrics()
@@ -244,8 +244,8 @@ class ContinuousScheduler:
             ladder = bucket_ladder(
                 r.fitness, r.dim, r.particle_cnt, r.iters,
                 max_batch=self.lane_width, variant=r.variant,
-                dtype=r.dtype, min_bucket=MIN_VALIDATED_SWARMS)
-            self._ladder_width[key] = max(MIN_VALIDATED_SWARMS, ladder[-1])
+                dtype=r.dtype)
+            self._ladder_width[key] = ladder[-1]
         return self._ladder_width[key]
 
     # -- admission ---------------------------------------------------------
@@ -283,9 +283,8 @@ class ContinuousScheduler:
         one, table = problem_rows([prob], lane.cfg.dim, lane.cfg.dtype)
         if lane.table is None:
             lane.table = table
-        # a row starts as its problem's standalone init (as init_batch's)
-        return init_swarm_async(hetero_member_config(lane.cfg, prob), r.seed,
-                                n_blocks=lane.nb), one
+        # the hetero init program at S=1, as a hetero batch's rows start
+        return init_row(lane.cfg, r.seed, one, lane.table, lane.nb), one
 
     def _splice(self, lane: _Lane, slot: int, a: _Active,
                 r: SolveRequest) -> None:

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import agreement
 from repro.core import (PSOConfig, batch_row, init_async_locals, init_batch,
                         init_swarm, publish_async_locals, run, run_async,
                         solve, solve_many, step_async)
@@ -221,13 +222,15 @@ def test_solve_server_async_variant_both_backends():
     reqs = [SolveRequest(dim=2, particle_cnt=128, fitness="cubic", seed=i,
                          iters=8, variant="async", sync_every=4)
             for i in range(3)]
-    # jnp backend == solve_many(variant="async") == standalone run_async
+    # jnp backend == solve_many(variant="async") == standalone run_async:
+    # three requests ride bucket 4, a batch program of 4 rows against a
+    # standalone one, so under the contract (tests/agreement.py)
     jnp_srv = SolveServer(max_batch=8, backend="jnp")
     for r in jnp_srv.solve_all(reqs):
         cfg = r.request.config().resolved()
         direct = run_async(cfg, init_swarm(cfg, r.request.seed), 8,
                            sync_every=4)
-        assert r.gbest_fit == float(direct.gbest_fit)
+        agreement.assert_fit_close(r.gbest_fit, direct.gbest_fit)
     # kernel backend routes through the batched async pallas_call
     k_srv = SolveServer(max_batch=8, backend="kernel", block_n=64)
     for r in k_srv.solve_all(reqs):

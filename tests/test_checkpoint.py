@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import agreement
 from repro import checkpoint as ckpt
 from repro.core import PSOConfig, init_swarm, run
 
@@ -162,7 +163,9 @@ def test_batched_async_resume_bit_exact_any_boundary():
 def test_batched_async_resume_mixed_phases():
     """Rows checkpointed at different iterations resume correctly: run_many
     splits the batch into per-phase dispatch groups, and each row still
-    matches its own standalone run_async continuation bit for bit."""
+    agrees with its own standalone run_async continuation under the
+    contract (tests/agreement.py): a two-row group and a standalone run
+    are two programs."""
     from repro.core import batch_row, run_async, run_many, stack_states
     from repro.core.pso import init_swarm
     cfg = PSOConfig(dim=2, particle_cnt=1024, fitness="cubic").resolved()
@@ -175,10 +178,9 @@ def test_batched_async_resume_mixed_phases():
     out = run_many(cfg, batch, 9, "async", sync_every=8)
     for i in range(6):
         single = run_async(cfg, batch_row(batch, i), 9, sync_every=8)
-        for f in ("pos", "pbest_fit", "gbest_fit", "lbest_fit"):
-            np.testing.assert_array_equal(np.asarray(getattr(out, f)[i]),
-                                          np.asarray(getattr(single, f)),
-                                          err_msg=f"row {i} {f}")
+        agreement.assert_states_agree(
+            batch_row(out, i), single, agreement.box_span(cfg),
+            ("pos", "pbest_fit", "gbest_fit", "lbest_fit"), msg=f"row {i}")
 
 
 def test_step_runner_retry_and_resume(tmp_path):

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import agreement
 import repro
 from repro import Constraint, ConstraintSet, Method, Problem
 from repro.core import PSOConfig, init_swarm, run, run_async, solve
@@ -216,34 +217,25 @@ def test_serial_mirror_matches_constrained_init_and_runs():
 
 
 # --------------------------------------------------------------------------
-# The new eager oracle: jnp engine bit-exactness (per-dispatch granularity)
+# The eager oracle: the jnp engine agrees under the contract
+# (tests/agreement.py), dispatched per iteration and as one loop program
 # --------------------------------------------------------------------------
 
 @pytest.mark.parametrize("prob_name", ["sphere_simplex",
                                        "sphere_simplex_pen", "repair"])
 def test_jnp_queue_lock_bit_exact_vs_constrained_oracle(prob_name):
-    """The jnp engine, dispatched per iteration, matches the independent
-    eager oracle BIT-EXACTLY (float equality on every field). The
-    multi-iteration fori_loop program additionally FMA-fuses across
-    iterations (pre-existing XLA:CPU caveat, see multi_swarm) and is
-    checked exact-on-gbest / ulp-tight-on-positions below."""
+    """The jnp engine, dispatched per iteration and as one loop program,
+    agrees with the independent eager oracle on every field."""
     prob = _ball_repair() if prob_name == "repair" else get_problem(prob_name)
     cfg = PSOConfig(dim=5, particle_cnt=64, fitness=prob).resolved()
+    span = agreement.box_span(cfg)
     o = ref.run_constrained_oracle(cfg, 3, 12, variant="queue_lock")
     s = init_swarm(cfg, 3)
     for _ in range(12):
         s = run(cfg, s, 1, "queue_lock")
-    assert np.array_equal(np.asarray(s.pos), np.asarray(o.pos))
-    assert np.array_equal(np.asarray(s.vel), np.asarray(o.vel))
-    assert np.array_equal(np.asarray(s.pbest_fit), np.asarray(o.pbest_fit))
-    assert float(s.gbest_fit) == float(o.gbest_fit)
-    assert np.array_equal(np.asarray(s.gbest_pos), np.asarray(o.gbest_pos))
-    # the fused loop program: exact gbest value, ulp-tight positions
+    agreement.assert_states_agree(s, o, span, msg="per iteration")
     sf = solve(cfg, seed=3, iters=12, variant="queue_lock")
-    np.testing.assert_allclose(np.asarray(sf.pos), np.asarray(o.pos),
-                               rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(float(sf.gbest_fit), float(o.gbest_fit),
-                               rtol=1e-5)
+    agreement.assert_states_agree(sf, o, span, msg="loop program")
 
 
 @pytest.mark.parametrize("prob_name,sync_every,n_blocks",
@@ -259,19 +251,14 @@ def test_jnp_async_bit_exact_vs_constrained_oracle(prob_name, sync_every,
     o = ref.run_constrained_oracle(cfg, 3, iters, variant="async",
                                    sync_every=sync_every, n_blocks=n_blocks)
     s = init_swarm(cfg, 3)
+    span = agreement.box_span(cfg)
+    fields = agreement.STATE_FIELDS + ("lbest_pos", "lbest_fit")
     for _ in range(iters):      # per-iteration windows, phase auto-aligned
         s = run_async(cfg, s, 1, sync_every=sync_every, n_blocks=n_blocks)
-    assert np.array_equal(np.asarray(s.pos), np.asarray(o.pos))
-    assert np.array_equal(np.asarray(s.pbest_fit), np.asarray(o.pbest_fit))
-    assert np.array_equal(np.asarray(s.lbest_fit), np.asarray(o.lbest_fit))
-    assert float(s.gbest_fit) == float(o.gbest_fit)
-    # full fori_loop program: exact gbest, ulp-tight positions
+    agreement.assert_states_agree(s, o, span, fields, msg="per iteration")
     sf = run_async(cfg, init_swarm(cfg, 3), iters, sync_every=sync_every,
                    n_blocks=n_blocks)
-    np.testing.assert_allclose(np.asarray(sf.pos), np.asarray(o.pos),
-                               rtol=1e-4, atol=1e-5)
-    assert float(sf.gbest_fit) == pytest.approx(float(o.gbest_fit),
-                                                rel=1e-6)
+    agreement.assert_states_agree(sf, o, span, fields, msg="loop program")
 
 
 def test_deb_improved_predicate():
@@ -370,15 +357,15 @@ def test_constrained_fused_kernel_single_block_bit_exact_vs_oracle(
     out = ops.run_queue_lock_fused(cfg, s0, iters=8, block_n=64)
     o = ref.run_fused_oracle(int(s0.seed), 0, pos, vel, pbp, pbf, gp, gf,
                              8, 64, fitness=fitness, **kw)
-    assert np.array_equal(np.asarray(ops.pack_dmajor(out.pos, 5)),
-                          np.asarray(o[0]))
-    assert float(out.gbest_fit) == float(o[5])
-    # the penalized fitness VALUE can round an ulp apart between the
-    # interpret program and the eager oracle even at bit-identical
-    # positions (the violation-sum chain fuses differently); positions and
-    # the gbest trajectory above are the bit-exact contract
-    np.testing.assert_allclose(np.asarray(out.pbest_fit),
-                               np.asarray(o[3])[0], rtol=1e-5, atol=1e-4)
+    # the interpret program and the eager oracle: the contract
+    span = agreement.box_span(cfg)
+    agreement.assert_swarm_close(ops.pack_dmajor(out.pos, 5), o[0], span,
+                                 "pos")
+    agreement.assert_swarm_close(ops.pack_dmajor(out.vel, 5), o[1], span,
+                                 "vel")
+    agreement.assert_fit_close(out.pbest_fit, np.asarray(o[3])[0],
+                               "pbest_fit")
+    agreement.assert_fit_close(out.gbest_fit, o[5], "gbest_fit")
 
 
 @pytest.mark.parametrize("prob_name", ["sphere_simplex",
@@ -414,9 +401,10 @@ def test_constrained_async_kernel_vs_oracle(prob_name, iters, sync_every,
     o = ref.run_fused_async_oracle(int(s0.seed), 0, pos, vel, pbp, pbf,
                                    gp, gf, iters, block_n, sync_every,
                                    fitness=fitness, **kw)
-    np.testing.assert_allclose(np.asarray(ops.pack_dmajor(out.pos, 5)),
-                               np.asarray(o[0]), rtol=1e-5, atol=1e-6)
-    assert float(out.gbest_fit) == pytest.approx(float(o[5]), rel=1e-6)
+    # the interpret program and the eager oracle: the contract
+    agreement.assert_swarm_close(ops.pack_dmajor(out.pos, 5), o[0],
+                                 agreement.box_span(cfg), "pos")
+    agreement.assert_fit_close(out.gbest_fit, o[5], "gbest_fit")
 
 
 def test_constrained_async_single_block_equals_fused_bitwise():
@@ -446,7 +434,8 @@ def test_constrained_kernel_projection_output_feasible():
 
 def test_constrained_kernel_repair_deb_pbest():
     """Kernel-level Deb rule: repair-mode ``_plane_ball`` through the fused
-    kernel matches the (Deb-ized) d-major oracle bit-for-bit, and the pbest
+    kernel agrees with the (Deb-ized) d-major oracle under the contract
+    (tests/agreement.py), and the pbest
     population stays feasible — the raw fold would have let the infeasible
     box corner displace feasible pbests. Gbest feasibility is NOT asserted:
     the kernel publishes from the current fitness (documented seam)."""
@@ -456,9 +445,9 @@ def test_constrained_kernel_repair_deb_pbest():
     out = ops.run_queue_lock_fused(cfg, s0, iters=8, block_n=64)
     o = ref.run_fused_oracle(int(s0.seed), 0, pos, vel, pbp, pbf, gp, gf,
                              8, 64, fitness=fitness, **kw)
-    assert np.array_equal(np.asarray(ops.pack_dmajor(out.pos, 3)),
-                          np.asarray(o[0]))
-    assert float(out.gbest_fit) == float(o[5])
+    agreement.assert_swarm_close(ops.pack_dmajor(out.pos, 3), o[0],
+                                 agreement.box_span(cfg), "pos")
+    agreement.assert_fit_close(out.gbest_fit, o[5], "gbest_fit")
     assert float(np.asarray(p.violation_fn(out.pbest_pos)).max()) <= 0.0
 
 

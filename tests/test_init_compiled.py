@@ -5,15 +5,19 @@ programs keyed on the static config and shape, with the seed as a uint32
 operand (``repro.core.pso._init_program``, ``multi_swarm.init_batch``).
 What these tests hold:
 
-* a batch row, an async-locals init and a heterogeneous row are their
-  standalone init bit for bit, in every field;
+* a batch row and an async-locals init are their standalone init bit for
+  bit, in every field; a heterogeneous row, a different program (its box
+  as runtime columns, its objective behind the table's switch), is its
+  problem's standalone init bit for bit but for the fitness values, which
+  agree under the contract of tests/agreement.py;
 * the integer part (seed, iteration) and the draws scaled into the box
   (positions and velocities) are the op-by-op body's bits under
   ``jax.disable_jit()``: the products are rounded on their own, so no
   program contracts them into an FMA;
-* a new seed never retraces, whatever its integer type, and a new mix of
-  problems in a heterogeneous batch of the same size compiles nothing
-  (the trace counter ``repro.telemetry.init_traces``).
+* a new seed never retraces, whatever its integer type, and a
+  heterogeneous batch is one program whatever its mix, so a new mix of the
+  same size compiles nothing (the trace counter
+  ``repro.telemetry.init_traces``).
 """
 from __future__ import annotations
 
@@ -21,6 +25,7 @@ import jax
 import numpy as np
 import pytest
 
+import agreement
 import repro.telemetry
 from repro.core.multi_swarm import batch_row, init_batch, problem_rows
 from repro.core.pso import (PSOConfig, init_async_locals, init_swarm,
@@ -36,6 +41,19 @@ def _assert_same(a, b, fields=FIELDS, msg=""):
         np.testing.assert_array_equal(np.asarray(getattr(a, f)),
                                       np.asarray(getattr(b, f)),
                                       err_msg=f"{msg}: {f}")
+
+
+def _assert_agree(row, ref, msg=""):
+    """A hetero row against its problem's standalone init: the draws, the
+    positions chosen from them and the integer fields exactly, the
+    fitness values (the objective behind the switch) under the
+    contract."""
+    _assert_same(row, ref, fields=tuple(f for f in FIELDS
+                                        if f not in agreement.FIT_FIELDS),
+                 msg=msg)
+    for f in agreement.FIT_FIELDS:
+        agreement.assert_fit_close(getattr(row, f), getattr(ref, f),
+                                   f"{msg} {f}")
 
 
 @pytest.mark.parametrize("dim,n,fitness", [
@@ -63,8 +81,8 @@ def test_async_init_is_init_plus_seeded_locals(n_blocks):
 
 @pytest.mark.parametrize("dim,n", [(10, 40), (3, 64), (1, 64)])
 def test_hetero_rows_equal_their_standalone_init(dim, n):
-    """Every field, fitness included: a mixed row is initialized by its own
-    problem's program."""
+    """Every field, fitness included: a mixed row starts where its own
+    problem's standalone init does."""
     probs = list(CEC) + ["cubic", "sphere", "ackley"]
     seeds = list(range(100, 100 + len(probs)))
     rows, table = problem_rows(probs, dim)
@@ -72,19 +90,20 @@ def test_hetero_rows_equal_their_standalone_init(dim, n):
                        rows=rows, table=table)
     for s, (p, sd) in enumerate(zip(probs, seeds)):
         ref = init_swarm(PSOConfig(dim=dim, particle_cnt=n, fitness=p), sd)
-        _assert_same(batch_row(batch, s), ref, msg=f"row {s} ({p})")
+        _assert_agree(batch_row(batch, s), ref, msg=f"row {s} ({p})")
 
 
 def test_hetero_batch_programs_keyed_on_problem_and_size():
-    """Any mix of the same problems at the same S reuses the programs: one
-    per problem and S, and one merge per shape."""
+    """Any mix at the same S reuses the one hetero init program."""
     cfg = PSOConfig(dim=4, particle_cnt=24).resolved()
     mixes = [["sphere"] * 5 + ["ackley"] * 3,
              ["ackley"] * 7 + ["sphere"],
              ["sphere", "ackley"] * 4,
              ["ackley"] * 2 + ["sphere"] * 6]
     rows, table = problem_rows(mixes[0], 4)
+    t0 = repro.telemetry.init_traces()
     init_batch(cfg, list(range(8)), rows=rows, table=table)
+    assert repro.telemetry.init_traces() - t0 == 1   # one program, any mix
     t0 = repro.telemetry.init_traces()
     for k, mix in enumerate(mixes):
         rows, table = problem_rows(mix, 4)
@@ -93,7 +112,7 @@ def test_hetero_batch_programs_keyed_on_problem_and_size():
         for s, p in enumerate(mix):
             ref = init_swarm(PSOConfig(dim=4, particle_cnt=24, fitness=p),
                              10 * k + s)
-            _assert_same(batch_row(batch, s), ref, msg=f"mix {k} row {s}")
+            _assert_agree(batch_row(batch, s), ref, msg=f"mix {k} row {s}")
     assert repro.telemetry.init_traces() - t0 == 2   # the standalone refs
 
 
@@ -155,7 +174,8 @@ def test_solve_facade_traces_init_once():
 
 def test_hetero_lane_admissions_trace_once_per_problem():
     """Eight admissions of CEC problems into one hetero lane trace one init
-    program per problem, and the next eight (new seeds) trace none."""
+    program (the hetero init at one row), and the next eight (new seeds)
+    trace none."""
     from repro.launch.serve import SolveRequest
     from repro.serving import ContinuousScheduler
 
@@ -168,7 +188,7 @@ def test_hetero_lane_admissions_trace_once_per_problem():
     sched = ContinuousScheduler(lane_width=8)
     t0 = repro.telemetry.init_traces()
     first = sched.run(reqs(0))
-    assert repro.telemetry.init_traces() - t0 == len(CEC)
+    assert repro.telemetry.init_traces() - t0 == 1
     assert len(sched.snapshot()["lanes"]) == 1
     t1 = repro.telemetry.init_traces()
     sched.run(reqs(500))

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import agreement
 import repro
 from repro import Method, Problem
 from repro.core import PSOConfig, get_problem, init_swarm, solve
@@ -357,11 +358,14 @@ def test_facade_matches_core_solve():
 
 
 def test_facade_solve_many_row_identity():
+    """A batched row against its standalone solve: two programs, so under
+    the contract (tests/agreement.py)."""
     rs = repro.solve_many("cubic", [0, 1, 2, 3], dim=2, particles=64,
                           iters=30, variant="queue")
     r1 = repro.solve("cubic", dim=2, particles=64, iters=30, seed=2,
                      variant="queue")
-    assert _digest(rs[2].state) == _digest(r1.state)
+    agreement.assert_states_agree(rs[2].state, r1.state,
+                                  agreement.box_span(r1.config))
     assert repro.best(rs).gbest_fit == max(r.gbest_fit for r in rs)
 
 

@@ -1,12 +1,13 @@
 """Update-rule layer: every (rule, variant) pair vs its ref.py eager
-oracle, plus the digest regression pinning the refactored scaffold
-bit-identical to the pre-refactor kernel bodies for the default rule."""
+oracle, and the default rule against the same rule spelled out (one
+program, bit for bit)."""
 import hashlib
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import agreement
 import repro
 from repro import Method
 from repro.core import PSOConfig, init_swarm
@@ -157,8 +158,9 @@ def test_async_single_block_equals_fused_per_rule(rule):
 @pytest.mark.parametrize("rule", RULES)
 @pytest.mark.parametrize("variant", ["queue_lock", "async"])
 def test_jnp_engine_vs_oracle_per_rule(rule, variant):
-    """Per-iteration dispatch matches the eager oracle bit-exactly for
-    every rule (the _advance_fn jit-subgraph precedent)."""
+    """Per-iteration dispatch agrees with the eager oracle for every rule,
+    under the contract (tests/agreement.py): two programs, an ulp or two
+    apart."""
     cfg = PSOConfig(dim=5, particle_cnt=64, fitness="sphere",
                     update_rule=rule).resolved()
     iters = 10
@@ -170,31 +172,45 @@ def test_jnp_engine_vs_oracle_per_rule(rule, variant):
             s = run_async(cfg, s, 1, sync_every=4, n_blocks=4)
         else:
             s = run(cfg, s, 1, "queue_lock")
-    assert np.array_equal(np.asarray(s.pos), np.asarray(o.pos))
-    assert np.array_equal(np.asarray(s.pbest_fit), np.asarray(o.pbest_fit))
-    assert float(s.gbest_fit) == float(o.gbest_fit)
+    agreement.assert_states_agree(
+        s, o, agreement.box_span(cfg),
+        fields=("pos", "vel", "pbest_pos", "pbest_fit", "gbest_fit"))
 
 
 # --------------------------------------------------------------------------
-# Digest regression: the scaffold refactor is bit-identical for the
-# default rule (same params as tests/test_problem.py's seed pins)
+# The default rule: the same program as the rule spelled out, bit for bit,
+# and the eager oracle's trajectory under the contract (same params as
+# tests/test_problem.py's kernel case)
 # --------------------------------------------------------------------------
 
 def test_scaffold_default_rule_digests_unchanged():
     cfg = PSOConfig(dim=2, particle_cnt=128, fitness="cubic").resolved()
     assert isinstance(resolve_rule(cfg.update_rule), PSORule)
-    s0 = init_swarm(cfg, 5)
+    s0, (pos, vel, pbp, pbf, gp, gf), fitness, kw = _oracle_inputs(cfg, 5)
+    span = agreement.box_span(cfg)
     k = ops.run_queue_lock_fused(cfg, s0, iters=12, block_n=64)
-    assert _digest(k) == "e738dfc1df826106"
+    o = ref.run_fused_oracle(int(s0.seed), 0, pos, vel, pbp, pbf, gp, gf,
+                             12, 64, fitness=fitness, **kw)
+    agreement.assert_swarm_close(ops.pack_dmajor(k.pos, 2), o[0], span,
+                                 "fused pos")
+    agreement.assert_fit_close(k.gbest_fit, o[5], "fused gbest_fit")
     a = ops.run_queue_lock_fused_async(cfg, s0, iters=12, sync_every=4,
                                        block_n=64)
-    assert _digest(a) == "919036ad04111333"
-    # and spelling the default rule explicitly traces the same program
+    oa = ref.run_fused_async_oracle(int(s0.seed), 0, pos, vel, pbp, pbf,
+                                    gp, gf, 12, 64, 4, fitness=fitness,
+                                    **kw)
+    agreement.assert_swarm_close(ops.pack_dmajor(a.pos, 2), oa[0], span,
+                                 "async pos")
+    agreement.assert_fit_close(a.gbest_fit, oa[5], "async gbest_fit")
+    # spelling the default rule explicitly traces the same program
     cfg2 = PSOConfig(dim=2, particle_cnt=128, fitness="cubic",
                      update_rule="pso").resolved()
     k2 = ops.run_queue_lock_fused(cfg2, init_swarm(cfg2, 5), iters=12,
                                   block_n=64)
-    assert _digest(k2) == "e738dfc1df826106"
+    assert _digest(k2) == _digest(k)
+    a2 = ops.run_queue_lock_fused_async(cfg2, init_swarm(cfg2, 5), iters=12,
+                                        sync_every=4, block_n=64)
+    assert _digest(a2) == _digest(a)
 
 
 # --------------------------------------------------------------------------
