@@ -11,17 +11,20 @@ does through one ``ServingMetrics`` object:
   (submit -> completion). Each span keeps a bounded reservoir of samples
   and reports count/mean/p50/p99.
 * **Counters** — monotonic event counts: ``submitted`` / ``admitted`` /
-  ``completed`` / ``failed`` requests, ``dispatches``, ``row_swaps``
+  ``completed`` / ``failed`` requests, ``dispatches``, ``chunks`` (the
+  chunks those dispatches carried: a scheduler dispatch runs until its
+  lane's next row leaves, so ``chunks / dispatches`` is how many chunks
+  one dispatch fuses), ``row_swaps``
   (a freed lane slot re-admitted a fresh request without restarting the
   program — the continuous-batching event), ``tail_ejections`` (a row
   left its lane to finish a sub-chunk remainder standalone),
   ``aot_hits`` / ``aot_misses`` / ``trace_events`` from the compile
   cache, and the batch-fill pair ``lane_slots`` / ``lane_active_slots``.
 
-``batch_fill`` is derived (active / stepped slots — 1.0 means every
-dispatched row was real work), and ``snapshot()`` renders the whole
-thing as a JSON-able dict so a replica can export its serving state to
-disk or over the wire (``dump()``).
+``batch_fill`` is derived (active / stepped slots, counted per chunk —
+1.0 means every dispatched row was real work), and ``snapshot()``
+renders the whole thing as a JSON-able dict so a replica can export its
+serving state to disk or over the wire (``dump()``).
 
 Everything here is host-side bookkeeping — no jax imports, no effect on
 compiled programs.

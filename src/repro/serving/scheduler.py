@@ -11,9 +11,12 @@ late arrival waits a whole batch.
 
 This scheduler keeps a small number of persistent **lanes** running
 instead. A lane is a ``SwarmBatch`` of ``width`` independent rows that
-advances ``sync_every`` iterations per dispatch (one chunk) through ONE
-compiled program, reused for the lane's whole lifetime. The paper's
-enhanced queue-lock semantics make the chunk boundary a natural
+advances in chunks of ``sync_every`` iterations through ONE compiled
+program, reused for the lane's whole lifetime. The program takes the
+number of chunks ``k`` as an operand and loops over the chunk
+(``lax.fori_loop``), so one dispatch carries as many chunks as the lane
+can run before a row leaves it. The paper's enhanced queue-lock
+semantics make the chunk boundary a natural
 preemption point: blocks only touch shared state at publication points,
 so between chunks every row is at a publication boundary and its state is
 a complete, bit-exact checkpoint (PR-4/PR-6 machinery: ``SwarmState``
@@ -37,6 +40,15 @@ Admission invariants (the whole correctness argument):
    ejects the row at the last chunk boundary and finishes standalone via
    ``run_async`` (the proven resume path — publication schedule
    unchanged). Requests shorter than one chunk never enter a lane.
+4. **A dispatch runs until the next row leaves.** It advances the lane
+   ``k`` chunks, ``k`` the fewest whole chunks any active row has left
+   (``(T - done) // sync_every``, at least 1 once the sub-chunk
+   remainders are ejected), so it ends exactly where a row finishes or
+   reaches its remainder: no row overshoots its budget, and every row
+   stays at phase 0. An arrival that comes during a dispatch waits for
+   its end — at most until the next row leaves, which is also when a
+   full lane frees a slot. With ``record_history=True``, ``k`` is 1, so
+   the history keeps its sample at every chunk boundary.
 
 Consequence: every per-request result computes the standalone
 ``core.pso.solve(cfg, seed, T, "async", sync_every)`` of that request,
@@ -68,7 +80,9 @@ import hashlib
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
+from jax import lax
 
 from repro.core.blocking import default_block_count
 from repro.core.multi_swarm import (ProblemRows, batch_row, hetero_fid,
@@ -133,8 +147,10 @@ class _Lane:
         content = (_HETERO if self.hetero
                    else "content:" + hashlib.sha1(
                        repr(self.key).encode()).hexdigest()[:16])
+        # ``k``: the chunk count is an operand of the program, so blobs
+        # exported with another signature are never loaded under this key.
         return (f"lane|d{c.dim}|n{c.particle_cnt}|{c.dtype}"
-                f"|se{self.sync_every}|nb{self.nb}|w{self.width}"
+                f"|se{self.sync_every}|k|nb{self.nb}|w{self.width}"
                 f"|r{c.update_rule}|t{c.topology}|{content}")
 
 
@@ -151,10 +167,11 @@ class ContinuousScheduler:
 
     Telemetry (``repro.telemetry``): ``trace`` (a ``TraceWriter``) records
     the serving timeline — one Perfetto row per lane with a span per
-    dispatched chunk, admit/eject instants, a per-request span, and a
-    lane-fill counter track. ``record_history=True`` samples every lane
-    row's gbest at its chunk boundaries onto ``SolveResult.history``
-    (lane-riding async requests only; standalone fallbacks report None).
+    dispatch (its chunk count in the args), admit/eject instants, a
+    per-request span, and a lane-fill counter track.
+    ``record_history=True`` samples every lane row's gbest at its chunk
+    boundaries onto ``SolveResult.history`` (lane-riding async requests
+    only; standalone fallbacks report None).
 
     Profiler spans (``repro.telemetry.span``): ``sched_step`` and
     ``sched_dispatch`` on every step, ``sched_admit`` while requests
@@ -270,7 +287,7 @@ class ContinuousScheduler:
             lane = self._lane_for(r)
             slot = lane.free_slot()
             if slot is None:
-                still.append(a)     # lane full: wait for a chunk boundary
+                still.append(a)     # lane full: wait for a row to leave
                 continue
             self._splice(lane, slot, a, r)
         self._pending = still
@@ -385,54 +402,62 @@ class ContinuousScheduler:
     def _lane_program(self, lane: _Lane):
         if lane.program is not None:
             return lane.program
-        cfg, chunk, se, nb, table = (lane.cfg, lane.sync_every,
-                                     lane.sync_every, lane.nb, lane.table)
-        if lane.hetero:
-            def build(batch, rows):
-                return run_many(cfg, batch, chunk, "async", sync_every=se,
-                                rows=rows, table=table, n_blocks=nb)
-            args = (lane.batch, lane.rows)
-        else:
-            def build(batch):
-                return run_many(cfg, batch, chunk, "async", sync_every=se,
-                                n_blocks=nb)
-            args = (lane.batch,)
+        cfg, se, nb, table = lane.cfg, lane.sync_every, lane.nb, lane.table
+
+        def build(batch, rows, k):
+            # k chunks of the one-chunk program (``rows`` is None in a
+            # content lane); every row stays at phase 0
+            return lax.fori_loop(0, k, lambda _, b: run_many(
+                cfg, b, se, "async", sync_every=se, rows=rows, table=table,
+                n_blocks=nb), batch)
         if self.compile_cache is None:
-            lane.program = build
+            lane.program = jax.jit(build)
         else:
             with span(SPAN_NAMES.sched_compile, metrics=self.metrics,
                       metric="compile_us"):
                 lane.program = self.compile_cache.get(
-                    lane.program_key(), build, *args)
+                    lane.program_key(), build, lane.batch, lane.rows,
+                    np.int32(1))
         return lane.program
 
-    def _chunk_event(self, lane: _Lane):
+    def _chunks(self, lane: _Lane) -> int:
+        """Whole chunks until the lane's next row leaves (invariant 4)."""
+        if self.record_history:
+            return 1
+        return min((a.request.iters - a.done) // lane.sync_every
+                   for a in lane.slots if a is not None)
+
+    def _chunk_event(self, lane: _Lane, k: int):
         """The ``TraceWriter`` event of the lane's next dispatch."""
-        return (f"chunk {lane.chunks_dispatched + 1}", dict(
+        first = lane.chunks_dispatched + 1
+        name = f"chunk {first}" if k == 1 else f"chunk {first}-{first + k - 1}"
+        return (name, dict(
             process="serving", thread=f"lane {lane.uid}", cat="dispatch",
             args={"active": lane.active_count, "width": lane.width,
-                  "sync_every": lane.sync_every}))
+                  "sync_every": lane.sync_every, "chunks": k}))
 
     def _dispatch(self, lane: _Lane) -> None:
         program = self._lane_program(lane)
-        args = (lane.batch, lane.rows) if lane.hetero else (lane.batch,)
+        k = self._chunks(lane)
         with span(SPAN_NAMES.sched_dispatch, metrics=self.metrics,
                   metric="dispatch_us", trace=self.trace,
-                  event=lambda: self._chunk_event(lane)) as sp:
-            out = program(*args)
+                  event=lambda: self._chunk_event(lane, k)) as sp:
+            out = program(lane.batch, lane.rows, np.int32(k))
             out.gbest_fit.block_until_ready()
         lane.batch = out
-        lane.chunks_dispatched += 1
+        lane.chunks_dispatched += k
+        active = lane.active_count
         self.metrics.inc("dispatches")
-        self.metrics.inc("lane_slots", lane.width)
-        self.metrics.inc("lane_active_slots", lane.active_count)
+        self.metrics.inc("chunks", k)
+        self.metrics.inc("lane_slots", k * lane.width)
+        self.metrics.inc("lane_active_slots", k * active)
         if self.trace is not None:
             self.trace.counter(f"lane {lane.uid} fill", sp.t0_us,
-                               {"active": lane.active_count,
-                                "idle": lane.width - lane.active_count})
+                               {"active": active,
+                                "idle": lane.width - active})
         for i, a in enumerate(lane.slots):
             if a is not None:
-                a.done += lane.sync_every
+                a.done += k * lane.sync_every
                 if a.history is not None:
                     a.history.append((a.done,
                                       float(lane.batch.gbest_fit[i])))
@@ -440,8 +465,9 @@ class ContinuousScheduler:
     # -- the loop ----------------------------------------------------------
     def step(self) -> Dict[int, SolveResult]:
         """One scheduling round: admit at the boundary, advance every
-        active lane one chunk, harvest completions. Returns the results
-        that completed this round (also retained for ``drain``/``run``)."""
+        active lane until its next row leaves (invariant 4), harvest
+        completions. Returns the results that completed this round (also
+        retained for ``drain``/``run``)."""
         with span(SPAN_NAMES.sched_step):
             before = set(self._results)
             if self._pending:
@@ -503,7 +529,6 @@ class ContinuousScheduler:
 
 def jax_broadcast_rows(one: ProblemRows, width: int) -> tuple:
     """Replicate a 1-row descriptor set to ``width`` rows (lane bootstrap)."""
-    import jax
     import jax.numpy as jnp
     return tuple(jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a[:1], (width,) + a.shape[1:]),

@@ -112,6 +112,73 @@ def test_scheduler_homogeneous_lane_for_custom_problem():
     _assert_bit_exact(results, reqs)
 
 
+# -- a dispatch runs until the lane's next row leaves ----------------------
+
+def _chunk_counts(trace):
+    """The chunk count of every lane dispatch a ``TraceWriter`` saw."""
+    return [e["args"]["chunks"] for e in trace.to_dict()["traceEvents"]
+            if e.get("cat") == "dispatch"]
+
+
+def test_scheduler_dispatch_runs_until_next_row_leaves():
+    """One lane of 4 holding rows with different remaining budgets: each
+    dispatch carries the fewest whole chunks any row has left. A row is
+    admitted mid-flight into the slot a finished row freed, a row leaves
+    with its remainder, a sub-chunk request runs standalone, and every
+    answer agrees with its standalone solve."""
+    from repro.serving import ContinuousScheduler
+    from repro.telemetry import TraceWriter
+    first = [_req(0, 40), _req(1, 16), _req(2, 28), _req(3, 4)]
+    late = _req(4, 24)
+    tw = TraceWriter()
+    sched = ContinuousScheduler(lane_width=4, trace=tw)
+    tickets = [sched.submit(r) for r in first]
+    sched.step()             # 5, 2 and 3 chunks left: 2 run, row 1 leaves
+    tickets.append(sched.submit(late))
+    done = sched.drain()     # then 1 chunk (row 2 reaches its remainder), 2
+    _assert_agree([done[t] for t in tickets], first + [late])
+    m = sched.metrics
+    assert _chunk_counts(tw) == [2, 1, 2]
+    assert m.get("dispatches") == 3 and m.get("chunks") == 5
+    assert m.get("lane_slots") == 5 * 4            # counted per chunk
+    assert m.get("lane_active_slots") == 2 * 3 + 1 * 3 + 2 * 2
+    assert m.get("row_swaps") == 1
+    assert m.get("tail_ejections") == 1
+    assert m.get("standalone_solves") == 1
+    assert sched.snapshot()["lanes"][0]["chunks"] == 5
+
+
+@pytest.mark.parametrize("iters,chunks,ejections",
+                         [(SE, 1, 0), (5 * SE, 5, 0), (5 * SE + 4, 5, 1)])
+def test_scheduler_lone_request_one_dispatch(iters, chunks, ejections):
+    """A lone request of n whole chunks is served in one dispatch of n
+    chunks; a remainder then leaves the lane on its own."""
+    from repro.serving import ContinuousScheduler
+    req = _req(0, iters)
+    sched = ContinuousScheduler(lane_width=8)
+    _assert_agree(sched.run([req]), [req])
+    m = sched.metrics
+    assert m.get("dispatches") == 1 and m.get("chunks") == chunks
+    assert m.get("tail_ejections") == ejections
+
+
+def test_scheduler_history_samples_every_chunk_boundary():
+    """With ``record_history`` a dispatch carries one chunk, so every row
+    keeps a sample at each of its chunk boundaries (and one at its
+    ejection), the last one its answer."""
+    from repro.serving import ContinuousScheduler
+    reqs = [_req(0, 24), _req(1, 40), _req(2, 28)]
+    sched = ContinuousScheduler(lane_width=4, record_history=True)
+    results = sched.run(reqs)
+    _assert_agree(results, reqs)
+    want = ([8, 16, 24], [8, 16, 24, 32, 40], [8, 16, 24, 28])
+    for res, its in zip(results, want):
+        assert list(res.history.iteration) == its
+        assert float(res.history.gbest_fit[-1]) == res.gbest_fit
+    m = sched.metrics
+    assert m.get("dispatches") == m.get("chunks") == 5
+
+
 # -- the restart story: persistent AOT compile cache -----------------------
 
 def test_compile_cache_restart_zero_retrace_bit_exact(tmp_path):
@@ -137,6 +204,34 @@ def test_compile_cache_restart_zero_retrace_bit_exact(tmp_path):
         np.testing.assert_array_equal(ra.gbest_pos, rb.gbest_pos)
     _assert_agree(b, reqs)
     assert sched.snapshot()["compile_cache"]["trace_events"] == 0
+
+
+def test_compile_cache_one_lane_program_for_every_chunk_count(tmp_path):
+    """The chunk count is an operand: one traced lane program serves
+    dispatches of 1 and of 3 chunks, and a restarted replica serves the
+    same dispatches from the exported blob with no trace at all."""
+    from repro.serving import CompileCache, ContinuousScheduler
+    from repro.telemetry import TraceWriter
+    reqs = [_req(0, 16), _req(1, 8), _req(2, 40)]
+
+    def serve(cc):
+        tw = TraceWriter()
+        out = ContinuousScheduler(lane_width=4, compile_cache=cc,
+                                  trace=tw).run(reqs)
+        assert _chunk_counts(tw) == [1, 1, 3]
+        return out
+
+    cold = CompileCache(str(tmp_path))
+    a = serve(cold)
+    assert cold.aot_misses == 1 and cold.trace_events == 1
+    warm = CompileCache(str(tmp_path))
+    assert warm.prewarm() == 1
+    b = serve(warm)
+    assert warm.aot_misses == 0 and warm.trace_events == 0
+    for ra, rb in zip(a, b):
+        assert ra.gbest_fit == rb.gbest_fit
+        np.testing.assert_array_equal(ra.gbest_pos, rb.gbest_pos)
+    _assert_agree(b, reqs)
 
 
 def test_compile_cache_memory_only_dedup():
